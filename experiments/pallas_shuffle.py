@@ -14,7 +14,7 @@ Spread variants measured against each other:
   onehot  — int8 one-hot (q_w, W) @ (W, L) on the MXU
 
 Usage:
-  python experiments/pallas_shuffle.py check     # interpret-mode correctness
+  JAX_PLATFORMS=cpu python experiments/pallas_shuffle.py check   # interpret
   python experiments/pallas_shuffle.py bench gather|onehot [W G]
 """
 import builtins
@@ -173,7 +173,6 @@ def _ref_impl(pid, data, G, W, quota):
 
 
 def check():
-    jax.config.update("jax_platforms", "cpu")
     cap, L, W, G = 4096, 16, 256, 4
     q_w, quota = 96, 320
     rng = np.random.default_rng(0)

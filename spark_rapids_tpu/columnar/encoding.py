@@ -1,7 +1,7 @@
 """Encoded columnar forms that cross the host link instead of decoded bytes.
 
-BENCH_r05 put 0.043 s of device compute under a 12.55 s H2D upload — the
-link is the wall, so this module stops shipping decoded bytes over it
+Where the host link is the wall (columnar/transfer.py), this module stops
+shipping decoded bytes over it
 (ROADMAP item 1; "GPU Acceleration of SQL Analytics on Compressed Data"
 measures order-of-magnitude effective-bandwidth gains from exactly this
 shape). Three cooperating pieces:

@@ -1,7 +1,8 @@
 """Transfer pipeline: chunked overlapped uploads and asynchronous downloads.
 
-BENCH_r05 put TPC-H Q1 at 0.043 s of device compute under a 12.55 s upload
-and 1.16 s download — the engine is data-movement-bound, the regime Theseus
+An early record (since deleted; the host link has not been measured on
+today's machine) put TPC-H Q1's device compute at a small fraction of its
+upload and download — a data-movement-bound engine, the regime Theseus
 says a distributed accelerator query engine must engineer around and the
 reference plugin covers with pinned-memory async H2D in
 ``HostToGpuCoalesceIterator``. This module makes the host link a pipeline

@@ -372,6 +372,11 @@ _SAMPLE_TARGET = 4096
 #: two-dispatch path) from "kernel path refused entirely" (None -> sort)
 _NOT_FUSABLE = object()
 
+#: exchange exec metrics: map-side batches split by the Pallas reorder
+#: kernel vs by the variadic sort (the kernel declines silently otherwise)
+KERNEL_SPLIT_BATCHES = "kernelSplitBatches"
+SORT_SPLIT_BATCHES = "sortSplitBatches"
+
 
 def _sample_bounds(orders: Sequence[SortOrder], sampled: List[List[ColV]],
                    n: int) -> Optional[List[ColV]]:
@@ -432,6 +437,21 @@ def _kmv_merge(pool: "np.ndarray", hashes: "np.ndarray") -> "np.ndarray":
     # copies of one heavy-hitter hash, which would evict every other
     # distinct hash from the pool and collapse the estimate
     return np.unique(np.concatenate([pool, np.unique(hashes)]))[:_KMV_K]
+
+
+def _key_hashes(xp, keys, ectx: EvalCtx) -> List:
+    """Per-row uint32 hash of each key expression, nulls hashed alike."""
+    out = []
+    for e in keys:
+        v = e.eval(ectx)
+        ch = _column_hash(xp, v)
+        if ch.ndim == 0:        # scalar key (literal): one value
+            ch = xp.broadcast_to(ch, (1,))
+        valid = v.validity
+        if getattr(valid, "ndim", 1) == 0:
+            valid = xp.broadcast_to(valid, ch.shape)
+        out.append(xp.where(valid, ch, _H_NULL))
+    return out
 
 
 def _kmv_estimate(pool: "np.ndarray") -> int:
@@ -564,34 +584,48 @@ class ShuffleExchangeExecBase(PhysicalExec):
         ndv = tuple(_kmv_estimate(pool) for pool in (self._key_sketches or ()))
         return StageStats(rows, tuple(r * width for r in rows), ndv)
 
-    def _sketch_keys(self, xp, ectx: EvalCtx, num_rows: int) -> None:
-        """Fold one batch's key-column hashes into the per-column KMV pools
-        (hash partitioning only). Under the device xp the per-batch cost is
-        an eager elementwise hash + top-k sort; only the k smallest hash
-        VALUES ever download (bounded, _KMV_K uint32s per column per batch)."""
+    def _sketch_keys(self, ectx: EvalCtx, num_rows: int) -> None:
+        """Fold one host batch's key-column hashes into the per-column KMV
+        pools (hash partitioning only)."""
         part = self.partitioning
         if not isinstance(part, HashPartitioning) or num_rows <= 0:
             return
+        self._merge_sketches([ch[:num_rows]
+                              for ch in _key_hashes(np, part.keys, ectx)])
+
+    def _sketch_keys_device(self, ctx: ExecContext, db: DeviceBatch) -> None:
+        """`_sketch_keys` for a device batch: ONE cached program per (keys,
+        schema, capacity) — the row count rides as a runtime argument, so
+        pieces of different sizes share it — hashes each key column and
+        top-k sorts it. Only the k smallest DISTINCT hash VALUES download
+        (bounded, _KMV_K uint32s per column per batch), never key data."""
+        part = self.partitioning
+        schema, cap, smax = db.schema, db.capacity, ctx.string_max_bytes
+        key = ("exchange-sketch", part.keys, schema, cap, smax)
+
+        def build(keys=part.keys, schema=schema, cap=cap, smax=smax):
+            def fn(num_rows, *flat):
+                ectx = EvalCtx(jnp, _unflatten_colvs(schema, flat), cap, smax)
+                live = jnp.arange(cap, dtype=np.int32) < num_rows
+                out = []
+                for ch in _key_hashes(jnp, keys, ectx):
+                    # dead rows repeat row 0 (live: num_rows > 0); unique
+                    # sorts, then truncates to k; the host merge collapses
+                    # the repeats
+                    ch = jnp.where(live, jnp.broadcast_to(ch, (cap,)), ch[0])
+                    out.append(jnp.unique(ch, size=min(_KMV_K, cap),
+                                          fill_value=ch[0]))
+                return tuple(out)
+            return fn
+
+        self._merge_sketches(
+            _cached_jit(key, build)(np.int32(db.num_rows), *_flatten(db)))
+
+    def _merge_sketches(self, hashes) -> None:
         if self._key_sketches is None:
             self._key_sketches = [np.zeros(0, dtype=np.uint32)
-                                  for _ in part.keys]
-        for ki, e in enumerate(part.keys):
-            v = e.eval(ectx)
-            ch = _column_hash(xp, v)
-            if ch.ndim == 0:        # scalar key (literal): one value
-                ch = xp.broadcast_to(ch, (1,))
-            valid = v.validity
-            if getattr(valid, "ndim", 1) == 0:
-                valid = xp.broadcast_to(valid, ch.shape)
-            ch = xp.where(valid, ch, _H_NULL)[:num_rows]
-            if xp is not np:
-                k = min(_KMV_K, int(ch.shape[0]))
-                # bounded download: only the k smallest DISTINCT hash
-                # values leave the device, never key data (same discipline
-                # as the range bounds sample in _device_bounds). unique
-                # sorts then truncates to k; the pad repeats ch[0], which
-                # the host-side merge collapses
-                ch = np.asarray(jnp.unique(ch, size=k, fill_value=ch[0]))
+                                  for _ in hashes]
+        for ki, ch in enumerate(hashes):
             self._key_sketches[ki] = _kmv_merge(self._key_sketches[ki],
                                                 np.asarray(ch))
 
@@ -699,7 +733,7 @@ class CpuShuffleExchangeExec(ShuffleExchangeExecBase):
             ectx = EvalCtx(np, colvs, cap, ctx.string_max_bytes)
             with np.errstate(invalid="ignore", over="ignore"):
                 pids = _compute_pids(np, part, ectx, cap, offset, bounds)
-                self._sketch_keys(np, ectx, cap)
+                self._sketch_keys(ectx, cap)
             sorted_cols, counts = split_by_pid(np, colvs, pids, hb.num_rows, n)
             offsets = np.concatenate([[0], np.cumsum(counts)])
             for j in range(n):
@@ -873,11 +907,7 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         sketch = isinstance(self.partitioning, HashPartitioning)
         for map_p, j, sub in self.iter_map_pieces(ctx):
             if sketch and sub.num_rows > 0:
-                colvs = [ColV(c.dtype, c.data, c.validity, c.lengths)
-                         for c in sub.columns]
-                self._sketch_keys(
-                    jnp, EvalCtx(jnp, colvs, sub.capacity,
-                                 ctx.string_max_bytes), sub.num_rows)
+                self._sketch_keys_device(ctx, sub)
             sub = uniform_string_batch(sub)
             layout = DevicePackLayout.for_batch_shape(
                 sub.schema, sub.capacity, batch_string_max(sub))
@@ -904,6 +934,7 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
             # values (the PR 4 repack headroom): the reorder moves 4
             # bytes/row where a decoded string column moves its full
             # byte-matrix row
+            self.metrics[SORT_SPLIT_BATCHES].add(1)
             yield from self._split_batch_encoded(ctx, part, db, offset, n,
                                                  bounds, enc)
             return
@@ -913,8 +944,10 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         if bounds is None:
             pieces = self._kernel_split(ctx, part, db, offset, n)
             if pieces is not None:
+                self.metrics[KERNEL_SPLIT_BATCHES].add(1)
                 yield from pieces
                 return
+        self.metrics[SORT_SPLIT_BATCHES].add(1)
         bounds_flat = tuple(flatten_colvs(bounds)) if bounds else ()
         nb = bounds[0].validity.shape[0] if bounds else 0
         # n is keyed: the traced program returns an n-length counts vector,
@@ -1080,49 +1113,51 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
                 return any(_touches_double(c) for c in e.children)
             if any(_touches_double(k) for k in part.keys):
                 return _NOT_FUSABLE
-        spec = pk.PackSpec.for_batch(db)
-        if spec is None or n < 2 or n > pk.MAX_PARTS:
-            return _NOT_FUSABLE
         schema, cap, smax = db.schema, db.capacity, ctx.string_max_bytes
-        geom = pk.KernelGeom.plan(cap, n, spec.lanes)
-        # offset rides as a RUNTIME argument, not a cache-key component: a
-        # round-robin repartition cycles offsets per source batch, and each
-        # distinct key value would retrace the heavyweight pack+Pallas
-        # program (the pids math is shape-stable in offset)
-        # schema is keyed explicitly: spec.plans usually pins it, but the
-        # traced fn zips schema's dtypes against the plans and nothing in
-        # PackSpec's equality promises the field types round-trip (R016)
-        key = ("exchange-fused", part, spec, geom, schema, cap, smax,
-               interpret)
 
-        def build(part=part, spec=spec, geom=geom, schema=schema, cap=cap,
-                  smax=smax, interpret=interpret):
-            inner = pk.reorder_program(spec, geom, cap, interpret)
+        def run(spec, geom):
+            # offset rides as a RUNTIME argument, not a cache-key component:
+            # a round-robin repartition cycles offsets per source batch, and
+            # each distinct key value would retrace the heavyweight
+            # pack+Pallas program (the pids math is shape-stable in offset)
+            # schema is keyed explicitly: spec.plans usually pins it, but
+            # the traced fn zips schema's dtypes against the plans and
+            # nothing in PackSpec's equality promises the field types
+            # round-trip (R016)
+            key = ("exchange-fused", part, spec, geom, schema, cap, smax,
+                   interpret)
 
-            def fn(num_rows, offset_rt, *flat):
-                # rebuild eval-ready columns from _deflate order (f64 data
-                # re-derived from the u64 bits sibling)
-                colvs, i = [], 0
-                for plan, f in zip(spec.plans, schema):
-                    main = flat[i]
-                    validity = flat[i + 1]
-                    i += 2
-                    lengths = None
-                    if plan.kind == "string":
-                        lengths = flat[i]
-                        i += 1
-                    data = (jax.lax.bitcast_convert_type(main, jnp.float64)
-                            if plan.kind == "f64bits" else main)
-                    colvs.append(ColV(f.dtype, data, validity, lengths))
-                ectx = EvalCtx(jnp, colvs, cap, smax)
-                pids = _compute_pids(jnp, part, ectx, cap, offset_rt, None)
-                return inner(num_rows, pids, *flat)
-            return fn
+            def build(part=part, spec=spec, geom=geom, schema=schema,
+                      cap=cap, smax=smax, interpret=interpret):
+                inner = pk.reorder_program(spec, geom, cap, interpret)
 
-        fn = _cached_jit(key, build)
-        out, summary = fn(np.int32(db.num_rows), np.int32(offset),
-                          *pk._deflate(spec, db))
-        return pk.finalize_split(out, summary, spec, geom)
+                def fn(num_rows, offset_rt, *flat):
+                    # rebuild eval-ready columns from _deflate order (f64
+                    # data re-derived from the u64 bits sibling)
+                    colvs, i = [], 0
+                    for plan, f in zip(spec.plans, schema):
+                        main = flat[i]
+                        validity = flat[i + 1]
+                        i += 2
+                        lengths = None
+                        if plan.kind == "string":
+                            lengths = flat[i]
+                            i += 1
+                        data = (jax.lax.bitcast_convert_type(main,
+                                                             jnp.float64)
+                                if plan.kind == "f64bits" else main)
+                        colvs.append(ColV(f.dtype, data, validity, lengths))
+                    ectx = EvalCtx(jnp, colvs, cap, smax)
+                    pids = _compute_pids(jnp, part, ectx, cap, offset_rt,
+                                         None)
+                    return inner(num_rows, pids, *flat)
+                return fn
+
+            return _cached_jit(key, build)(
+                np.int32(db.num_rows), np.int32(offset),
+                *pk._deflate(spec, db))
+
+        return pk.split_widening(db, n, interpret, run)
 
     def _kernel_split(self, ctx, part, db: DeviceBatch, offset: int, n: int):
         """The fused-kernel split: compute pids (same hash/round-robin math

@@ -19,7 +19,7 @@ from spark_rapids_tpu.memory.store import (BufferCatalog, DeviceMemoryStore,
                                            DiskStore, HostMemoryStore,
                                            build_store_chain)
 
-_DEFAULT_HBM_BYTES = 16 << 30  # conservative fallback when stats are absent
+_CPU_BACKEND_HBM_BYTES = 16 << 30  # the CPU backend reports no memory stats
 
 
 class DeviceManager:
@@ -38,16 +38,17 @@ class DeviceManager:
 
     @staticmethod
     def _detect_hbm_bytes() -> int:
-        try:
-            import jax
-            stats = jax.devices()[0].memory_stats()
-            if stats:
-                return int(stats.get("bytes_limit")
-                           or stats.get("bytes_reservable_limit")
-                           or _DEFAULT_HBM_BYTES)
-        except Exception:
-            pass
-        return _DEFAULT_HBM_BYTES
+        import jax
+        dev = jax.devices()[0]
+        stats = dev.memory_stats() or {}
+        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
+        if limit:
+            return int(limit)
+        if dev.platform == "tpu":
+            raise RuntimeError(
+                f"{dev} reports no bytes_limit in memory_stats(); set "
+                f"{cfg.DEVICE_POOL_BYTES.key} to size the device pool")
+        return _CPU_BACKEND_HBM_BYTES
 
     def _derive_device_budget(self, conf: TpuConf) -> int:
         explicit = conf.get(cfg.DEVICE_POOL_BYTES)

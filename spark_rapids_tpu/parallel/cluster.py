@@ -398,6 +398,8 @@ class ProcessExecutor:
         listener.listen(1)
         port = listener.getsockname()[1]
         env = dict(os.environ)
+        # executors are CPU-only today (docs/components.md): an accelerator
+        # belongs to one process at a time and the driver holds it
         env.setdefault("JAX_PLATFORMS", "cpu")
         repo = os.path.dirname(os.path.dirname(
             os.path.dirname(os.path.abspath(__file__))))

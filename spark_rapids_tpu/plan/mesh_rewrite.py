@@ -28,6 +28,7 @@ Lowering rules:
 """
 from __future__ import annotations
 
+import logging
 from typing import Optional
 
 from spark_rapids_tpu import config as cfg
@@ -58,8 +59,14 @@ def mesh_rewrite(plan: PhysicalExec, conf: TpuConf) -> PhysicalExec:
     devs = list(jax.devices())
     if conf.get(cfg.MESH_REQUIRE_ICI):
         devs = pl.largest_ici_group(devs)
-    n = conf.get(cfg.MESH_NUM_DEVICES) or len(devs)
-    n = min(n, len(devs))
+    asked = conf.get(cfg.MESH_NUM_DEVICES)
+    n = min(asked or len(devs), len(devs))
+    if asked > n:
+        logging.getLogger(__name__).warning(
+            "%s=%d but one ICI domain here holds %d device(s); %s",
+            cfg.MESH_NUM_DEVICES.key, asked, len(devs),
+            f"sharding over {n}" if n >= 2
+            else "keeping the single-device plan")
     if n < 2:
         return plan
     mesh = make_mesh(n, devices=devs)

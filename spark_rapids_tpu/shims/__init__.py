@@ -67,33 +67,8 @@ class Jax05PlusShims(JaxShims):
         return (major, minor) >= (0, 5)
 
 
-class Jax04Shims(JaxShims):
-    """The 0.4 line: old-style uint32 PRNG keys were still the safe default
-    and jax.tree.map did not exist before 0.4.25."""
-
-    @staticmethod
-    def version_match(version: str) -> bool:
-        major, minor = _parse(version)
-        return (major, minor) == (0, 4)
-
-    def prng_key(self, seed: int):
-        import jax
-        return jax.random.PRNGKey(seed)
-
-    def tree_map(self, fn, tree):
-        import jax
-        return jax.tree_util.tree_map(fn, tree)
-
-    def shard_map(self, f, mesh, in_specs, out_specs, check_vma=False):
-        """0.4 location (jax.experimental.shard_map) and flag name
-        (check_rep)."""
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=check_vma)
-
-
 #: registration order = match priority (ShimLoader's provider list)
-PROVIDERS: List[type] = [Jax05PlusShims, Jax04Shims]
+PROVIDERS: List[type] = [Jax05PlusShims]
 
 _ACTIVE: Optional[JaxShims] = None
 

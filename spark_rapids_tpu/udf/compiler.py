@@ -7,21 +7,17 @@ traversal, emits Catalyst). Same two-stage strategy here: the compiled output
 is one of OUR expressions, which then rides the normal plan-rewrite path onto
 the TPU — the compiler never generates device code itself.
 
-This interpreter walks CPython 3.10–3.12 bytecode symbolically: the operand
+This interpreter walks CPython 3.12 bytecode symbolically: the operand
 stack holds Expression nodes; a conditional jump forks interpretation down
 both successors and joins them as an If over the two reachable RETURNs
 (loops and anything else unsupported raise UdfCompileError, leaving the UDF
 on the row-wise fallback path — the reference falls back identically when
-its opcode coverage runs out). Pre-3.11 spellings (BINARY_ADD et al.,
-CALL_FUNCTION/CALL_METHOD, JUMP_IF_*_OR_POP, unflagged LOAD_GLOBAL) are
-handled alongside the 3.11+ forms, the same version-drift posture as
-shims/ takes for jax.
+its opcode coverage runs out).
 """
 from __future__ import annotations
 
 import dis
 import math
-import sys
 from typing import Any, Dict, List, Tuple
 
 from spark_rapids_tpu.columnar.dtypes import DType
@@ -75,21 +71,6 @@ _CMPOPS = {
     "==": pr.EqualTo, "!=": pr.NotEqual, "<": pr.LessThan,
     "<=": pr.LessThanOrEqual, ">": pr.GreaterThan, ">=": pr.GreaterThanOrEqual,
 }
-#: CPython <= 3.10 spellings of what 3.11 folded into BINARY_OP
-_LEGACY_BINOPS = {
-    "BINARY_ADD": "+", "BINARY_SUBTRACT": "-", "BINARY_MULTIPLY": "*",
-    "BINARY_TRUE_DIVIDE": "/", "BINARY_FLOOR_DIVIDE": "//",
-    "BINARY_MODULO": "%", "BINARY_POWER": "**", "BINARY_AND": "&",
-    "BINARY_OR": "|", "BINARY_XOR": "^", "BINARY_LSHIFT": "<<",
-    "BINARY_RSHIFT": ">>",
-    "INPLACE_ADD": "+", "INPLACE_SUBTRACT": "-", "INPLACE_MULTIPLY": "*",
-    "INPLACE_TRUE_DIVIDE": "/", "INPLACE_FLOOR_DIVIDE": "//",
-    "INPLACE_MODULO": "%", "INPLACE_POWER": "**", "INPLACE_AND": "&",
-    "INPLACE_OR": "|", "INPLACE_XOR": "^", "INPLACE_LSHIFT": "<<",
-    "INPLACE_RSHIFT": ">>",
-}
-_PY311 = sys.version_info >= (3, 11)
-_PY312 = sys.version_info >= (3, 12)
 #: global functions: name -> (expr class, arity) — arity None = variadic>=2
 _FUNCTIONS = {
     "abs": (ar.Abs, 1), "len": (st.Length, 1), "round": (ma.Rint, None),
@@ -151,7 +132,7 @@ class _State:
         while i < len(instrs):
             ins = instrs[i]
             op = ins.opname
-            if op in ("RESUME", "NOP", "CACHE", "PRECALL"):
+            if op in ("RESUME", "NOP", "CACHE"):
                 i += 1
             elif op == "PUSH_NULL":
                 stack.append(_Null())
@@ -182,18 +163,14 @@ class _State:
             elif op == "RETURN_VALUE":
                 return self._expr(stack.pop())
             elif op == "LOAD_GLOBAL":
-                # the low "push NULL" flag bit exists only on 3.11+; on 3.10
-                # ins.arg is a plain co_names index
-                if _PY311 and ins.arg & 1:
+                if ins.arg & 1:                 # low bit: push NULL first
                     stack.append(_Null())
                 stack.append(self._global(ins.argval))
                 i += 1
-            elif op in ("LOAD_ATTR", "LOAD_METHOD"):
+            elif op == "LOAD_ATTR":
                 obj = stack.pop()
                 name = ins.argval
-                # 3.12 folded LOAD_METHOD into LOAD_ATTR behind arg's low bit
-                methodish = (op == "LOAD_METHOD"
-                             or (_PY312 and bool(ins.arg & 1)))
+                methodish = bool(ins.arg & 1)   # low bit: a method load
                 if isinstance(obj, _Module):
                     target = _Callable(f"{obj.name}.{name}")
                     stack.append(target)
@@ -206,21 +183,13 @@ class _State:
                     raise UdfCompileError(f"attribute {name!r} is not "
                                           f"supported")
                 i += 1
-            elif op == "BINARY_OP" or op in _LEGACY_BINOPS:
-                sym = (_LEGACY_BINOPS[op] if op in _LEGACY_BINOPS
-                       else ins.argrepr.rstrip("="))
-                cls = _BINOPS.get(sym)
+            elif op == "BINARY_OP":
+                cls = _BINOPS.get(ins.argrepr.rstrip("="))
                 if cls is None:
                     raise UdfCompileError(f"operator {ins.argrepr!r} is not "
                                           f"supported")
                 r, l = self._expr(stack.pop()), self._expr(stack.pop())
                 stack.append(cls(l, r))
-                i += 1
-            elif op == "DUP_TOP":
-                stack.append(stack[-1])
-                i += 1
-            elif op == "ROT_TWO":
-                stack[-1], stack[-2] = stack[-2], stack[-1]
                 i += 1
             elif op == "COMPARE_OP":
                 sym = ins.argrepr.replace("bool(", "").rstrip(")")
@@ -262,23 +231,14 @@ class _State:
                 e = nu.IsNull(l)
                 stack.append(pr.Not(e) if ins.arg else e)
                 i += 1
-            elif op.startswith("POP_JUMP_BACKWARD_IF_"):
-                # 3.11 spelling of a loop back-edge
-                raise UdfCompileError("loops are not supported")
             elif op in ("POP_JUMP_IF_FALSE", "POP_JUMP_IF_TRUE",
-                        "POP_JUMP_IF_NONE", "POP_JUMP_IF_NOT_NONE",
-                        # 3.11 spellings; 3.10/3.12 drop the direction
-                        "POP_JUMP_FORWARD_IF_FALSE",
-                        "POP_JUMP_FORWARD_IF_TRUE",
-                        "POP_JUMP_FORWARD_IF_NONE",
-                        "POP_JUMP_FORWARD_IF_NOT_NONE"):
-                kind = op.replace("_FORWARD", "")
+                        "POP_JUMP_IF_NONE", "POP_JUMP_IF_NOT_NONE"):
                 v = self._expr(stack.pop())
-                if kind == "POP_JUMP_IF_NONE":
+                if op == "POP_JUMP_IF_NONE":
                     pred = pr.Not(nu.IsNull(v))       # jump when None
-                elif kind == "POP_JUMP_IF_NOT_NONE":
+                elif op == "POP_JUMP_IF_NOT_NONE":
                     pred = nu.IsNull(v)               # jump when not None
-                elif kind == "POP_JUMP_IF_TRUE":
+                elif op == "POP_JUMP_IF_TRUE":
                     pred = pr.Not(_as_bool(v))
                 else:
                     pred = _as_bool(v)
@@ -289,26 +249,8 @@ class _State:
                 else_e = self.run(self.by_offset[ins.argval], list(stack),
                                   dict(locals_))
                 return _merge_if(pred, then_e, else_e)
-            elif op in ("JUMP_IF_FALSE_OR_POP", "JUMP_IF_TRUE_OR_POP"):
-                # 3.10 spelling of `and`/`or` chains: the short-circuit
-                # branch keeps the tested value on the stack
-                v = self._expr(stack.pop())
-                self.forks += 1
-                if self.forks > _MAX_FORKS:
-                    raise UdfCompileError("too many branches")
-                fall = self.run(i + 1, list(stack), dict(locals_))
-                jump = self.run(self.by_offset[ins.argval],
-                                list(stack) + [v], dict(locals_))
-                if op == "JUMP_IF_FALSE_OR_POP":
-                    return _merge_if(_as_bool(v), fall, jump)
-                return _merge_if(_as_bool(v), jump, fall)
             elif op == "JUMP_FORWARD":
                 i = self.by_offset[ins.argval]
-            elif op == "JUMP_ABSOLUTE":
-                target = self.by_offset[ins.argval]
-                if target <= i:
-                    raise UdfCompileError("loops are not supported")
-                i = target
             elif op == "JUMP_BACKWARD":
                 raise UdfCompileError("loops are not supported")
             elif op == "CALL":
@@ -316,33 +258,6 @@ class _State:
                 call_args = [self._expr(stack.pop()) for _ in range(argc)][::-1]
                 a = stack.pop()
                 b = stack.pop() if stack else _Null()
-                marker, self_obj = None, None
-                for item in (a, b):
-                    if isinstance(item, _Callable):
-                        marker = item
-                    elif isinstance(item, Expression):
-                        self_obj = item
-                if marker is None:
-                    raise UdfCompileError("call target is not a supported "
-                                          "function")
-                stack.append(self._call(marker.name, self_obj, call_args))
-                i += 1
-            elif op == "CALL_FUNCTION":
-                # 3.10: stack is [func, arg0..argN-1]; no NULL slot
-                call_args = [self._expr(stack.pop())
-                             for _ in range(ins.arg)][::-1]
-                target = stack.pop()
-                if not isinstance(target, _Callable):
-                    raise UdfCompileError("call target is not a supported "
-                                          "function")
-                stack.append(self._call(target.name, None, call_args))
-                i += 1
-            elif op == "CALL_METHOD":
-                # 3.10: stack is [method, self_or_null, arg0..argN-1]
-                call_args = [self._expr(stack.pop())
-                             for _ in range(ins.arg)][::-1]
-                a = stack.pop()
-                b = stack.pop()
                 marker, self_obj = None, None
                 for item in (a, b):
                     if isinstance(item, _Callable):

@@ -121,7 +121,6 @@ def test_mesh_tpch_at_32_devices():
     import sys
     script = r"""
 import jax
-jax.config.update("jax_platforms", "cpu")
 from spark_rapids_tpu.api import TpuSession
 from spark_rapids_tpu.benchmarks.tpch_data import gen_all
 from spark_rapids_tpu.benchmarks.tpch_queries import QUERIES

@@ -114,6 +114,29 @@ def test_kernel_overflow_falls_back():
     assert pk.split_batch_kernel(batch, pids, 8, interpret=True) is None
 
 
+def test_kernel_widens_window_bound_for_clustered_keys():
+    """Runs of 200 equal pids put 200 rows of a 512-row window into one
+    partition — over the 128-row segment bound of an 8-way split, with every
+    group total well inside its quota. The kernel must report the window
+    overflow apart from a quota overflow and succeed at the doubled bound
+    instead of sending the batch to the sort."""
+    import jax.numpy as jnp
+    table = _table(4000)
+    batch = DeviceBatch.from_arrow(table, string_max_bytes=16)
+    pids_np = ((np.arange(batch.capacity) // 200) % 8).astype(np.int32)
+    assert pk.KernelGeom.plan(batch.capacity, 8, 1).q_w == 128
+    res = pk.split_batch_kernel(batch, jnp.asarray(pids_np), 8,
+                                interpret=True)
+    assert res is not None, "clustered pids fell back to the sort"
+    out, stats, spec, geom = res
+    assert geom.q_w == 256
+    live = pids_np[:table.num_rows]
+    for j in range(8):
+        got = pk.consolidate(out, stats, j, spec, batch.schema, geom)
+        want = table.filter(pa.array(live == j))
+        assert _rows_key(got.to_arrow()) == _rows_key(want), j
+
+
 def test_uploaded_doubles_carry_bit_siblings():
     batch = DeviceBatch.from_arrow(_table(50), string_max_bytes=16)
     dcol = batch.columns[2]
@@ -151,6 +174,21 @@ def test_exchange_kernel_mode_matches_sort_path():
     out_fast = q(fast).collect()
     out_slow = q(slow).collect()
     assert_tables_equal(out_slow, out_fast, approx_float=1e-9)
+    # the exchange says which path split its batches: the kernel declining
+    # (mode off, non-TPU backend, overflow...) is otherwise invisible
+    assert _split_counts(fast) == (1, 0)
+    assert _split_counts(slow) == (0, 1)
+
+
+def _split_counts(sess):
+    """(kernel, sort) split-batch totals over the last plan's exchanges."""
+    from spark_rapids_tpu.api.dataframe import _iter_execs
+    from spark_rapids_tpu.execs import exchange_execs as xe
+    exchanges = [nd for nd in _iter_execs(sess.last_plan)
+                 if isinstance(nd, xe.TpuShuffleExchangeExec)]
+    assert exchanges
+    return (sum(e.metrics[xe.KERNEL_SPLIT_BATCHES].value for e in exchanges),
+            sum(e.metrics[xe.SORT_SPLIT_BATCHES].value for e in exchanges))
 
 
 def test_fused_program_shared_across_round_robin_offsets():

@@ -13,11 +13,18 @@ def test_shim_loader_picks_provider():
 
 
 def test_shim_provider_selection_logic():
+    assert shims.PROVIDERS == [shims.Jax05PlusShims]    # exactly one
     assert shims.Jax05PlusShims.version_match("0.9.0")
-    assert shims.Jax05PlusShims.version_match("0.5.1")
     assert not shims.Jax05PlusShims.version_match("0.4.30")
-    assert shims.Jax04Shims.version_match("0.4.30")
-    assert not shims.Jax04Shims.version_match("0.5.0")
+
+
+def test_shim_loader_rejects_unserved_jax(monkeypatch):
+    import jax
+    monkeypatch.setattr(shims, "_ACTIVE", None)
+    monkeypatch.setattr(jax, "__version__", "0.4.30")
+    import pytest
+    with pytest.raises(RuntimeError, match="no shim provider"):
+        shims.get()
 
 
 def test_shim_rng_and_mesh_work():
@@ -47,3 +54,44 @@ def test_config_docs_current():
     assert path.read_text() == config.generate_docs(), (
         "docs/configs.md is stale; regenerate with "
         "python -m spark_rapids_tpu.config docs/configs.md")
+
+
+# ---------------------------------------------------------------- device set-up
+_REPO = pathlib.Path(__file__).resolve().parent.parent
+
+
+def _run_py(args, env_overrides):
+    import os
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update({"JAX_PLATFORMS": "cpu", "PYTHONPATH": str(_REPO),
+                **env_overrides})
+    return subprocess.run([sys.executable, *args], env=env, cwd=str(_REPO),
+                          capture_output=True, text=True, timeout=300)
+
+
+_PRINT_CACHE_DIR = ["-c", "import jax, spark_rapids_tpu.device; "
+                          "print(jax.config.jax_compilation_cache_dir)"]
+
+
+def test_compile_cache_env_var_wins(tmp_path):
+    r = _run_py(_PRINT_CACHE_DIR, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == str(tmp_path)
+
+
+def test_compile_cache_defaults_to_checkout():
+    r = _run_py(_PRINT_CACHE_DIR, {})
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == str(_REPO / ".jax_cache")
+
+
+def test_chip_smoke_refuses_cpu_backend():
+    """Without a TPU the smoke fails at the device check: non-zero exit, no
+    data generated, no summary printed."""
+    r = _run_py(["chip_smoke.py"], {})
+    assert r.returncode != 0
+    assert "needs a TPU" in r.stderr
+    assert "data:" not in r.stdout and '"ok"' not in r.stdout
