@@ -6,7 +6,9 @@ Mosaic), a served leg (``QueryServer`` + ``QueryServiceClient`` over TCP
 localhost) and, on four or more devices, a mesh leg. Every answer is compared
 with the CPU engine's on the same tables, outside the timed calls. The first
 failed check ends the process with a non-zero code; without a TPU it fails
-before any data is generated. The last line of stdout is one JSON summary.
+before any data is generated. The second-to-last line of stdout is the
+smoke's summary (``summary: {...}``: legs, walls, programs compiled); the last
+is ``{"ok": true, "device": {"platform", "kind", "count"}}`` and nothing else.
 
 Walls printed here are smoke walls — one cold reading each, almost all of it
 XLA compilation. They are not benchmark metrics. What is left out of the
@@ -53,7 +55,7 @@ def require_tpu():
         raise SystemExit(
             f"chip_smoke: needs a TPU, jax found platform {dev.platform!r}")
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": jax.device_count()}
+              "count": len(jax.devices())}
     versions = {p: importlib.metadata.version(p)
                 for p in ("jax", "jaxlib", "libtpu")}
     runtime = ".so" if native.try_get_lib() is not None else "python fallback"
@@ -312,9 +314,10 @@ def main(argv=None):
         print(f"mesh: not run ({jax.device_count()} device)", flush=True)
         mesh = "not run"
 
-    print(json.dumps({
+    # the smoke's own record, then — last, alone — the line the chip check
+    # reads: exactly {"ok", "device": {"platform", "kind", "count"}}
+    print("summary: " + json.dumps({
         "ok": True,
-        "device": device,
         "platform": device["platform"],
         "device_kind": device["kind"],
         "n_devices": device["count"],
@@ -328,6 +331,7 @@ def main(argv=None):
         "xla_persistent_cache": xla.counts,
         "claim": None,
     }))
+    print(json.dumps({"ok": True, "device": device}), flush=True)
 
 
 if __name__ == "__main__":

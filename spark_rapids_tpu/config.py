@@ -344,8 +344,8 @@ SHUFFLE_KERNEL_MODE = _conf(
     "shuffle.kernel.mode", str, "auto",
     "Map-side partition reorder strategy: 'auto' uses the fused Pallas "
     "kernel (one streaming HBM pass: MXU one-hot spread into quota-padded "
-    "partition pieces, 25+ GB/s/chip measured vs 3.8 GB/s for the variadic "
-    "sort) on real TPU backends and the sort path elsewhere; 'interpret' "
+    "partition pieces; rate not measured on the current machine) on real "
+    "TPU backends and the sort path elsewhere; 'interpret' "
     "forces the kernel in Pallas interpreter mode (tests); 'off' always "
     "uses the sort path. Overflowing quotas or non-packable batches fall "
     "back to the sort path automatically.",

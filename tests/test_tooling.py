@@ -95,3 +95,28 @@ def test_chip_smoke_refuses_cpu_backend():
     assert r.returncode != 0
     assert "needs a TPU" in r.stderr
     assert "data:" not in r.stdout and '"ok"' not in r.stdout
+
+
+def test_chip_smoke_last_line_is_the_result_object(monkeypatch, capsys):
+    """The chip check reads the last stdout line: one JSON object with exactly
+    "ok" and "device" {"platform", "kind", "count"}. The smoke's own summary
+    goes on the line before it. Legs are stubbed; only main's output is run."""
+    import json
+    import sys
+    monkeypatch.syspath_prepend(str(_REPO))
+    import chip_smoke
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    monkeypatch.setattr(chip_smoke, "require_tpu", lambda: device)
+    monkeypatch.setattr(chip_smoke, "collect_leg",
+                        lambda *a: ({3: None}, {"q3": 0.0}))
+    monkeypatch.setattr(chip_smoke, "exchange_leg", lambda *a: {})
+    monkeypatch.setattr(chip_smoke, "served_leg", lambda *a: {})
+    monkeypatch.setattr(chip_smoke, "mesh_leg", lambda *a: {})
+    chip_smoke.main(["--scale", "0.001"])
+    sys.modules.pop("chip_smoke", None)
+    *_, summary, last = capsys.readouterr().out.splitlines()
+    assert json.loads(last) == {"ok": True, "device": device}
+    assert summary.startswith("summary: ")
+    record = json.loads(summary.removeprefix("summary: "))
+    assert record["ok"] is True and record["claim"] is None
+    assert record["mesh"] in ("ok", "not run")
