@@ -778,6 +778,9 @@ class _SiteAnalyzer:
                 if leaf in ("jit", "shard_map", "pjit") and expr.args:
                     from_expr(expr.args[0], depth + 1)
                     return
+                if leaf == "named_jit" and len(expr.args) > 1:
+                    from_expr(expr.args[1], depth + 1)  # (kind, fn)
+                    return
                 factory = (nested.get(leaf) or local_defs.get(leaf)
                            or env.defs.get(leaf))
                 if factory is not None:

@@ -29,8 +29,8 @@ from spark_rapids_tpu.analysis.core import (Finding, Rule, SourceFile,
                                             call_name, register)
 
 #: callables that construct a compiled program when invoked
-_JIT_NAMES = {"jax.jit", "jit", "pjit", "jax.pjit", "jax.shard_map",
-              "shard_map"}
+_JIT_NAMES = {"jax.jit", "jit", "named_jit", "pjit", "jax.pjit",
+              "jax.shard_map", "shard_map"}
 
 
 def is_jit_call(node: ast.Call) -> bool:

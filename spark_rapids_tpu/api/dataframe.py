@@ -528,22 +528,43 @@ class DataFrame:
         return self.session.cache_manager.lookup(self._plan) is not None
 
     # ---- actions -------------------------------------------------------------
+    def _trace_scope(self):
+        """Tracer activation for one action under trace.enabled (nesting
+        counts, so the root span's scope and the action driver's compose),
+        else a no-op."""
+        import contextlib
+        from spark_rapids_tpu import config as _cfg
+        from spark_rapids_tpu.utils import tracing as _tracing
+        if not self.session.conf.get(_cfg.TRACE_ENABLED):
+            return contextlib.nullcontext()
+        _tracing.TRACER.configure(
+            self.session.conf.get(_cfg.TRACE_BUFFER_SPANS))
+        return _tracing.TRACER.activate()
+
     def _executed_plan(self, prepared=None) -> PhysicalExec:
         from spark_rapids_tpu import config as _cfg
-        logical = (prepared if prepared is not None
-                   else self.session.cache_manager.prepare(self._plan))
-        cpu_plan = plan_physical(logical, self.session.conf)
-        overrides = TpuOverrides(self.session.conf)
-        final = overrides.apply(cpu_plan)
-        if self.session.conf.get(_cfg.MESH_ENABLED):
-            from spark_rapids_tpu.plan.mesh_rewrite import mesh_rewrite
-            final = mesh_rewrite(final, self.session.conf)
-        self.session.last_explain = overrides.last_explain
-        self.session.last_plan = final
+        from spark_rapids_tpu.utils import tracing as _tracing
+        with _tracing.span("plan", _tracing.LAYER_PLAN) as sp:
+            logical = (prepared if prepared is not None
+                       else self.session.cache_manager.prepare(self._plan))
+            cpu_plan = plan_physical(logical, self.session.conf)
+            overrides = TpuOverrides(self.session.conf)
+            final = overrides.apply(cpu_plan)
+            if self.session.conf.get(_cfg.MESH_ENABLED):
+                from spark_rapids_tpu.plan.mesh_rewrite import mesh_rewrite
+                final = mesh_rewrite(final, self.session.conf)
+            self.session.last_explain = overrides.last_explain
+            self.session.last_plan = final
+            if sp is not None:
+                execs = list(_iter_execs(final))
+                sp.note(execs=len(execs),
+                        cpu_execs=sum(type(nd).__name__.startswith("Cpu")
+                                      for nd in execs))
         return final
 
     def _run_partitions(self, final: PhysicalExec,
-                        capture_device: bool = False, query=None) -> List:
+                        capture_device: bool = False, query=None,
+                        publish_trace: bool = True) -> List:
         """Execute and collect per-partition results as arrow tables. With
         ``capture_device`` (cache materialization), a single-process plan
         whose root is the download transition instead returns the raw
@@ -586,8 +607,7 @@ class DataFrame:
         # on, per-operator counters land in session.last_metrics
         import contextlib
         from spark_rapids_tpu.utils import tracing as _tracing
-        from spark_rapids_tpu.utils.metrics import (NamedRange,
-                                                    action_depth_scope,
+        from spark_rapids_tpu.utils.metrics import (action_depth_scope,
                                                     adaptive_delta,
                                                     adaptive_snapshot,
                                                     memory_delta,
@@ -599,11 +619,7 @@ class DataFrame:
                                                     transfer_delta,
                                                     transfer_snapshot)
         trace = self.session.conf.get(_cfg.TRACE_ENABLED)
-        if trace:
-            _tracing.TRACER.configure(
-                self.session.conf.get(_cfg.TRACE_BUFFER_SPANS))
-        trace_scope = (_tracing.TRACER.activate() if trace
-                       else contextlib.nullcontext())
+        trace_scope = self._trace_scope()
         transfer_before = transfer_snapshot()
         memory_before = memory_snapshot()
         serving_before = serving_snapshot()
@@ -627,17 +643,21 @@ class DataFrame:
         trace_mark = _tracing.TRACER.mark()
         t_wall = _time.perf_counter()
         t_admit = _time.perf_counter()
-        t_admit_ns = _time.perf_counter_ns()
         try:
+            # the action span (profiler range tpu-sql-action) opens first,
+            # so the admission wait is a child inside it; then the
             # device-admission throttle for the whole task (GpuSemaphore
             # analog), fair-shared by tenant; a cancelled query blocked on
             # admission unwinds here instead of waiting for a permit
-            with dm.semaphore.held(tenant=tenant, cancel_check=cancel), \
-                    NamedRange("tpu-sql-action", trace=trace):
-                _tracing.record("serving.admission_wait", "serving",
-                                t_admit_ns,
-                                _time.perf_counter_ns() - t_admit_ns,
-                                {"tenant": tenant})
+            with contextlib.ExitStack() as action:
+                action.enter_context(_tracing.span(
+                    "action", _tracing.LAYER_ACTION,
+                    profile=_tracing.ACTION_RANGE))
+                with _tracing.span("serving.admission_wait",
+                                   _tracing.LAYER_SERVING,
+                                   {"tenant": tenant} if trace else None):
+                    action.enter_context(dm.semaphore.held(
+                        tenant=tenant, cancel_check=cancel))
                 if query is not None:
                     query.note_admission_wait(_time.perf_counter() - t_admit)
                 if self.session.conf.get(_cfg.ADAPTIVE_ENABLED) and \
@@ -718,7 +738,9 @@ class DataFrame:
                                           cleanups=cleanups, query=query)
                         for b in final.execute(ctx):
                             ctx.check_cancelled()
-                            t = b.to_arrow()
+                            with _tracing.span("download.to_arrow",
+                                               _tracing.LAYER_TRANSFER):
+                                t = b.to_arrow()
                             tables.append(t)
                             if query is not None:
                                 query.emit_batch(t)
@@ -765,19 +787,24 @@ class DataFrame:
                 if query is not None:
                     query.record_exec_metrics(snap)
                 self.session.last_metrics = snap
-            if trace:
-                # the action's span window: kept on the session for
-                # introspection and exported per trace.export.path (the
-                # file is rewritten per action — last-action semantics)
-                records = _tracing.TRACER.since(trace_mark)
-                self.session.last_trace = records
-                export = self.session.conf.get(_cfg.TRACE_EXPORT_PATH)
-                if export:
-                    _tracing.export_chrome(
-                        records, export,
-                        metadata={"action_wall_s": round(
-                            self.session.last_action_wall_s, 6)})
+            if trace and publish_trace:
+                self._publish_trace(trace_mark)
         return tables
+
+    def _publish_trace(self, mark: int) -> None:
+        """The span window since ``mark``: kept on the session for
+        introspection and exported per trace.export.path (the file is
+        rewritten per action — last-action semantics)."""
+        from spark_rapids_tpu import config as _cfg
+        from spark_rapids_tpu.utils import tracing as _tracing
+        records = _tracing.TRACER.since(mark)
+        self.session.last_trace = records
+        export = self.session.conf.get(_cfg.TRACE_EXPORT_PATH)
+        if export:
+            _tracing.export_chrome(
+                records, export,
+                metadata={"action_wall_s": round(
+                    self.session.last_action_wall_s, 6)})
 
     def collect(self) -> pa.Table:
         return self._collect()
@@ -788,13 +815,32 @@ class DataFrame:
         scheduler worker is driving (cancellation checkpoints, fair-share
         tenant, per-query metric snapshot); ``final`` reuses an already-
         planned physical tree."""
-        if final is None:
-            final = self._executed_plan()
-        tables = self._run_partitions(final, query=query)
-        schema = self._plan.schema().to_pa()
-        if not tables:
-            return schema.empty_table()
-        return pa.concat_tables(tables)
+        import contextlib
+        from spark_rapids_tpu import config as _cfg
+        from spark_rapids_tpu.utils import tracing as _tracing
+        trace_mark = _tracing.TRACER.mark()
+        try:
+            with contextlib.ExitStack() as scopes:
+                if query is None:
+                    # embedded: the query's span tree opens here (a served
+                    # query's root is the scheduler worker's)
+                    scopes.enter_context(self._trace_scope())
+                    scopes.enter_context(_tracing.span(
+                        "query", _tracing.LAYER_QUERY, profile=False))
+                if final is None:
+                    final = self._executed_plan()
+                tables = self._run_partitions(final, query=query,
+                                              publish_trace=False)
+                schema = self._plan.schema().to_pa()
+                if not tables:
+                    return schema.empty_table()
+                with _tracing.span("result.concat",
+                                   _tracing.LAYER_TRANSFER):
+                    return pa.concat_tables(tables)
+        finally:
+            # after the root has closed, so the window holds the whole tree
+            if self.session.conf.get(_cfg.TRACE_ENABLED):
+                self._publish_trace(trace_mark)
 
     def to_pandas(self):
         return self.collect().to_pandas()

@@ -99,91 +99,88 @@ def upload_table(table: pa.Table,
     ``DeviceBatch.from_arrow`` path. ``stats``, when given, is filled with the
     per-chunk timing breakdown bench.py publishes (per_chunk_upload_s,
     stage_s, upload_overlap_efficiency, inflight_high_water).
+
+    Spans: ``transfer.upload`` over the whole call, and under it
+    ``upload.stage`` per chunk, ``upload.wait`` per bounded wait and
+    ``upload.assemble``; they take the plan id of the exec that uploads.
     """
     m = um.TRANSFER_METRICS
     t_start = time.perf_counter()
-    t_start_ns = time.perf_counter_ns()
     bounds = chunk_bounds(table, chunk_rows)
-    if len(bounds) < 2:
-        # args dicts build only when tracing is live — the per-upload
-        # disabled cost stays one bool read (the <2% nightly bound)
-        span = (_tracing.span("transfer.upload", "transfer",
-                              {"rows": table.num_rows, "chunks": 1})
-                if _tracing.TRACER.on else _tracing._NULL_SPAN)
-        with span:
-            batch = DeviceBatch.from_arrow(table, string_max_bytes,
-                                           device=device,
-                                           with_bits=with_bits)
-        if stats is not None:
-            # bench instrumentation wants the honest transfer wall; the
-            # engine path must NOT sync — the async device_put overlapping
-            # the consumer's work is the whole point on serial paths
-            _wait_uploaded(batch)
-        wall = time.perf_counter() - t_start
-        m[um.TRANSFER_UPLOAD_BYTES].add(batch.device_size_bytes)
-        m[um.TRANSFER_UPLOAD_SECONDS].add(wall)
-        m[um.TRANSFER_UPLOAD_CHUNKS].add(1)
-        m[um.TRANSFER_INFLIGHT_PEAK].set_max(1)
-        if stats is not None:
-            stats.update(chunks=1, wall_s=wall, stage_s=wall,
-                         per_chunk_upload_s=[round(wall, 4)],
-                         upload_overlap_efficiency=0.0,
-                         inflight_high_water=1)
-        return batch
-
     n = table.num_rows
     ends = bounds[1:] + [n]
     chunks: List[DeviceBatch] = []
     inflight: List[DeviceBatch] = []
     per_chunk: List[float] = []
-    stage_total = 0.0
+    stage_total = wait_total = 0.0
     peak = 0
-    for start, end in zip(bounds, ends):
-        t0 = time.perf_counter()
-        # staging (numpy work) for THIS chunk happens while the previous
-        # chunks' device_puts are still in flight — that's the overlap.
-        # bucketed chunks: similar-sized chunks share one power-of-two
-        # capacity, so the slice/concat programs of the assembly below hit
-        # XLA's compile cache across tables instead of compiling per exact
-        # chunk-size tuple (padding is built ON DEVICE — no link bytes)
-        # (span timestamps are the staging call boundaries that already
-        # exist — the async device_put is NOT awaited, per R002; the args
-        # dict builds only when tracing is live)
-        span = (_tracing.span("transfer.upload_chunk", "transfer",
-                              {"rows": end - start, "offset": start,
-                               "inflight": len(inflight)})
-                if _tracing.TRACER.on else _tracing._NULL_SPAN)
-        with span:
-            b = DeviceBatch.from_arrow(table.slice(start, end - start),
-                                       string_max_bytes, device=device,
-                                       with_bits=with_bits)
-        t1 = time.perf_counter()
-        stage_total += t1 - t0
-        per_chunk.append(round(t1 - t0, 4))
-        chunks.append(b)
-        inflight.append(b)
-        peak = max(peak, len(inflight))
-        while len(inflight) >= max_inflight:
-            _wait_uploaded(inflight.pop(0))   # bounded: block on the OLDEST
-    # device-side assembly: slice + concat + one capacity pad, the same
-    # cached-program shape every coalesce uses (concat_device_batches).
-    # No trailing sync: the assembly is enqueued behind the in-flight
-    # transfers and the caller's first use of the result awaits it.
-    from spark_rapids_tpu.execs.tpu_execs import concat_device_batches
-    out = concat_device_batches(chunks, chunks[0].schema, string_max_bytes)
+    # args dicts build only when tracing is live — the per-upload and
+    # per-chunk disabled cost stays one bool read (the <2% nightly bound)
+    with _tracing.span("transfer.upload", _tracing.LAYER_TRANSFER,
+                       {"rows": n, "chunks": len(bounds)}
+                       if _tracing.TRACER.on else None) as upload:
+        for start, end in zip(bounds, ends):
+            t0 = time.perf_counter()
+            # staging (numpy work) for THIS chunk happens while the
+            # previous chunks' device_puts are still in flight — that's
+            # the overlap. Bucketed chunks: similar-sized chunks share one
+            # power-of-two capacity, so the slice/concat programs of the
+            # assembly below hit XLA's compile cache across tables instead
+            # of compiling per exact chunk-size tuple (padding is built ON
+            # DEVICE — no link bytes). The span's two ends are the staging
+            # call's, which already exist — the async device_put is NOT
+            # awaited, per R002.
+            with _tracing.span("upload.stage", _tracing.LAYER_TRANSFER,
+                               {"rows": end - start, "offset": start,
+                                "inflight": len(inflight)}
+                               if _tracing.TRACER.on else None) as stage:
+                b = DeviceBatch.from_arrow(
+                    table.slice(start, end - start) if len(bounds) > 1
+                    else table,
+                    string_max_bytes, device=device, with_bits=with_bits)
+                if stage is not None:
+                    stage.note(bytes=b.device_size_bytes)
+            t1 = time.perf_counter()
+            stage_total += t1 - t0
+            per_chunk.append(round(t1 - t0, 4))
+            chunks.append(b)
+            inflight.append(b)
+            peak = max(peak, len(inflight))
+            # bounded: block on the OLDEST. A single-shot upload waits for
+            # nothing — the async device_put overlapping the consumer's
+            # work is the whole point on serial paths
+            while len(bounds) > 1 and len(inflight) >= max_inflight:
+                t0 = time.perf_counter()
+                with _tracing.span("upload.wait", _tracing.LAYER_TRANSFER):
+                    _wait_uploaded(inflight.pop(0))
+                wait_total += time.perf_counter() - t0
+        if len(chunks) > 1:
+            # device-side assembly: slice + concat + one capacity pad, the
+            # same cached-program shape every coalesce uses. No trailing
+            # sync: the assembly is enqueued behind the in-flight
+            # transfers and the caller's first use of the result awaits it.
+            from spark_rapids_tpu.execs.tpu_execs import \
+                concat_device_batches
+            with _tracing.span("upload.assemble", _tracing.LAYER_TRANSFER):
+                out = concat_device_batches(chunks, chunks[0].schema,
+                                            string_max_bytes)
+        else:
+            out = chunks[0]
+        if upload is not None:
+            upload.note(inflight_peak=peak, bytes=out.device_size_bytes)
     if stats is not None:
-        _wait_uploaded(out)     # bench: honest wall including assembly
+        # bench instrumentation wants the honest transfer wall including
+        # the assembly; the engine path must NOT sync
+        _wait_uploaded(out)
     wall = time.perf_counter() - t_start
     m[um.TRANSFER_UPLOAD_BYTES].add(out.device_size_bytes)
-    m[um.TRANSFER_UPLOAD_SECONDS].add(wall)
+    # the host's time in the upload's two blocking parts, staging and the
+    # bounded waits: what the link rate of session.last_metrics divides by.
+    # The dispatch of the assembly and what the device does after the last
+    # wait are not in it.
+    m[um.TRANSFER_UPLOAD_SECONDS].add(stage_total + wait_total)
     m[um.TRANSFER_UPLOAD_CHUNKS].add(len(chunks))
     m[um.TRANSFER_INFLIGHT_PEAK].set_max(peak)
-    if _tracing.TRACER.on:
-        _tracing.record("transfer.upload", "transfer", t_start_ns,
-                        time.perf_counter_ns() - t_start_ns,
-                        {"rows": n, "chunks": len(chunks),
-                         "inflight_peak": peak,
-                         "bytes": out.device_size_bytes})
     if stats is not None:
         # fraction of the upload wall covered by productive host staging:
         # 1.0 = every transfer fully hidden behind staging; a serial
@@ -238,7 +235,8 @@ class PendingDownload:
 
     def result(self) -> pa.Table:
         t0 = time.perf_counter()
-        fetched = jax.device_get(self._sliced)
+        with _tracing.span("download.wait", _tracing.LAYER_TRANSFER):
+            fetched = jax.device_get(self._sliced)
         self._sliced = fetched      # idempotent: device_get of host arrays
         dt = time.perf_counter() - t0
         m = um.TRANSFER_METRICS
@@ -248,12 +246,12 @@ class PendingDownload:
         # shows riding under the remaining compute (streaming collect).
         # Per-batch path: the args dict builds only when tracing is live.
         if _tracing.TRACER.on:
-            _tracing.record("transfer.download", "transfer",
+            _tracing.record("transfer.download", _tracing.LAYER_TRANSFER,
                             self._t_dispatch_ns,
                             time.perf_counter_ns() - self._t_dispatch_ns,
-                            {"bytes": self.nbytes, "rows": self._num_rows,
-                             "resolve_ms": round(dt * 1e3, 3)})
-        return fetched_to_arrow(self._schema, fetched, self._num_rows)
+                            {"bytes": self.nbytes, "rows": self._num_rows})
+        with _tracing.span("download.to_arrow", _tracing.LAYER_TRANSFER):
+            return fetched_to_arrow(self._schema, fetched, self._num_rows)
 
 
 def start_download(batch: DeviceBatch) -> PendingDownload:

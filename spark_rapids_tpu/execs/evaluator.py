@@ -79,7 +79,8 @@ def eval_exprs_host(exprs: Sequence[Expression], batch: HostBatch,
 
 
 # ------------------------------------------------------------------ TPU (jitted)
-from spark_rapids_tpu.serving.program_cache import global_program_cache
+from spark_rapids_tpu.serving.program_cache import (global_program_cache,
+                                                    named_jit)
 
 _PROGRAM_CACHE = global_program_cache()
 #: legacy alias for the serving cache's program table (cleared by conftest
@@ -134,8 +135,8 @@ def eval_exprs_device(exprs: Sequence[Expression], batch: DeviceBatch,
     attrs = tuple(sorted((ctx_attrs or {}).items()))
     key = (exprs, batch.schema, batch.capacity, string_max_bytes, attrs)
     fn = _PROGRAM_CACHE.get_or_build(
-        key, lambda: jax.jit(_trace_fn(exprs, batch.schema, batch.capacity,
-                                       string_max_bytes, attrs)))
+        key, lambda: named_jit("project", _trace_fn(
+            exprs, batch.schema, batch.capacity, string_max_bytes, attrs)))
     flat_out = fn(*_flatten_batch(batch))
     out_schema = output_schema(exprs)
     cols = []

@@ -42,7 +42,6 @@ from typing import Iterator, Optional, Tuple
 import numpy as np
 
 from spark_rapids_tpu import device as _device  # noqa: F401 - jax setup
-import jax
 import jax.numpy as jnp
 
 from spark_rapids_tpu.columnar.batch import DeviceBatch
@@ -53,6 +52,7 @@ from spark_rapids_tpu.execs.evaluator import colv_to_column
 from spark_rapids_tpu.exprs.core import (ColV, EvalCtx, Expression, flat_len,
                                          flatten_colvs, unflatten_colvs)
 from spark_rapids_tpu.ops import batch_kernels as bk
+from spark_rapids_tpu.serving.program_cache import named_jit
 
 #: per-stage metric: operators collapsed into this stage
 FUSED_OPS = "fusedOps"
@@ -211,7 +211,7 @@ class FusedStageExec(PhysicalExec):
                     outs.extend(flatten_colvs(ovals))
                     outs.append(n)
                 return tuple(outs)
-            return jax.jit(fn)
+            return named_jit("stage", fn)
 
         source = self.children[0].execute(ctx)
         if self.coalesce is not None:

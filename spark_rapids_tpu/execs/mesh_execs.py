@@ -37,7 +37,8 @@ from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.columnar.dtypes import DType, Schema, bucket_capacity
 from spark_rapids_tpu.execs.base import ExecContext, PhysicalExec
 from spark_rapids_tpu.execs.evaluator import colv_to_column, output_schema
-from spark_rapids_tpu.execs.tpu_execs import _cached_jit
+from spark_rapids_tpu.execs.tpu_execs import _PROGRAM_CACHE
+from spark_rapids_tpu.serving.program_cache import named_jit
 from spark_rapids_tpu.exprs.core import (ColV, EvalCtx, Expression, flat_len,
                                          flatten_colvs, unflatten_colvs)
 from spark_rapids_tpu.exprs.misc import Alias, SortOrder
@@ -72,8 +73,10 @@ def _shard_jit(mesh: Mesh, key: Tuple, builder, in_specs, out_specs):
     def make(shim=shim):
         return shim.shard_map(builder(), mesh=mesh, in_specs=in_specs,
                               out_specs=out_specs, check_vma=False)
-    return _cached_jit(
-        ("mesh", type(shim).__name__, mesh, key, in_specs, out_specs), make)
+    # named by the operator's own key, not by the "mesh" that wraps it
+    return _PROGRAM_CACHE.get_or_build(
+        ("mesh", type(shim).__name__, mesh, key, in_specs, out_specs),
+        lambda: named_jit(key[0], make()))
 
 
 def _specs(n: int, spec=P(DATA_AXIS)) -> Tuple:

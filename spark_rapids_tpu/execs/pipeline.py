@@ -28,6 +28,7 @@ from contextlib import nullcontext
 from typing import Iterator
 
 from spark_rapids_tpu.execs.base import ExecContext, PhysicalExec
+from spark_rapids_tpu.utils import tracing as _tracing
 
 #: metric: high-water mark of queued batches at a pipeline boundary
 PIPELINE_INFLIGHT_PEAK = "pipelineInflightPeak"
@@ -91,7 +92,7 @@ class PipelinedExec(PhysicalExec):
                 # uploads/compiles, and cancellation stops the producer at
                 # its next batch instead of filling the queue for a dead
                 # consumer
-                with bind_query(query), hold:
+                with bind_query(query), _tracing.adopt(spawning_span), hold:
                     for b in src:
                         ctx.check_cancelled()
                         peak.set_max(q.qsize() + 1)
@@ -106,6 +107,8 @@ class PipelinedExec(PhysicalExec):
                     close()     # run the child generator's cleanup
             _put_abortable(q, ("end", None), stop)
 
+        # the producer's spans are children of the span open here
+        spawning_span = _tracing.current() if _tracing.TRACER.on else None
         worker = threading.Thread(target=produce, daemon=True,
                                   name="exec-pipeline")
         worker.start()

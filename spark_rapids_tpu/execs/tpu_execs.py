@@ -32,7 +32,8 @@ from spark_rapids_tpu.exprs.misc import Alias, SortOrder
 from spark_rapids_tpu.ops import batch_kernels as bk
 from spark_rapids_tpu.ops.aggregate import group_aggregate
 
-from spark_rapids_tpu.serving.program_cache import global_program_cache
+from spark_rapids_tpu.serving.program_cache import (global_program_cache,
+                                                    named_jit)
 
 _PROGRAM_CACHE = global_program_cache()
 #: legacy alias for the serving cache's program table: tests introspect its
@@ -79,8 +80,10 @@ def _cached_jit(key, builder):
     operator config + schema (dtype signature) + capacity bucket, so any
     query hitting the same plan shape reuses the program (serving/
     program_cache.py: hit/miss/disk-warm accounting, in-flight build
-    latch, LRU bound, per-query attribution)."""
-    return _PROGRAM_CACHE.get_or_build(key, lambda: jax.jit(builder()))
+    latch, LRU bound, per-query attribution). The program is named by the
+    key's leading string, its kind (``named_jit``)."""
+    return _PROGRAM_CACHE.get_or_build(
+        key, lambda: named_jit(key[0], builder()))
 
 
 def concat_device_batches(batches: List[DeviceBatch], schema: Schema,

@@ -24,6 +24,7 @@ from spark_rapids_tpu.columnar.dtypes import DType, Schema
 from spark_rapids_tpu.columnar.host import HostBatch
 from spark_rapids_tpu.execs.base import ExecContext, LeafExec
 from spark_rapids_tpu.exprs.core import Expression
+from spark_rapids_tpu.utils import tracing as _tracing
 from spark_rapids_tpu.io.datasource import (ColumnStats, PartitionedFile,
                                             append_partition_columns,
                                             assigned_files, evolve_schema,
@@ -423,7 +424,7 @@ class TpuParquetScanExec(_ParquetScanBase):
             # query id, so per-query trace exports include the prefetched
             # scan's transfer spans
             from spark_rapids_tpu.serving.lifecycle import bind_query
-            with bind_query(ctx.query):
+            with bind_query(ctx.query), _tracing.adopt(spawning_span):
                 try:
                     for t in self._iter_arrow(ctx):
                         # staging + device_put happen HERE, ahead of the
@@ -440,6 +441,7 @@ class TpuParquetScanExec(_ParquetScanBase):
                     return
                 _put_abortable(q, ("end", None), stop)
 
+        spawning_span = _tracing.current() if _tracing.TRACER.on else None
         worker = threading.Thread(target=produce, daemon=True,
                                   name="parquet-scan-prefetch")
         worker.start()

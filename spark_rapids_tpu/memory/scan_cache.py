@@ -18,6 +18,8 @@ import weakref
 from collections import OrderedDict
 from typing import Optional, Tuple
 
+from spark_rapids_tpu.utils import tracing as _tracing
+
 
 class DeviceScanCache:
     """LRU over (table identity, string width) -> DeviceBatch.
@@ -55,29 +57,43 @@ class DeviceScanCache:
             mine = False
             with self._lock:
                 got = self._get_locked(table, smax)
-                if got is not None:
-                    return got
-                ev = self._inflight.get(key)
-                if ev is None:
-                    ev = threading.Event()
-                    # released in the mine-branch finally below: the store
-                    # and the release correlate through `mine` (set True in
-                    # this branch only), one hop beyond what path-
-                    # insensitive dataflow can prove
-                    self._inflight[key] = ev  # tpu-lint: disable=R008
-                    mine = True
+                if got is None:
+                    ev = self._inflight.get(key)
+                    if ev is None:
+                        ev = threading.Event()
+                        # released in the mine-branch finally below: the
+                        # store and the release correlate through `mine`
+                        # (set True in this branch only), one hop beyond
+                        # what path-insensitive dataflow can prove
+                        self._inflight[key] = ev  # tpu-lint: disable=R008
+                        mine = True
+            if got is not None:
+                if _tracing.TRACER.on:
+                    _tracing.instant("scan_cache.hit",
+                                     _tracing.LAYER_TRANSFER,
+                                     {"bytes": got.device_size_bytes})
+                return got
             if mine:
                 try:
                     batch = builder()
-                    self.put(table, smax, batch)
+                    kept = self.put(table, smax, batch)
+                    if _tracing.TRACER.on:
+                        # not_kept: built, but over the budget — the next
+                        # query (and any waiter on this latch) uploads again
+                        _tracing.instant(
+                            "scan_cache.miss" if kept
+                            else "scan_cache.not_kept",
+                            _tracing.LAYER_TRANSFER,
+                            {"bytes": batch.device_size_bytes})
                     return batch
                 finally:
                     with self._lock:
                         self._inflight.pop(key, None)
                     ev.set()
-            while not ev.wait(0.05):
-                if cancel_check is not None:
-                    cancel_check()
+            with _tracing.span("scan_cache.wait", _tracing.LAYER_TRANSFER):
+                while not ev.wait(0.05):
+                    if cancel_check is not None:
+                        cancel_check()
 
     def _get_locked(self, table, smax: int):
         key = (id(table), smax)
@@ -95,17 +111,20 @@ class DeviceScanCache:
         with self._lock:
             return self._get_locked(table, smax)
 
-    def put(self, table, smax: int, batch) -> None:
+    def put(self, table, smax: int, batch) -> bool:
+        """Insert; False when the batch was not kept (over the whole
+        budget, or the table cannot be weakly referenced)."""
         try:
             ref = weakref.ref(table)
         except TypeError:  # object not weakref-able: skip caching
-            return
+            return False
         nbytes = batch.device_size_bytes
         if nbytes > self.max_bytes:
-            return
+            return False
         with self._lock:
             self._entries[(id(table), smax)] = (ref, batch, nbytes)
             self._evict_locked()
+        return True
 
     def _evict(self) -> None:
         with self._lock:

@@ -99,6 +99,12 @@ class QuotaExceededError(RuntimeError):
 _QUERY_IDS = itertools.count(1)
 
 
+def next_query_id() -> int:
+    """The next query id: a QueryHandle's, or the one a traced embedded
+    action takes for its span tree (utils/tracing.py) — one id space."""
+    return next(_QUERY_IDS)
+
+
 class ResultStream:
     """Bounded FIFO of streamed result batches between the scheduler
     worker (producer: ``QueryHandle.emit_batch``) and a consumer — the
@@ -203,7 +209,7 @@ class QueryHandle:
                  timeout: Optional[float] = None,
                  label: Optional[str] = None,
                  stream: Optional[ResultStream] = None):
-        self.query_id = next(_QUERY_IDS)
+        self.query_id = next_query_id()
         self.tenant = tenant
         self.label = label or f"query-{self.query_id}"
         #: optional streaming sink: each result batch is pushed here as its
@@ -393,8 +399,16 @@ class QueryHandle:
 
     def mark_admitted(self) -> None:
         self._transition(QueryState.ADMITTED)
-        self.note_metric("queue_wait_s", round(
-            time.perf_counter() - self.submitted_at, 6))
+        first = self.metric("queue_wait_s") is None
+        waited = time.perf_counter() - self.submitted_at
+        self.note_metric("queue_wait_s", round(waited, 6))
+        if first and _tracing.TRACER.on:
+            # submission -> first pickup, a window only known now; a
+            # footprint requeue's further waits are admission waits
+            _tracing.record("serving.queue_wait", _tracing.LAYER_SERVING,
+                            int(self.submitted_at * 1e9),
+                            int(waited * 1e9), {"tenant": self.tenant},
+                            query_id=self.query_id)
 
     def mark_running(self) -> None:
         self._transition(QueryState.RUNNING)

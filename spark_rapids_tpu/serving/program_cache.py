@@ -39,8 +39,21 @@ from collections import OrderedDict
 from typing import Any, Callable, Dict, Optional, Tuple
 
 from spark_rapids_tpu.serving.lifecycle import current_query
+from spark_rapids_tpu.utils import tracing as _tracing
 
 _INDEX_FILENAME = "serving-program-index.json"
+
+
+def named_jit(kind: str, fn: Callable, **jit_kwargs):
+    """``jax.jit(fn)`` under the program kind's name — the leading string
+    of the cache key (``filter``, ``agg``, ``join_gather``, ...; ``-``
+    becomes ``_``). The XLA module is then ``jit_<kind>``, which a device
+    trace shows for every operation of the program, and ``_Program`` names
+    its ``program.<kind>`` spans by it. A kind says which operator, never
+    which fingerprint, schema or capacity: a few dozen names in all."""
+    import jax
+    fn.__name__ = fn.__qualname__ = kind.replace("-", "_")
+    return jax.jit(fn, **jit_kwargs)
 
 
 def stable_key_hash(key: Any) -> str:
@@ -56,15 +69,26 @@ class _Program:
     that call and attributes it to the triggering query's ``compile_s``
     (an upper bound: it includes the first execution)."""
 
-    __slots__ = ("fn", "_cache", "_first_pending", "_lock")
+    __slots__ = ("fn", "kind", "_cache", "_first_pending", "_lock")
 
     def __init__(self, fn: Callable, cache: "ProgramCache"):
         self.fn = fn
+        #: the name ``named_jit`` gave the program
+        self.kind = getattr(fn, "__name__", "fn")
         self._cache = cache
         self._first_pending = True
         self._lock = threading.Lock()
 
     def __call__(self, *args, **kwargs):
+        if not _tracing.TRACER.on:
+            return self._call(*args, **kwargs)
+        # the dispatch (and, first, the compile or cache load): which
+        # program ran, and which compiled, in a window
+        with _tracing.span("program." + self.kind, _tracing.LAYER_PROGRAM,
+                           {"first": self._first_pending}):
+            return self._call(*args, **kwargs)
+
+    def _call(self, *args, **kwargs):
         if not self._first_pending:
             return self.fn(*args, **kwargs)
         t0 = time.perf_counter()

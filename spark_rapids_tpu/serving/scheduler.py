@@ -36,6 +36,7 @@ from spark_rapids_tpu.serving.lifecycle import (OverloadedError,
                                                 bind_query)
 from spark_rapids_tpu.serving.program_cache import (configure_from_conf,
                                                     plan_key)
+from spark_rapids_tpu.utils import tracing as _tracing
 from spark_rapids_tpu.utils.errors import triage_boundary, wire_boundary
 from spark_rapids_tpu.utils.fair_share import (activation_reset, pick_tenant,
                                                weight_of)
@@ -301,14 +302,20 @@ class SessionScheduler:
 
     def _run_handle(self, handle: QueryHandle) -> None:
         import contextlib
-        from spark_rapids_tpu.utils import tracing as _tracing
         # trace the WHOLE handle run (lifecycle transitions, planning,
         # admission) — the action driver's own activation nests inside
         trace_scope = (_tracing.TRACER.activate()
                        if self.session.conf.get(cfg.TRACE_ENABLED)
                        else contextlib.nullcontext())
         try:
-            with trace_scope:
+            # the query's span tree: its root runs from submission to the
+            # terminal state, so the queue wait is a child inside it (a
+            # handle picked up again after a footprint requeue opens a
+            # further root under the same id)
+            with trace_scope, _tracing.span(
+                    "query", _tracing.LAYER_QUERY,
+                    query_id=handle.query_id, profile=False,
+                    t0_ns=int(handle.submitted_at * 1e9)):
                 self._run_handle_traced(handle)
         finally:
             # EVERY terminal path — completion, failure, queued-cancel —
@@ -345,7 +352,10 @@ class SessionScheduler:
             with bind_query(handle):
                 handle.check_cancelled()
                 if handle._planned is None:
-                    df = self._as_dataframe(handle._work)
+                    # SQL text: parse + analysis is planning too (a
+                    # sibling of the plan span _executed_plan records)
+                    with _tracing.span("plan", _tracing.LAYER_PLAN):
+                        df = self._as_dataframe(handle._work)
                     final = df._executed_plan()
                     handle.note_metric("plan_key",
                                        plan_key(final, self.session.conf))

@@ -50,6 +50,7 @@ from jax.experimental.pallas import tpu as pltpu
 from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.columnar.dtypes import DType, Schema, bucket_capacity
+from spark_rapids_tpu.serving.program_cache import named_jit
 
 W = 512                    #: window rows (one spread dot per window)
 GROUP_WINDOWS = 64         #: windows per group (one output piece set each)
@@ -467,7 +468,7 @@ def reorder_program(spec: PackSpec, geom: KernelGeom, cap: int,
              jnp.max(stats[:, :, 1])[None], jnp.max(stats[:, :, 2])[None]])
         return out, summary
 
-    fn = jax.jit(fn)
+    fn = named_jit(key[0], fn)
     _PROGRAMS[key] = fn
     return fn
 
@@ -593,7 +594,8 @@ def consolidate_all(out, stats_host: np.ndarray, spec: PackSpec,
     key = ("pdma", spec, geom, ri_cap, dst_rows)
     fn = _PROGRAMS.get(key)
     if fn is None:
-        fn = jax.jit(_build_dma_compact(spec, geom, ri_cap, dst_rows))
+        fn = named_jit(key[0], _build_dma_compact(spec, geom, ri_cap,
+                                                  dst_rows))
         _PROGRAMS[key] = fn
     compact = fn(jnp.asarray(prefix8), jnp.asarray(nb8),
                  jnp.asarray(ridx), out)
@@ -616,7 +618,7 @@ def consolidate_all(out, stats_host: np.ndarray, spec: PackSpec,
                     return _flatten_unpacked(
                         unpack_columns(spec, schema, compact_j[:bucket]))
                 return f
-            ufn = jax.jit(build())
+            ufn = named_jit(ukey[0], build())
             _PROGRAMS[ukey] = ufn
         batches.append(_res_to_batch(spec, schema, ufn(compact[j]), total))
     return batches
@@ -788,7 +790,7 @@ def consolidate(out, stats_host: np.ndarray, j: int, spec: PackSpec,
                 # lane extraction has been seen to corrupt lanes
                 mat = jax.lax.optimization_barrier(mat)
                 return _flatten_unpacked(unpack_columns(spec, schema, mat))
-            return jax.jit(f)
+            return named_jit(key[0], f)
         fn = build()
         _PROGRAMS[key] = fn
 

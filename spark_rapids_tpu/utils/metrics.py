@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import contextlib as _contextlib
 import threading
-import time
 from typing import Dict, Optional
 
 # Standard metric names (GpuMetricNames analog, GpuExec.scala:28-52)
@@ -451,35 +450,3 @@ def transfer_delta(before: Dict[str, float]) -> Dict[str, float]:
     out["transfer.compression_ratio"] = (
         round(out[TRANSFER_ENCODED_BYTES] / dec, 4) if dec > 0 else 1.0)
     return out
-
-
-class NamedRange:
-    """Timed, profiler-visible range tied to a metric (NvtxWithMetrics analog).
-
-    Adds elapsed nanoseconds to ``metric`` on exit and, when tracing is enabled,
-    shows up as a named range in the XLA/TensorBoard profile.
-    """
-
-    def __init__(self, name: str, metric: Optional[Metric] = None, trace: bool = False):
-        self._name = name
-        self._metric = metric
-        self._trace = trace
-        self._ctx = None
-        self._t0 = 0
-
-    def __enter__(self) -> "NamedRange":
-        if self._trace:
-            try:
-                import jax.profiler
-                self._ctx = jax.profiler.TraceAnnotation(self._name)
-                self._ctx.__enter__()
-            except Exception:
-                self._ctx = None
-        self._t0 = time.perf_counter_ns()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        if self._metric is not None:
-            self._metric.add(time.perf_counter_ns() - self._t0)
-        if self._ctx is not None:
-            self._ctx.__exit__(*exc)

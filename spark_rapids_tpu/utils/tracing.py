@@ -24,6 +24,19 @@ Design constraints (the R002 contract):
   already exist — exec ``__next__`` calls, chunk staging returns, async
   D2H resolution, admission wakeups. No new device syncs anywhere: a span
   never calls ``block_until_ready``/``np.asarray`` on device data.
+- one span tree per query: every record carries ``span_id`` and the
+  ``parent_id`` of the span open on the recording thread (threads the
+  program starts for a query ``adopt()`` the spawning span), and the id
+  of its query — the bound ``QueryHandle.query_id`` when served, else an
+  ordinal from the same counter taken when the root opens.
+- one clock: a live ``span()`` also enters a ``jax.profiler``
+  ``TraceAnnotation`` named ``<name>#<plan_id>``, so the ring and a
+  profile hold the same spans and the profile's are on the device
+  trace's clock. Windows between asynchronous boundaries that are only
+  known afterwards (``record()`` with explicit timestamps:
+  ``transfer.download``, ``shuffle.fetch``, ``serving.queue_wait``,
+  ``serving.preempt_yield``), instants and the ``query`` root are in
+  the ring alone.
 - disabled mode is near-zero-cost: every hook is gated on one module-bool
   read (``enabled()``); ``span()`` returns a shared no-op context manager
   without allocating. The disabled overhead is microbenchmarked in
@@ -34,36 +47,58 @@ Design constraints (the R002 contract):
   filtering uses the span's query id (bound thread-locally by the serving
   worker via ``serving.lifecycle.bind_query``).
 
-Span layers (``cat``): ``exec`` (operator execute boundaries), ``transfer``
-(chunk upload / async download), ``shuffle`` (fetch / retry), ``memory``
-(grace partition / spill), ``serving`` (lifecycle transitions, admission
-and preemption waits, wire frames).
+Span layers (``cat``): ``query`` (the root), ``plan`` (plan + rewrite),
+``action`` (the device-admitted run), ``exec`` (operator execute
+boundaries), ``program`` (calls of cached XLA programs), ``transfer``
+(upload stage / wait / assemble, scan-cache waits, downloads), ``shuffle``
+(fetch / retry), ``memory`` (grace partition / spill), ``serving``
+(lifecycle transitions, queue, admission and preemption waits, wire
+frames). docs/observability.md has the table of names.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
 
 #: span layer names every consumer agrees on (docs/observability.md)
+LAYER_QUERY = "query"
+LAYER_PLAN = "plan"
+LAYER_ACTION = "action"
 LAYER_EXEC = "exec"
+LAYER_PROGRAM = "program"
 LAYER_TRANSFER = "transfer"
 LAYER_SHUFFLE = "shuffle"
 LAYER_MEMORY = "memory"
 LAYER_SERVING = "serving"
+
+#: the action span's profiler name: benchmark/reduce.py names an idle gap
+#: by it when no finer range covers the gap
+ACTION_RANGE = "tpu-sql-action"
+
+
+def profiler_name(name: str, plan_id: Optional[int]) -> str:
+    """``<name>#<plan_id>``, the form benchmark/reduce.py takes for a
+    program range. The suffix is the plan node's ordinal (``#0`` for spans
+    outside any exec), stable from query to query — never the query id,
+    which would splinter a per-name reduction."""
+    return f"{name}#{plan_id or 0}"
 
 
 class SpanRecord:
     """One completed span (or instant event, ``dur_ns == 0``)."""
 
     __slots__ = ("name", "cat", "ts_ns", "dur_ns", "tid", "query_id",
-                 "plan_id", "args", "seq")
+                 "plan_id", "args", "seq", "span_id", "parent_id", "self_ns")
 
     def __init__(self, name: str, cat: str, ts_ns: int, dur_ns: int,
                  tid: int, query_id: Optional[int],
                  plan_id: Optional[int], args: Optional[Dict[str, Any]],
-                 seq: int):
+                 seq: int = 0, span_id: int = 0,
+                 parent_id: Optional[int] = None,
+                 self_ns: Optional[int] = None):
         self.name = name
         self.cat = cat
         self.ts_ns = ts_ns
@@ -73,6 +108,11 @@ class SpanRecord:
         self.plan_id = plan_id
         self.args = args
         self.seq = seq
+        self.span_id = span_id
+        #: the span that was open on the recording thread (None: a root)
+        self.parent_id = parent_id
+        #: duration minus the live child spans on the same thread
+        self.self_ns = dur_ns if self_ns is None else self_ns
 
     def to_event(self) -> Dict[str, Any]:
         """Chrome trace-event form (``ph: X`` complete events; instants
@@ -93,8 +133,10 @@ class SpanRecord:
             args["query_id"] = self.query_id
         if self.plan_id is not None:
             args["plan_id"] = self.plan_id
-        if args:
-            ev["args"] = args
+        args["span_id"] = self.span_id
+        if self.parent_id is not None:
+            args["parent_id"] = self.parent_id
+        ev["args"] = args
         return ev
 
 
@@ -113,40 +155,164 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: per-thread innermost open frame (``.top``): what a new span's parent,
+#: query id and plan id come from
+_TLS = threading.local()
+_SPAN_IDS = itertools.count(1)
 
-class _LiveSpan:
-    """Context manager recording one span on exit."""
 
-    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0")
+class _Frame:
+    """One open span on a thread's stack. ``child_ns`` sums the live child
+    spans closed under it, which on one thread nest and never overlap, so
+    duration minus ``child_ns`` is the span's self time."""
+
+    __slots__ = ("span_id", "query_id", "plan_id", "child_ns", "_prev")
+
+    def __init__(self, span_id: int, query_id: Optional[int],
+                 plan_id: Optional[int]):
+        self.span_id = span_id
+        self.query_id = query_id
+        self.plan_id = plan_id
+        self.child_ns = 0
+        self._prev = None
+
+    def push(self) -> None:
+        self._prev = getattr(_TLS, "top", None)
+        _TLS.top = self
+
+    def pop(self, dur_ns: int) -> None:
+        _TLS.top = self._prev
+        if self._prev is not None:
+            self._prev.child_ns += dur_ns
+
+    # adopt(): the frame as a context manager on the adopting thread
+    def __enter__(self):
+        self.push()
+        return self
+
+    def __exit__(self, *exc):
+        self.pop(0)
+        return False
+
+
+def current() -> Optional[_Frame]:
+    """The innermost span open on this thread (None outside any)."""
+    return getattr(_TLS, "top", None)
+
+
+def adopt(parent: Optional[_Frame]):
+    """Context manager for a thread the program starts for a query
+    (pipeline producer, scan prefetch): spans recorded on it become
+    children of ``parent``, the span that was open where the thread was
+    spawned (``current()`` there; None while tracing is off)."""
+    if parent is None:
+        return _NULL_SPAN
+    return _Frame(parent.span_id, parent.query_id, parent.plan_id)
+
+
+def _profiler_annotation(name: str):
+    """A jax.profiler.TraceAnnotation for ``name`` (the named ranges
+    TRACE_ENABLED promises — NvtxWithMetrics analog), or None when the
+    profiler is unavailable. The one route to the profiler: live spans and
+    the per-pull exec ranges both come through here."""
+    global _TRACE_ANNOTATION
+    if _TRACE_ANNOTATION is None:
+        try:
+            import jax.profiler
+            _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+        except Exception:
+            _TRACE_ANNOTATION = False
+    if _TRACE_ANNOTATION is False:
+        return None
+    try:
+        return _TRACE_ANNOTATION(name)
+    except Exception:
+        return None
+
+
+_TRACE_ANNOTATION = None
+
+
+class _LiveSpan(_Frame):
+    """Context manager recording one span on exit, and showing it as a
+    profiler range while it is open."""
+
+    __slots__ = ("_tracer", "_name", "_cat", "_args", "_t0", "_profile",
+                 "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+                 args: Optional[Dict[str, Any]], plan_id: Optional[int],
+                 query_id: Optional[int], profile, t0_ns: Optional[int]):
+        super().__init__(0, query_id, plan_id)
         self._tracer = tracer
         self._name = name
         self._cat = cat
         self._args = args
+        self._t0 = t0_ns
+        self._profile = profile
+        self._ann = None
 
     def __enter__(self):
-        self._t0 = time.perf_counter_ns()
+        self.push()
+        parent = self._prev
+        if parent is not None:
+            if self.plan_id is None:
+                self.plan_id = parent.plan_id
+            if self.query_id is None:
+                self.query_id = parent.query_id
+        elif self.query_id is None:
+            self.query_id = _root_query_id()
+        self.span_id = next(_SPAN_IDS)
+        if self._profile:
+            self._ann = _profiler_annotation(
+                self._profile if isinstance(self._profile, str)
+                else profiler_name(self._name, self.plan_id))
+            if self._ann is not None:
+                self._ann.__enter__()
+        if self._t0 is None:
+            self._t0 = time.perf_counter_ns()
         return self
 
+    def note(self, **args) -> None:
+        """Add args only known once the work is done (row counts, sizes)."""
+        self._args = {**(self._args or {}), **args}
+
     def __exit__(self, *exc):
-        self._tracer.record(self._name, self._cat, self._t0,
-                            time.perf_counter_ns() - self._t0, self._args)
+        dur = time.perf_counter_ns() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.pop(dur)
+        self._tracer._put(SpanRecord(
+            self._name, self._cat, self._t0, dur, threading.get_ident(),
+            self.query_id, self.plan_id, self._args, span_id=self.span_id,
+            parent_id=self._prev.span_id if self._prev is not None else None,
+            self_ns=max(dur - self.child_ns, 0)))
         return False
 
 
-def _current_query_id() -> Optional[int]:
+def _lifecycle():
     # lazy, cached: only runs while tracing is ON (never on the hot path)
-    global _CURRENT_QUERY
-    if _CURRENT_QUERY is None:
-        from spark_rapids_tpu.serving.lifecycle import current_query
-        _CURRENT_QUERY = current_query
-    q = _CURRENT_QUERY()
+    global _LIFECYCLE
+    if _LIFECYCLE is None:
+        from spark_rapids_tpu.serving import lifecycle
+        _LIFECYCLE = lifecycle
+    return _LIFECYCLE
+
+
+_LIFECYCLE = None
+
+
+def _current_query_id() -> Optional[int]:
+    q = _lifecycle().current_query()
     return q.query_id if q is not None else None
 
 
-_CURRENT_QUERY = None
+def _root_query_id() -> int:
+    """The id a span tree takes when it opens with no parent: the bound
+    query's, else the next of the ids QueryHandles take, so an embedded
+    ``collect()`` and a served query never share one."""
+    qid = _current_query_id()
+    return qid if qid is not None else _lifecycle().next_query_id()
 
 
 class Tracer:
@@ -166,6 +332,9 @@ class Tracer:
         self._ring: List[Optional[SpanRecord]] = [None] * self._capacity
         self._seq = 0               # monotonically increasing record count
         self._active = 0
+        #: records the ring overwrote or a resize let go: a reader
+        #: refuses a window that reaches back past them
+        self.dropped = 0
         #: the one-field fast path every disabled hook reads
         self.on = False
 
@@ -185,6 +354,7 @@ class Tracer:
             lo = max(0, self._seq - min(self._capacity, capacity))
             for i in range(lo, self._seq):
                 new_ring[i % capacity] = self._ring[i % self._capacity]
+            self.dropped = max(self.dropped, lo)
             self._capacity = capacity
             self._ring = new_ring
 
@@ -209,27 +379,51 @@ class Tracer:
         return _Scope()
 
     # ---- recording ---------------------------------------------------------
-    def record(self, name: str, cat: str, ts_ns: int, dur_ns: int,
-               args: Optional[Dict[str, Any]] = None,
-               plan_id: Optional[int] = None,
-               query_id: Optional[int] = None) -> None:
-        if not self.on:
-            return
-        if query_id is None:
-            query_id = _current_query_id()
-        rec = SpanRecord(name, cat, ts_ns, dur_ns, threading.get_ident(),
-                         query_id, plan_id, args, 0)
+    def _put(self, rec: SpanRecord) -> None:
         with self._lock:
             rec.seq = self._seq
             self._ring[self._seq % self._capacity] = rec
             self._seq += 1
+            self.dropped = max(self.dropped, self._seq - self._capacity)
+
+    def record(self, name: str, cat: str, ts_ns: int, dur_ns: int,
+               args: Optional[Dict[str, Any]] = None,
+               plan_id: Optional[int] = None,
+               query_id: Optional[int] = None) -> None:
+        """A span whose two ends are only known afterwards (a window
+        between asynchronous boundaries), or an instant: ring only. Its
+        parent is the span open on this thread, unless that belongs to
+        another query than the one named."""
+        if not self.on:
+            return
+        top = getattr(_TLS, "top", None)
+        if top is not None and query_id not in (None, top.query_id):
+            top = None
+        parent_id = None
+        if top is not None:
+            parent_id = top.span_id
+            query_id = top.query_id
+            if plan_id is None:
+                plan_id = top.plan_id
+        elif query_id is None:
+            query_id = _current_query_id()
+        self._put(SpanRecord(name, cat, ts_ns, dur_ns, threading.get_ident(),
+                             query_id, plan_id, args,
+                             span_id=next(_SPAN_IDS), parent_id=parent_id))
 
     def span(self, name: str, cat: str,
-             args: Optional[Dict[str, Any]] = None):
-        """Timed scope; the disabled path returns one shared no-op."""
+             args: Optional[Dict[str, Any]] = None, *,
+             plan_id: Optional[int] = None, query_id: Optional[int] = None,
+             profile=True, t0_ns: Optional[int] = None):
+        """Timed scope, in the ring and (``profile``: True for
+        ``<name>#<plan_id>``, a string for that name, False for none) in
+        the profiler's trace; the disabled path returns one shared no-op.
+        ``plan_id`` and ``query_id`` default to the enclosing span's;
+        ``t0_ns`` backdates the start to a boundary already passed."""
         if not self.on:
             return _NULL_SPAN
-        return _LiveSpan(self, name, cat, args)
+        return _LiveSpan(self, name, cat, args, plan_id, query_id, profile,
+                         t0_ns)
 
     def instant(self, name: str, cat: str,
                 args: Optional[Dict[str, Any]] = None) -> None:
@@ -261,6 +455,7 @@ class Tracer:
         with self._lock:
             self._ring = [None] * self._capacity
             self._seq = 0
+            self.dropped = 0
 
 
 #: the process-wide tracer every layer records into
@@ -271,8 +466,11 @@ def enabled() -> bool:
     return TRACER.on
 
 
-def span(name: str, cat: str, args: Optional[Dict[str, Any]] = None):
-    return TRACER.span(name, cat, args)
+def span(name: str, cat: str, args: Optional[Dict[str, Any]] = None, *,
+         plan_id: Optional[int] = None, query_id: Optional[int] = None,
+         profile=True, t0_ns: Optional[int] = None):
+    return TRACER.span(name, cat, args, plan_id=plan_id, query_id=query_id,
+                       profile=profile, t0_ns=t0_ns)
 
 
 def instant(name: str, cat: str,
@@ -351,34 +549,16 @@ def note_exec_spill(node, partitions: int, depth: int) -> None:
         obs["grace_depth"] = max(obs.get("grace_depth", 0), depth)
 
 
-def _profiler_annotation(name: str):
-    """A jax.profiler.TraceAnnotation for ``name`` (the per-exec named
-    range TRACE_ENABLED promises — NvtxWithMetrics analog), or None when
-    the profiler is unavailable."""
-    global _TRACE_ANNOTATION
-    if _TRACE_ANNOTATION is None:
-        try:
-            import jax.profiler
-            _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
-        except Exception:
-            _TRACE_ANNOTATION = False
-    if _TRACE_ANNOTATION is False:
-        return None
-    try:
-        return _TRACE_ANNOTATION(name)
-    except Exception:
-        return None
-
-
-_TRACE_ANNOTATION = None
-
-
 def trace_exec(node, ctx, raw) -> Iterator:
     """Wrap one exec's ``execute()`` iteration with span recording: each
     ``__next__`` is timed (and shows as a named jax.profiler range), rows/
     batches/bytes are observed from the yielded batches, and ONE span per
     execute() call lands in the ring (ts = first pull, dur = pull window).
-    Self time subtracts nested child pulls on the same thread.
+    Each pull is a frame on the thread's span stack, so spans opened inside
+    it (uploads, program calls, child execs) are this span's children and
+    take its plan id. EXPLAIN ANALYZE's self time subtracts nested child
+    EXEC pulls on the same thread; the record's ``self_ns`` subtracts every
+    live child span.
 
     A subclass delegating to ``super().execute()`` (FusedAggregateStage ->
     TpuHashAggregate) must not double-record the node: when the CURRENT
@@ -388,14 +568,18 @@ def trace_exec(node, ctx, raw) -> Iterator:
         yield from raw(node, ctx)
         return
     rec = _ExecRecorder(node)
-    qid = _current_query_id()
-    range_name = f"{node.name}#{node.plan_id}" if node.plan_id is not None \
-        else node.name
+    opener = getattr(_TLS, "top", None)
+    qid = opener.query_id if opener is not None else _current_query_id()
+    span_id = next(_SPAN_IDS)
+    range_name = profiler_name(node.name, node.plan_id)
+    self_ns = 0
     it = iter(raw(node, ctx))
     try:
         while True:
             parent = getattr(_EXEC_TLS, "rec", None)
             _EXEC_TLS.rec = rec
+            frame = _Frame(span_id, qid, node.plan_id)
+            frame.push()
             ann = _profiler_annotation(range_name)
             t0 = time.perf_counter_ns()
             if rec.t_first == 0:
@@ -414,6 +598,8 @@ def trace_exec(node, ctx, raw) -> Iterator:
                 if parent is not None:
                     parent.child_ns += dt
                 _EXEC_TLS.rec = parent
+                frame.pop(dt)
+                self_ns += max(dt - frame.child_ns, 0)
             rec.batches += 1
             n = getattr(batch, "num_rows", None)
             if n is not None:
@@ -426,16 +612,19 @@ def trace_exec(node, ctx, raw) -> Iterator:
             close()
         _accumulate(node, rec)
         if rec.t_first:
-            TRACER.record(
+            TRACER._put(SpanRecord(
                 node.name, LAYER_EXEC, rec.t_first,
                 time.perf_counter_ns() - rec.t_first,
+                threading.get_ident(), qid, node.plan_id,
                 {"rows": rec.rows, "batches": rec.batches,
                  "bytes": rec.bytes,
                  "busy_ms": round(rec.wall_ns / 1e6, 3),
                  "self_ms": round(max(rec.wall_ns - rec.child_ns, 0) / 1e6,
                                   3),
                  "partition": ctx.partition_id},
-                plan_id=node.plan_id, query_id=qid)
+                span_id=span_id,
+                parent_id=opener.span_id if opener is not None else None,
+                self_ns=self_ns))
 
 
 # ---------------------------------------------------------------- rendering

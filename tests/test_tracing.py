@@ -125,13 +125,16 @@ def test_chrome_export_valid_with_layers(tmp_path):
         assert "name" in e and "ts" in e and e["ph"] in ("X", "i")
     assert doc["otherData"]["action_wall_s"] > 0
     counts = tracing.layer_counts(sess.last_trace)
+    # the window is the whole tree now: root, plan and action with the
+    # layers under them
     assert all(counts[c] >= 1 for c in
-               ("exec", "transfer", "memory", "serving")), counts
+               ("query", "plan", "action", "exec", "program", "transfer",
+                "memory", "serving")), counts
+    assert counts["query"] == counts["action"] == 1, counts
 
 
-def test_per_exec_profiler_ranges(monkeypatch):
-    """TRACE_ENABLED's docstring promise (satellite): named profiler
-    ranges PER OPERATOR, not just the one whole-action range."""
+def _fake_annotations(monkeypatch):
+    """Patch the profiler's range class; the names it was given."""
     names = []
 
     class FakeAnnotation:
@@ -145,6 +148,13 @@ def test_per_exec_profiler_ranges(monkeypatch):
             return False
 
     monkeypatch.setattr(tracing, "_TRACE_ANNOTATION", FakeAnnotation)
+    return names
+
+
+def test_per_exec_profiler_ranges(monkeypatch):
+    """TRACE_ENABLED's docstring promise (satellite): named profiler
+    ranges PER OPERATOR, not just the one whole-action range."""
+    names = _fake_annotations(monkeypatch)
     sess = TpuSession({**BASE_CONF,
                        "spark.rapids.tpu.trace.enabled": "true"})
     _q(sess).collect()
@@ -180,6 +190,279 @@ def test_handle_analyze_requires_tracing():
     handle.result(timeout=300)
     with pytest.raises(RuntimeError, match="trace.enabled"):
         handle.explain_analyze()
+
+
+# ----------------------------------------------- one span tree per query
+TPCH_CONF = {**BASE_CONF, "spark.rapids.tpu.sql.hasNans": "false",
+             "spark.rapids.tpu.trace.enabled": "true",
+             # several chunks, so an upload has stage, wait and assemble
+             "spark.rapids.tpu.transfer.chunkRows": "2048"}
+#: every kind a cached program may be named by: the leading strings of the
+#: program-cache keys ("-" -> "_") and the evaluator's "project"
+KNOWN_KINDS = {
+    "project", "filter", "agg", "sort", "stage", "window", "join_size",
+    "join_gather", "slice_padded", "exchange", "exchange_enc",
+    "exchange_enc_piece", "exchange_sketch", "exchange_fused",
+    "exchange_pids", "exchange_keys", "grace_split", "grace_sample",
+    "mesh_gather", "dist_agg", "ici_repart", "mproject", "mfilter",
+    "mshrink", "mexpand", "mwindow", "mwindow_part", "magg",
+    "magg_merge_ag", "magg_merge_part", "magg_part", "mjoin_size",
+    "mjoin_gather", "mjoin_lpart", "mjoin_rpart", "msort", "msort_sample",
+    "msort_part", "munion", "mexchange"}
+#: ring-only by their nature: the root, and windows whose two ends are only
+#: known afterwards (tracing.record with explicit timestamps)
+RING_ONLY = {"query", "transfer.download", "serving.queue_wait",
+             "serving.preempt_yield", "shuffle.fetch"}
+
+
+@pytest.fixture(scope="module")
+def tpch():
+    from spark_rapids_tpu.benchmarks.tpch_data import gen_all
+    return gen_all(0.002, seed=7)
+
+
+def _tpch_query(qnum, sess, tables):
+    from spark_rapids_tpu.benchmarks.tpch_queries import QUERIES
+    return QUERIES[qnum]({k: sess.create_dataframe(tables[k])
+                          for k in ("lineitem", "orders", "customer")})
+
+
+def _assert_one_tree(records, query_id=None):
+    """``records`` hold exactly one whole tree: one root, every span with
+    the root's query id, a parent among them and inside its interval."""
+    roots = [r for r in records if r.parent_id is None]
+    assert [r.name for r in roots] == ["query"], [
+        (r.name, r.parent_id) for r in roots]
+    root = roots[0]
+    assert root.query_id is not None
+    if query_id is not None:
+        assert root.query_id == query_id
+    by_id = {r.span_id: r for r in records}
+    assert len(by_id) == len(records)
+    for r in records:
+        assert r.query_id == root.query_id, (r.name, r.query_id)
+        if r is root:
+            continue
+        parent = by_id.get(r.parent_id)
+        assert parent is not None, f"{r.name}: parent not in the tree"
+        assert parent.ts_ns <= r.ts_ns, (r.name, parent.name)
+        assert r.ts_ns + r.dur_ns <= parent.ts_ns + parent.dur_ns, (
+            r.name, parent.name)
+        assert 0 <= r.self_ns <= r.dur_ns
+    return root
+
+
+@pytest.mark.parametrize("qnum", [1, 6, 3])
+def test_span_tree_per_query(qnum, tpch, monkeypatch):
+    """Embedded collect(): the action's window is one tree under one
+    ``query`` root, ids from an ordinal; the ring and the profiler hold
+    the same spans under the same names; spans under an exec take its
+    plan id; the parts of the tree sum."""
+    annotated = _fake_annotations(monkeypatch)
+    # a scan cache too small for any table, as SF1 lineitem finds the
+    # default: every query uploads again
+    sess = TpuSession({**TPCH_CONF,
+                       "spark.rapids.tpu.sql.scanCache.maxBytes": "1"})
+    first = _tpch_query(qnum, sess, tpch).collect()
+    first_id = sess.last_trace[-1].query_id
+    del annotated[:]
+    out = _tpch_query(qnum, sess, tpch).collect()
+    assert out.equals(first)
+    records = sess.last_trace
+    root = _assert_one_tree(records)
+    assert root is records[-1]              # the root closes last
+    assert root.query_id != first_id        # a fresh ordinal per action
+    names = [r.name for r in records]
+    for expected in ("plan", "action", "serving.admission_wait",
+                     "scan_cache.not_kept", "transfer.upload", "upload.stage", "upload.wait",
+                     "upload.assemble", "download.wait",
+                     "download.to_arrow", "result.concat"):
+        assert expected in names, (expected, sorted(set(names)))
+    assert "transfer.upload_chunk" not in names
+    plan = next(r for r in records if r.name == "plan")
+    assert plan.args["execs"] >= 3 and plan.args["cpu_execs"] >= 1
+    # the ring's names are the profiler's: <name>#<plan_id>, the action
+    # under the name benchmark/reduce.py looks for
+    ring = {tracing.ACTION_RANGE if r.name == "action"
+            else tracing.profiler_name(r.name, r.plan_id)
+            for r in records if r.dur_ns and r.name not in RING_ONLY}
+    assert ring == set(annotated), ring ^ set(annotated)
+    assert all(":" not in n and "#" in n
+               for n in annotated if n != tracing.ACTION_RANGE)
+    # a span under an exec carries that exec's plan id
+    by_id = {r.span_id: r for r in records}
+    uploads = [r for r in records if r.name == "transfer.upload"]
+    for up in uploads:
+        host_to_device = by_id[up.parent_id]
+        assert host_to_device.name == "HostToDeviceExec"
+        assert up.plan_id == host_to_device.plan_id is not None
+    stages = [r for r in records if r.name == "upload.stage"]
+    assert {by_id[r.parent_id].name for r in stages} == {"transfer.upload"}
+    assert all(r.plan_id == by_id[r.parent_id].plan_id for r in stages)
+    assert sum(r.args["rows"] for r in stages) == sum(
+        r.args["rows"] for r in uploads)
+    programs = [r for r in records if r.cat == "program"]
+    assert programs and all(r.name[len("program."):] in KNOWN_KINDS
+                            and r.args["first"] is False for r in programs)
+    # self time: a span's duration less its live children on its thread
+    action = next(r for r in records if r.name == "action")
+    kids = [r for r in records if r.parent_id == root.span_id]
+    assert root.self_ns == root.dur_ns - sum(k.dur_ns for k in kids)
+    assert action.self_ns < action.dur_ns
+
+
+def test_span_tree_of_a_served_query(tpch):
+    """Served: the tree's id is the handle's, its root runs from
+    submission (so the queue wait is inside), planning is in it, and two
+    queries' trees do not mix."""
+    sess = TpuSession(TPCH_CONF)
+    handles = [sess.submit(_tpch_query(q, sess, tpch)) for q in (6, 1)]
+    for h in handles:
+        assert h.result(timeout=300).num_rows >= 1
+    for h in handles:
+        records = tracing.TRACER.since(0, query_id=h.query_id)
+        root = _assert_one_tree(records, h.query_id)
+        names = {r.name for r in records}
+        assert {"serving.queue_wait", "plan", "action",
+                "serving.admission_wait", "result.concat"} <= names, names
+        wait = next(r for r in records if r.name == "serving.queue_wait")
+        assert wait.parent_id == root.span_id and wait.ts_ns == root.ts_ns
+        assert wait.dur_ns == pytest.approx(
+            h.metrics["queue_wait_s"] * 1e9, abs=2e3)
+        assert root.dur_ns == pytest.approx(h.metrics["wall_s"] * 1e9,
+                                            rel=0.05, abs=5e6)
+
+
+def test_scan_cache_latch_is_a_span(monkeypatch):
+    """A query latched behind another's upload of the same table records
+    the wait; the builder records whether its batch was kept."""
+    from spark_rapids_tpu.memory.scan_cache import DeviceScanCache
+
+    class Batch:
+        device_size_bytes = 100
+
+    table = pa.table({"a": [1]})
+    started, release = threading.Event(), threading.Event()
+
+    def slow_upload():
+        started.set()
+        assert release.wait(30)
+        return Batch()
+
+    t = tracing.Tracer(capacity=64)
+    monkeypatch.setattr(tracing, "TRACER", t)
+    cache = DeviceScanCache(max_bytes=50)       # over budget: never kept
+    got = []
+    with t.activate():
+        builder = threading.Thread(target=lambda: got.append(
+            cache.get_or_put(table, 8, slow_upload)))
+        builder.start()
+        assert started.wait(30)
+        # the waiter's poll while latched is what lets the builder finish,
+        # so it has waited by then
+        waiter = threading.Thread(target=lambda: got.append(
+            cache.get_or_put(table, 8, Batch, cancel_check=release.set)))
+        waiter.start()
+        builder.join(30)
+        waiter.join(30)
+    assert len(got) == 2 and not builder.is_alive() and not waiter.is_alive()
+    names = [r.name for r in t.since(0)]
+    # the waiter waited, found nothing kept, and built again itself
+    assert names.count("scan_cache.wait") == 1
+    assert names.count("scan_cache.not_kept") == 2
+    assert "scan_cache.hit" not in names and "scan_cache.miss" not in names
+
+
+def test_programs_are_named_by_kind(tpch):
+    """After Q1/Q6/Q3 no cached program is called ``fn`` or ``<lambda>``:
+    each carries its kind, which the XLA module (``jit_<kind>``) and the
+    program spans take."""
+    from spark_rapids_tpu.serving.program_cache import global_program_cache
+    sess = TpuSession({k: v for k, v in TPCH_CONF.items()
+                       if "trace" not in k})
+    for qnum in (1, 6, 3):
+        _tpch_query(qnum, sess, tpch).collect()
+    programs = list(global_program_cache()._programs.values())
+    assert len(programs) >= 4
+    kinds = {p.kind for p in programs}
+    assert not kinds & {"fn", "<lambda>", "f"}, kinds
+    assert kinds <= KNOWN_KINDS, kinds - KNOWN_KINDS
+    assert {"agg", "join_size", "join_gather", "sort"} <= kinds, kinds
+    for p in programs:
+        assert p.fn.__name__ == p.kind
+        assert p.fn.lower  # still the jitted callable
+
+
+def test_tracing_off_costs_one_bool_read(monkeypatch):
+    """Off: span() is the shared no-op, nothing reaches the ring, and a
+    cached program's call reads the tracer's flag once and nothing more."""
+    from spark_rapids_tpu.serving import program_cache as pc
+    assert not tracing.TRACER.on
+    assert tracing.span("x", "exec") is tracing._NULL_SPAN
+    assert tracing.adopt(None) is tracing._NULL_SPAN
+    mark = tracing.TRACER.mark()
+    sess = TpuSession(BASE_CONF)
+    _q(sess).collect()
+    assert tracing.TRACER.mark() == mark            # nothing recorded
+
+    class CountingTracer:
+        reads = 0
+
+        @property
+        def on(self):
+            CountingTracer.reads += 1
+            return False
+
+        def __getattr__(self, name):    # anything else would be a hook
+            raise AssertionError(f"tracer.{name} touched with tracing off")
+
+    prog = pc._Program(lambda x: x + 1, pc.ProgramCache(index_path="off"))
+    assert prog(1) == 2                             # first call, untimed here
+    counting = CountingTracer()
+    monkeypatch.setattr(pc._tracing, "TRACER", counting)
+    assert prog(2) == 3
+    assert CountingTracer.reads == 1
+
+
+def test_ring_counts_what_it_dropped():
+    t = tracing.Tracer(capacity=16)
+    with t.activate():
+        for i in range(16):
+            t.record(f"s{i}", "exec", i, 1)
+        assert t.dropped == 0
+        for i in range(5):
+            t.record(f"t{i}", "exec", i, 1)
+    assert t.dropped == 5 and t.since(0)[0].seq == 5
+    t.configure(32)                 # a resize keeps what is held
+    assert t.dropped == 5 and len(t.since(0)) == 16
+    t.clear()
+    assert t.dropped == 0
+
+
+def test_producer_threads_adopt_the_spawning_span():
+    t = tracing.Tracer(capacity=64)
+    seen = {}
+    with t.activate():
+        with t.span("parent", "exec", plan_id=4, profile=False) as parent:
+            spawning = tracing.current()
+            assert spawning is parent
+
+            def work():
+                with tracing.adopt(spawning):
+                    with t.span("child", "transfer", profile=False):
+                        pass
+                seen["after"] = tracing.current()
+
+            th = threading.Thread(target=work)
+            th.start()
+            th.join(30)
+            assert not th.is_alive()
+    child, par = t.since(0)
+    assert (child.name, child.parent_id, child.plan_id, child.query_id) == (
+        "child", par.span_id, 4, par.query_id)
+    assert child.tid != par.tid and seen["after"] is None
+    # another thread's child is concurrency, not the parent's own time
+    assert par.self_ns == par.dur_ns
 
 
 # ------------------------------------------------- registry coverage (S4)
