@@ -82,9 +82,4 @@ BENCH_CONF = {
     # flips for benchmarks: spark.rapids.sql.variableFloatAgg.enabled)
     "spark.rapids.tpu.sql.variableFloatAgg.enabled": "true",
     "spark.rapids.tpu.sql.incompatibleOps.enabled": "true",
-    # v5e has 16 GB HBM; the 2 GiB default thrashes at SF >= 1 (store_sales
-    # alone exceeds it device-side, so every query re-uploaded it — 5.4 s
-    # per query measured; the reference's tuning guide similarly sizes the
-    # device pool to the data)
-    "spark.rapids.tpu.sql.scanCache.maxBytes": str(12 << 30),
 }

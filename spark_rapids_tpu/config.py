@@ -122,9 +122,11 @@ SCAN_CACHE_ENABLED = _conf(
     "RapidsBufferCatalog's cached batches).")
 
 SCAN_CACHE_BYTES = _conf(
-    "sql.scanCache.maxBytes", int, 2 << 30,
+    "sql.scanCache.maxBytes", int, 0,
     "Upper bound on device bytes held by the scan cache; least-recently-used tables are "
-    "evicted past it.")
+    "evicted past it. 0 means derive from the device: half of "
+    "memory.outOfCore.headroomFraction of the device budget (memory.tpu.poolSizeBytes, or "
+    "allocFraction of the detected HBM) that the device store does not hold.")
 
 ENABLE_CAST_FLOAT_TO_STRING = _conf(
     "sql.castFloatToString.enabled", bool, False,

@@ -194,8 +194,9 @@ class HostToDeviceExec(PhysicalExec):
                 and ctx.conf.get(cfg.SCAN_CACHE_ENABLED)):
             if ctx.partition_id != 0:
                 return
-            from spark_rapids_tpu.memory.scan_cache import get_cache
-            cache = get_cache(ctx.conf.get(cfg.SCAN_CACHE_BYTES))
+            from spark_rapids_tpu.memory.scan_cache import (derived_budget,
+                                                            get_cache)
+            cache = get_cache(derived_budget(ctx.conf))
             smax = ctx.string_max_bytes
             # per-key latch: concurrent queries missing on the same table
             # share ONE upload instead of each paying the host link

@@ -4,7 +4,8 @@ Repeated actions over the same DataFrame re-run the whole physical plan,
 including the host->device upload of the scanned arrow table — by far the
 dominant cost on a remote-attached chip. This cache keeps the uploaded
 DeviceBatch alive across actions, keyed by the identity of the (immutable)
-arrow table, with LRU eviction over a device-byte budget.
+arrow table, with LRU eviction over a device-byte budget that is read from
+the device (``derived_budget``), not from a constant.
 
 Reference analog: the device tier of the spillable buffer store
 (RapidsDeviceMemoryStore.scala / RapidsBufferCatalog.scala) which keeps hot
@@ -68,23 +69,16 @@ class DeviceScanCache:
                         self._inflight[key] = ev  # tpu-lint: disable=R008
                         mine = True
             if got is not None:
-                if _tracing.TRACER.on:
-                    _tracing.instant("scan_cache.hit",
-                                     _tracing.LAYER_TRANSFER,
-                                     {"bytes": got.device_size_bytes})
+                self._instant("scan_cache.hit", got)
                 return got
             if mine:
                 try:
                     batch = builder()
                     kept = self.put(table, smax, batch)
-                    if _tracing.TRACER.on:
-                        # not_kept: built, but over the budget — the next
-                        # query (and any waiter on this latch) uploads again
-                        _tracing.instant(
-                            "scan_cache.miss" if kept
-                            else "scan_cache.not_kept",
-                            _tracing.LAYER_TRANSFER,
-                            {"bytes": batch.device_size_bytes})
+                    # not_kept: built, but over the budget — the next
+                    # query (and any waiter on this latch) uploads again
+                    self._instant("scan_cache.miss" if kept
+                                  else "scan_cache.not_kept", batch)
                     return batch
                 finally:
                     with self._lock:
@@ -94,6 +88,15 @@ class DeviceScanCache:
                 while not ev.wait(0.05):
                     if cancel_check is not None:
                         cancel_check()
+
+    def _instant(self, name: str, batch) -> None:
+        """``held`` (bytes in the cache after the call) beside ``budget``
+        says why an entry was or was not kept."""
+        if _tracing.TRACER.on:
+            _tracing.instant(name, _tracing.LAYER_TRANSFER,
+                             {"bytes": batch.device_size_bytes,
+                              "held": self.total_bytes(),
+                              "budget": self.max_bytes})
 
     def _get_locked(self, table, smax: int):
         key = (id(table), smax)
@@ -161,6 +164,31 @@ class DeviceScanCache:
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
+
+
+def derived_budget(conf) -> int:
+    """The cache's byte budget on the device this process runs on.
+
+    An explicit ``sql.scanCache.maxBytes`` is the budget. Unset (0), it is
+    half of what the out-of-core contract lets one operator's working set
+    occupy — ``memory.outOfCore.headroomFraction`` of the device budget
+    the store does not hold already (``GraceController.threshold_bytes``
+    is the same expression). A cached table is an operator's input, and
+    an input stands in HBM twice when it matters: chunks beside the
+    assembled batch while it is uploaded, the table beside what the
+    operators carry of it while they run. Half of the working-set share is
+    therefore what resident inputs can take and still be both built and
+    read in one pass."""
+    from spark_rapids_tpu import config as cfg
+    explicit = conf.get(cfg.SCAN_CACHE_BYTES)
+    if explicit:
+        return explicit
+    from spark_rapids_tpu.memory.device_manager import DeviceManager
+    from spark_rapids_tpu.plan.footprint import device_budget_estimate
+    dm = DeviceManager.peek()
+    used = dm.device_store.used_bytes if dm is not None else 0
+    free = max(device_budget_estimate(conf) - used, 0)
+    return int(free * conf.get(cfg.OOC_HEADROOM)) // 2
 
 
 _cache: Optional[DeviceScanCache] = None

@@ -424,12 +424,19 @@ def test_connect_retries_until_peer_registers(tmp_path):
     conf = TpuConf({
         "spark.rapids.tpu.shuffle.tcp.registryDir": str(tmp_path / "reg"),
         "spark.rapids.tpu.shuffle.connectTimeout": 0.3,
-        "spark.rapids.tpu.shuffle.retryBackoffMs": 50})
+        "spark.rapids.tpu.shuffle.retryBackoffMs": 50,
+        # the schedule, not the clock, bounds the client: a loaded host
+        # may take seconds to bring the late peer up
+        "spark.rapids.tpu.shuffle.maxRetries": 30})
     a = TcpTransport("exec-early", conf)
     result = {}
 
     def late_start():
-        time.sleep(0.6)                 # past the first connect attempt
+        # only once the first connect attempt has failed
+        deadline = time.monotonic() + 30
+        while (a.metrics[mt.SHUFFLE_CONNECT_RETRIES].value < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         result["b"] = TcpTransport("exec-late", conf)
         result["b"].server.register_request_handler(
             "ping", lambda peer, payload: b"pong")
