@@ -10,6 +10,7 @@ comparison are the benchmark's own."""
 import concurrent.futures
 import functools
 import importlib
+import os
 import re
 import shutil
 import sys
@@ -47,12 +48,17 @@ class Counters:
         from spark_rapids_tpu.serving.program_cache import global_program_cache
         from spark_rapids_tpu.utils.metrics import TRANSFER_METRICS
         programs = global_program_cache().stats()
+        transfer = TRANSFER_METRICS.snapshot()
         return {"program_hits": programs["hits"],
                 "program_misses": programs["misses"],
                 # first call of each program: compile plus one execution
                 "first_call_s": programs["compile_s"],
-                "upload_bytes":
-                    TRANSFER_METRICS.snapshot()["transfer.upload_bytes"],
+                "upload_bytes": transfer["transfer.upload_bytes"],
+                # what a file scan staged in the file's own encoding, and
+                # what the same columns would have staged decoded
+                "encoded_bytes": transfer["transfer.encoded_bytes"],
+                "decoded_equivalent_bytes":
+                    transfer["transfer.decoded_equivalent_bytes"],
                 "xla_compile_requests": self.xla_compile_requests}
 
 
@@ -86,16 +92,46 @@ def check_device(chips, need_tpu):
     return device, peaks
 
 
-def setup(cell, seed, trace, scale=1.0, need_tpu=True, tables=None):
-    """Everything before the window: device check, tables from the seed
-    (``tables``: already made from it, by a tool that reads several cells in
-    one process), the session (and server), every program the cell's queries
+def dataframes(session, tables, tables_from):
+    """The cell's DataFrames as its configuration says they come
+    (``tables_from``), and the directory written for them, if any.
+
+    ``memory``: ``createDataFrame`` of each Arrow table. ``parquet``: each
+    table written as ``<tmp>/<table>/part-0.parquet`` by pyarrow at its
+    defaults and read by ``session.read.parquet(<tmp>/<table>)``; the
+    directory lies under ``TMPDIR`` and is the caller's to remove."""
+    if tables_from == "memory":
+        return {name: session.createDataFrame(table)
+                for name, table in tables.items()}, None
+    import pyarrow.parquet as pq
+    tmp = tempfile.mkdtemp(prefix="benchmark-tables-")
+    try:
+        dfs = {}
+        for name, table in tables.items():
+            table_dir = os.path.join(tmp, name)
+            os.mkdir(table_dir)
+            pq.write_table(table, os.path.join(table_dir, "part-0.parquet"))
+            dfs[name] = session.read.parquet(table_dir)
+    except BaseException:
+        shutil.rmtree(tmp, ignore_errors=True)
+        raise
+    return dfs, tmp
+
+
+def setup(cell, seed, trace, scale=None, need_tpu=True, tables=None):
+    """Everything before the window: device check, tables from the seed at
+    the configuration's ``scale_factor`` (``scale``: another one, for a
+    rehearsal; ``tables``: already made, by a tool that reads several cells
+    in one process), the session (and server), the DataFrames as the
+    configuration's ``tables_from`` says, every program the cell's queries
     use built."""
     mf = manifest.load()
     entry = manifest.workload_entry(mf, cell)
     workload = manifest.workload_file(cell)
     config = manifest.config_file(mf, entry["config"])
     device, peaks = check_device(entry["chips"], need_tpu)
+    if scale is None:
+        scale = config["scale_factor"]
 
     qids = sorted({q["id"] for q in workload["queries"]})
     sql = {q: manifest.query_sql(q) for q in qids}
@@ -109,25 +145,25 @@ def setup(cell, seed, trace, scale=1.0, need_tpu=True, tables=None):
     if trace:
         conf[TRACE_CONF] = "true"
     session = TpuSession(conf)
-    dfs = {name: session.createDataFrame(table)
-           for name, table in tables.items()}
+    dfs, tmp_dir = dataframes(session, tables, manifest.tables_from(config))
     st = types.SimpleNamespace(
         cell=cell, manifest=mf, entry=entry, workload=workload, config=config,
         device=device, peaks=peaks, counters=counters(), qids=qids, sql=sql,
-        tables=tables, session=session, dfs=dfs, server=None, client=None,
-        cpu_execs=set())
-    if workload["entry"] == "served":
-        from spark_rapids_tpu.serving.client import QueryServiceClient
-        from spark_rapids_tpu.serving.server import QueryServer
-        for name, df in dfs.items():
-            df.createOrReplaceTempView(name)
-        st.server = QueryServer(session)
-        host, port = st.server.address
-        st.client = QueryServiceClient([f"{host}:{port}"], session.conf)
-    else:
-        st.build = {q: importlib.import_module(f"benchmark.queries.{q}").build
-                    for q in qids}
+        tables=tables, session=session, dfs=dfs, tmp_dir=tmp_dir,
+        server=None, client=None, cpu_execs=set())
     try:
+        if workload["entry"] == "served":
+            from spark_rapids_tpu.serving.client import QueryServiceClient
+            from spark_rapids_tpu.serving.server import QueryServer
+            for name, df in dfs.items():
+                df.createOrReplaceTempView(name)
+            st.server = QueryServer(session)
+            host, port = st.server.address
+            st.client = QueryServiceClient([f"{host}:{port}"], session.conf)
+        else:
+            st.build = {
+                q: importlib.import_module(f"benchmark.queries.{q}").build
+                for q in qids}
         warm_up(st)
     except BaseException:
         teardown(st)
@@ -178,6 +214,9 @@ def teardown(st):
         st.server.shutdown()
         st.session.scheduler.shutdown(wait=True, timeout=GRACE_S)
         st.server = None
+    if st.tmp_dir is not None:
+        shutil.rmtree(st.tmp_dir, ignore_errors=True)
+        st.tmp_dir = None
 
 
 class SliceTracer:
@@ -357,7 +396,7 @@ def memory_stats():
     return {"peak_bytes_in_use": peak, "bytes_limit": limit}
 
 
-def run_cell(cell, seed, seconds, trace, t_start, scale=1.0, need_tpu=True):
+def run_cell(cell, seed, seconds, trace, t_start, scale=None, need_tpu=True):
     """The whole run. Returns (result object for the last line, the numbers
     compared)."""
     st = setup(cell, seed, trace, scale, need_tpu)

@@ -13,6 +13,10 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
+#: where a configuration's tables come from (its file's ``tables_from``):
+#: ``createDataFrame`` of the Arrow tables, or parquet files written in
+#: set-up and read by ``session.read.parquet``
+TABLES_FROM = ("memory", "parquet")
 
 
 class ManifestError(ValueError):
@@ -33,6 +37,25 @@ def config_file(manifest, name):
         if c["name"] == name:
             return _read_json(os.path.join(ROOT, c["file"]))
     raise ManifestError(f"no configuration {name!r}")
+
+
+def tables_from(config):
+    """Where the configuration's tables come from; ``memory`` unless its
+    file says otherwise."""
+    return config.get("tables_from", TABLES_FROM[0])
+
+
+def config_problems(name, config):
+    """Breaches in what a configuration's file states for the harness."""
+    bad = []
+    if tables_from(config) not in TABLES_FROM:
+        bad.append(f"configuration {name!r}: tables_from "
+                   f"{config['tables_from']!r} is none of {TABLES_FROM}")
+    sf = config.get("scale_factor")
+    if isinstance(sf, bool) or not isinstance(sf, (int, float)) or not sf > 0:
+        bad.append(f"configuration {name!r}: scale_factor {sf!r} is not a "
+                   "positive number")
+    return bad
 
 
 def workload_entry(manifest, name):
@@ -119,6 +142,9 @@ def problems_of(manifest):
             bad.append(f"configuration file {f!r} lies outside paths")
         elif not os.path.isfile(os.path.join(ROOT, f)):
             bad.append(f"configuration file {f!r} does not exist")
+        else:
+            bad += config_problems(c.get("name"),
+                                   config_file(manifest, c.get("name")))
         for key in c.get("reduced", []):
             if not NAME.match(key):
                 bad.append(f"reduced key {key!r} outside the allowed characters")

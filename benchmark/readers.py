@@ -30,6 +30,15 @@ def counter_at_window_start(ctx, counter, scale=1.0):
     return scale * ctx["before"][counter]
 
 
+def counter_ratio(ctx, numerator, denominator, scale=1.0):
+    """One counter's rise over the window as a share of another's; None
+    where the other did not rise."""
+    below = counter_delta(ctx, [denominator])
+    if not below:
+        return None
+    return scale * counter_delta(ctx, [numerator]) / below
+
+
 def request_percentile(ctx, field, q, scale=1.0):
     values = [r[field] for r in ctx.get("requests", [])
               if r.get(field) is not None]

@@ -22,28 +22,32 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--seconds", type=float, default=8.0)
     ap.add_argument("--control-seeds", type=int, default=3)
-    ap.add_argument("--scale", type=float, default=1.0)
+    ap.add_argument("--scale", type=float,
+                    help="default: the cells' configurations' scale_factor")
     ap.add_argument("--any-backend", action="store_true",
                     help="rehearsal: do not insist on a TPU")
     args = ap.parse_args(argv)
     from benchmark import correct, harness, manifest
     from benchmark.datagen import gen_tables
     mf = manifest.load()
+    wanted, scales = set(), set()
+    for c in args.workloads:
+        config = manifest.config_file(
+            mf, manifest.workload_entry(mf, c)["config"])
+        scales.add(args.scale or config["scale_factor"])
+        wanted |= set(manifest.tables_named(
+            [q["id"] for q in manifest.workload_file(c)["queries"]],
+            config["schema"]))
+    if len(scales) > 1:
+        ap.error(f"the cells' scale factors differ ({sorted(scales)}): one "
+                 "set of tables cannot serve them")
+    (scale,) = scales
     bad = 0
     for i, seed in enumerate(args.seeds):
-        tables, qids = None, set()
+        tables, qids = gen_tables(sorted(wanted), scale, seed), set()
         for cell in args.workloads:
             t0 = time.perf_counter()
-            if tables is None:
-                wanted = set()
-                for c in args.workloads:
-                    config = manifest.config_file(
-                        mf, manifest.workload_entry(mf, c)["config"])
-                    wanted |= set(manifest.tables_named(
-                        [q["id"] for q in manifest.workload_file(c)["queries"]],
-                        config["schema"]))
-                tables = gen_tables(sorted(wanted), args.scale, seed)
-            st = harness.setup(cell, seed, False, args.scale,
+            st = harness.setup(cell, seed, False, scale,
                                not args.any_backend, tables=tables)
             try:
                 win = harness.window(st, args.seconds, seed)

@@ -13,6 +13,7 @@ from benchmark.datagen import gen_tables
 SCANAGG = "tpch_sf1_session.scanagg"
 JOIN = "tpch_sf1_session.join"
 SERVED = "tpch_sf1_server.short_openloop"
+PARQUET = "tpch_sf1_parquet.scanagg"
 
 
 def _run(cell, seed=11):
@@ -44,7 +45,7 @@ def test_the_reference_agrees_with_itself_and_a_wrong_shape_is_counted(tables):
     assert miss > 0 and gap == correct.GAP_WHEN_INCOMPARABLE
 
 
-@pytest.mark.parametrize("cell", [SCANAGG, JOIN, SERVED])
+@pytest.mark.parametrize("cell", [SCANAGG, JOIN, SERVED, PARQUET])
 def test_a_sound_run_is_correct(cell):
     result, numbers = _run(cell)
     assert result["correct"] is True, numbers
@@ -72,8 +73,10 @@ def _one_more(column):
     # an answer altered where it is produced: a float by 1e-8 of itself ...
     (SCANAGG, "q6", _scaled("revenue", 1 + 1e-8), "q6.rel_gap"),
     (SERVED, "q1", _scaled("sum_charge", 1 + 1e-8), "q1.rel_gap"),
+    (PARQUET, "q6", _scaled("revenue", 1 + 1e-8), "q6.rel_gap"),
     # ... a count by one, a key by one
     (SCANAGG, "q1", _one_more("count_order"), "q1.exact_mismatch"),
+    (PARQUET, "q1", _one_more("count_order"), "q1.exact_mismatch"),
     (JOIN, "q3", _one_more("l_orderkey"), "q3.exact_mismatch"),
     # ... a row dropped
     (JOIN, "q3", lambda t: t.slice(1), "q3.exact_mismatch"),
@@ -108,7 +111,25 @@ def test_half_of_the_rows_left_out_is_not_correct(monkeypatch):
     assert not correct.holds(numbers["q6.rel_gap"])
 
 
-def test_an_operator_on_the_cpu_engine_is_not_correct(monkeypatch):
+def test_half_of_the_rows_left_out_of_the_file_is_not_correct(monkeypatch):
+    """The file the program scans holds half of lineitem; the reference is
+    computed on the table from the seed, all of it."""
+    import pyarrow.parquet as pq
+    whole = pq.write_table
+
+    def half(table, where, *args, **kwargs):
+        return whole(table.slice(0, table.num_rows // 2), where,
+                     *args, **kwargs)
+
+    monkeypatch.setattr(pq, "write_table", half)
+    result, numbers = _run(PARQUET)
+    assert result["correct"] is False
+    assert not correct.holds(numbers["q1.exact_mismatch"])   # count_order
+    assert not correct.holds(numbers["q6.rel_gap"])
+
+
+@pytest.mark.parametrize("cell", [SCANAGG, PARQUET])
+def test_an_operator_on_the_cpu_engine_is_not_correct(monkeypatch, cell):
     """The answer is right but the device path did not make it."""
     real = manifest.config_file
 
@@ -118,7 +139,7 @@ def test_an_operator_on_the_cpu_engine_is_not_correct(monkeypatch):
                                     "spark.rapids.tpu.sql.enabled": "false"}}
 
     monkeypatch.setattr(manifest, "config_file", on_cpu)
-    result, numbers = _run(SCANAGG)
+    result, numbers = _run(cell)
     assert result["correct"] is False
     assert numbers["cpu_execs"]["value"] > 0
     assert correct.holds(numbers["q1.rel_gap"])

@@ -19,7 +19,8 @@ def _metric(mf, name):
 def test_the_committed_manifest_is_valid(mf, capsys):
     assert manifest.problems_of(mf) == []
     assert run.main(["--validate"]) == 0
-    assert "valid: 3 cells" in capsys.readouterr().out
+    assert ("valid: 4 cells, 4 end-to-end and 25 per-layer metrics"
+            in capsys.readouterr().out)
 
 
 def test_pr22_mistake_is_refused(mf):
@@ -45,6 +46,7 @@ def test_pr22_mistake_is_refused(mf):
     (lambda mf: mf["workloads"][0].update(name="bad name"), "name"),
     (lambda mf: mf["configs"][0].update(source="x" * 201), "source"),
     (lambda mf: mf["workloads"].pop(2), "has no cell"),
+    (lambda mf: mf["workloads"].pop(3), "has no cell"),
     (lambda mf: _metric(mf, "first_call_s").update(why="because"), "keys"),
     (lambda mf: _metric(mf, "latency_p90_s").update(bound=0.5), "bound"),
     (lambda mf: _metric(mf, "upload_mb_setup").update(moves="nothing"),
