@@ -234,13 +234,15 @@ def served_leg(tpu, dfs, collected):
 
 def mesh_leg(tables, collected_q3):
     """Q3 sharded over four devices: mesh join + aggregate in the plan, a
-    scattered column really spread over four devices, same answer."""
+    scattered column really spread over four devices, same answer; the
+    tables stay on the mesh, so a second Q3 scatters none."""
     from spark_rapids_tpu.api import TpuSession
     from spark_rapids_tpu.api.dataframe import _iter_execs
     from spark_rapids_tpu.benchmarks.tpch_queries import QUERIES
     from spark_rapids_tpu.execs.base import ExecContext
     from spark_rapids_tpu.execs.mesh_execs import MeshScatterExec
     from spark_rapids_tpu.testing import assert_tables_equal
+    from spark_rapids_tpu.utils.metrics import TRANSFER_METRICS
     sess = TpuSession({
         **CONF, "spark.rapids.tpu.sql.mesh.enabled": "true",
         "spark.rapids.tpu.sql.mesh.numDevices": str(MESH_DEVICES)})
@@ -256,13 +258,19 @@ def mesh_leg(tables, collected_q3):
     scatters = [nd for nd in _iter_execs(sess.last_plan)
                 if isinstance(nd, MeshScatterExec)]
     check(scatters, f"mesh/Q3: no MeshScatterExec in the plan:\n{tree}")
-    # the narrowest scatter (customer): re-running it uploads the least
-    scatter = min(scatters, key=lambda nd: nd.children[0].size_estimate()
-                  or float("inf"))
-    (batch,) = list(scatter.execute(ExecContext(sess.conf)))
-    devices = batch.columns[0].data.sharding.device_set
-    check(len(devices) == MESH_DEVICES,
-          f"mesh/Q3: scattered column lives on {len(devices)} device(s)")
+    # where the scattered tables lie: the scan cache serves each scatter
+    # the batch the query read, so running them again uploads nothing
+    uploaded = TRANSFER_METRICS.snapshot()["transfer.upload_bytes"]
+    for scatter in scatters:
+        (batch,) = list(scatter.execute(ExecContext(sess.conf)))
+        devices = batch.columns[0].data.sharding.device_set
+        check(len(devices) == MESH_DEVICES,
+              f"mesh/Q3: scattered column lives on {len(devices)} device(s)")
+    again = QUERIES[3](dfs).collect()
+    assert_tables_equal(got, again)
+    moved = TRANSFER_METRICS.snapshot()["transfer.upload_bytes"] - uploaded
+    check(moved == 0, f"mesh/Q3: a second collect() scattered {moved} bytes "
+                      "of tables that should be resident")
     print(f"mesh: Q3 ok over {sorted(d.id for d in devices)} "
           f"smoke_wall_s={wall}", flush=True)
     return {"q3": wall}

@@ -23,6 +23,7 @@ the whole mesh, never per batch per device.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Iterator, List, Optional, Sequence, Tuple
 
@@ -51,10 +52,14 @@ from spark_rapids_tpu.parallel.mesh_batch import (MeshBatch, flatten_mesh,
                                                   scatter_arrow,
                                                   scatter_device_batch)
 
+from spark_rapids_tpu.utils import tracing as _tracing
+
 _SAMPLE_PER_SHARD = 512
 
 #: per-process log of mesh exchange sizings (count pre-pass results): the
-#: MapOutputStatistics analog, consumed by skew/capacity tests and debugging
+#: MapOutputStatistics analog, consumed by skew/capacity tests and debugging.
+#: Each entry is the dict a traced run records as the ``args`` of the
+#: exchange's ``mesh.exchange`` span (``exchange_stats``)
 EXCHANGE_STATS: list = []
 
 
@@ -132,12 +137,48 @@ class MeshExec(PhysicalExec):
 class MeshScatterExec(MeshExec):
     """Host rows -> mesh-sharded batch (the upload + partition step: the
     HostToDeviceExec role fused with the initial even distribution the
-    reference gets from Spark's input partitioning)."""
+    reference gets from Spark's input partitioning).
+
+    Directly over an in-memory scan the scattered batch is kept across
+    actions by the scan cache, as HostToDeviceExec keeps its upload: keyed
+    by the mesh too, each shard charged to the device that holds it."""
 
     def __init__(self, child: PhysicalExec, mesh: Mesh):
         super().__init__((child,), child.output, mesh)
 
     def execute(self, ctx: ExecContext) -> Iterator[MeshBatch]:
+        from spark_rapids_tpu import config as cfg
+        from spark_rapids_tpu.execs.cpu_execs import CpuLocalScanExec
+        child = self.children[0]
+        cached = "hit"
+        with _tracing.span("mesh.scatter", _tracing.LAYER_SHUFFLE) as sp:
+            if (isinstance(child, CpuLocalScanExec)
+                    and ctx.conf.get(cfg.SCAN_CACHE_ENABLED)):
+                from spark_rapids_tpu.memory.scan_cache import (
+                    derived_budget, get_cache)
+                smax = ctx.string_max_bytes
+
+                def build():
+                    nonlocal cached
+                    cached = "built"
+                    return scatter_arrow(child.table, self.mesh, smax)
+                # per-key latch: concurrent queries missing on the same
+                # table share ONE scatter instead of each paying the link
+                mb = get_cache(derived_budget(ctx.conf)).get_or_put(
+                    child.table, smax, build,
+                    cancel_check=ctx.check_cancelled, mesh=self.mesh)
+                child.count_output(mb.num_rows)
+            else:
+                cached = "built"
+                mb = self._scatter_child(ctx)
+            if sp is not None:
+                sp.note(rows=mb.num_rows, bytes=mb.device_size_bytes,
+                        shards=mb.n_dev, cached=cached)
+        self.count_output(mb.num_rows)
+        yield mb
+
+    def _scatter_child(self, ctx: ExecContext) -> MeshBatch:
+        """Whatever the host child yields, over all its partitions."""
         import pyarrow as pa
         child = self.children[0]
         tables = []
@@ -154,9 +195,7 @@ class MeshScatterExec(MeshExec):
             table = tables[0]
         else:
             table = pa.concat_tables(tables)
-        mb = scatter_arrow(table, self.mesh, ctx.string_max_bytes)
-        self.count_output(mb.num_rows)
-        yield mb
+        return scatter_arrow(table, self.mesh, ctx.string_max_bytes)
 
 
 @dataclass(frozen=True)
@@ -602,24 +641,7 @@ def _mesh_repartition(mb: MeshBatch, op_key: Tuple, pid_builder,
             return counts
         return fn
 
-    fnc = _shard_jit(mesh, base_key + ("count",), build_count,
-                     (P(DATA_AXIS),) + _specs(n_extra, P()) + _specs(nflat),
-                     P(DATA_AXIS))
-    cmat = np.asarray(fnc(rows, *extra_flat, *flatten_mesh(mb))).reshape(
-        n_dev, n_dev)
-    chunk_cap = max(bucket_capacity(int(cmat.max(initial=0))), 1)
-    recv = cmat.sum(axis=0).astype(np.int32)
-    out_cap = max(bucket_capacity(int(recv.max(initial=0))), 1)
-    # observability: the count pre-pass result that sized this exchange (the
-    # MapOutputStatistics role — skew/capacity-growth tests assert on it)
-    EXCHANGE_STATS.append({
-        "op": op_key[0], "chunk_cap": chunk_cap, "out_cap": out_cap,
-        "in_cap": cap, "recv_max": int(recv.max(initial=0)),
-        "recv_min": int(recv.min(initial=0)), "rows": int(mb.num_rows)})
-    if len(EXCHANGE_STATS) > 256:
-        del EXCHANGE_STATS[:128]
-
-    def build_exchange(chunk_cap=chunk_cap, out_cap=out_cap):
+    def build_exchange(chunk_cap, out_cap):
         def fn(rows, *args):
             extra = args[:n_extra]
             colvs = unflatten_colvs(schema, args[n_extra:])
@@ -662,15 +684,67 @@ def _mesh_repartition(mb: MeshBatch, op_key: Tuple, pid_builder,
             return tuple(outs)
         return fn
 
-    fne = _shard_jit(mesh, base_key + ("exchange", chunk_cap, out_cap),
-                     build_exchange,
+    fnc = _shard_jit(mesh, base_key + ("count",), build_count,
                      (P(DATA_AXIS),) + _specs(n_extra, P()) + _specs(nflat),
-                     (P(DATA_AXIS),) + _specs(nflat))
-    res = fne(rows, *extra_flat, *flatten_mesh(mb))
-    new_rows = np.asarray(res[0]).astype(np.int32)
+                     P(DATA_AXIS))
+    # the two child spans run from the program's call to the host's read of
+    # its result, a read the sizing and the row counts need anyway
+    with _tracing.span("mesh.exchange", _tracing.LAYER_SHUFFLE) as exchange:
+        with _tracing.span("mesh.exchange.count", _tracing.LAYER_SHUFFLE):
+            cmat = np.asarray(
+                fnc(rows, *extra_flat, *flatten_mesh(mb))).reshape(
+                    n_dev, n_dev)
+        # observability: the count pre-pass result that sized this exchange
+        # (the MapOutputStatistics role — skew/capacity-growth tests assert
+        # on it)
+        stats = exchange_stats(op_key[0], cmat, mb.row_bytes, cap)
+        chunk_cap, out_cap = stats["chunk_cap"], stats["out_cap"]
+        EXCHANGE_STATS.append(stats)
+        if len(EXCHANGE_STATS) > 256:
+            del EXCHANGE_STATS[:128]
+        if exchange is not None:
+            exchange.note(**stats)
+        fne = _shard_jit(
+            mesh, base_key + ("exchange", chunk_cap, out_cap),
+            functools.partial(build_exchange, chunk_cap, out_cap),
+            (P(DATA_AXIS),) + _specs(n_extra, P()) + _specs(nflat),
+            (P(DATA_AXIS),) + _specs(nflat))
+        with _tracing.span("mesh.exchange.move", _tracing.LAYER_SHUFFLE):
+            res = fne(rows, *extra_flat, *flatten_mesh(mb))
+            new_rows = np.asarray(res[0]).astype(np.int32)
     assert int(new_rows.sum()) == mb.num_rows, (
         f"mesh repartition lost rows: {new_rows.sum()} != {mb.num_rows}")
     return MeshBatch(schema, mesh_columns(schema, res[1:]), new_rows, mesh)
+
+
+def exchange_stats(op: str, cmat: np.ndarray, row_bytes: int,
+                   in_cap: int) -> dict:
+    """What one repartition moves, from its count matrix (``cmat[s, d]``:
+    live rows of shard s whose destination is shard d): the capacities the
+    exchange is sized with, and the ``args`` of its ``mesh.exchange`` span.
+
+    ``bytes`` is the least that must cross between devices (the rows off
+    the diagonal, at the row's bytes); ``wire_bytes`` what ``all_to_all``
+    ships for them: every (source, destination) pair of different shards a
+    chunk of ``chunk_cap`` rows, live or padding; ``max_shard_bytes`` the
+    most of ``bytes`` that any one shard sends or receives."""
+    n_dev = cmat.shape[0]
+    cmat = cmat.astype(np.int64)
+    recv = cmat.sum(axis=0)
+    stay = np.diagonal(cmat)
+    sent_off, recv_off = cmat.sum(axis=1) - stay, recv - stay
+    chunk_cap = max(bucket_capacity(int(cmat.max(initial=0))), 1)
+    return {
+        "op": op, "rows": int(cmat.sum()),
+        "moved_rows": int(sent_off.sum()),
+        "bytes": int(sent_off.sum()) * row_bytes,
+        "wire_bytes": n_dev * (n_dev - 1) * chunk_cap * row_bytes,
+        "max_shard_bytes": int(max(sent_off.max(initial=0),
+                                   recv_off.max(initial=0))) * row_bytes,
+        "chunk_cap": chunk_cap,
+        "out_cap": max(bucket_capacity(int(recv.max(initial=0))), 1),
+        "in_cap": in_cap, "recv_max": int(recv.max(initial=0)),
+        "recv_min": int(recv.min())}
 
 
 def _hash_pid_builder(keys: Tuple[Expression, ...], n_dev: int):
@@ -1130,13 +1204,7 @@ class MeshHashAggregateExec(MeshExec):
 def _mesh_batch_bytes(mb: MeshBatch) -> int:
     """Actual data bytes of the LIVE rows (per-row width x true row count) —
     the MapOutputStatistics role for runtime join adaptivity."""
-    row_bytes = 0
-    for c in mb.columns:
-        width = int(np.prod(c.data.shape[1:])) if c.data.ndim > 1 else 1
-        row_bytes += c.data.dtype.itemsize * width + 1  # + validity byte
-        if c.lengths is not None:
-            row_bytes += 4
-    return int(mb.num_rows) * row_bytes
+    return int(mb.num_rows) * mb.row_bytes
 
 
 def _gather_colv(v: ColV) -> ColV:

@@ -3,9 +3,16 @@
 Repeated actions over the same DataFrame re-run the whole physical plan,
 including the host->device upload of the scanned arrow table — by far the
 dominant cost on a remote-attached chip. This cache keeps the uploaded
-DeviceBatch alive across actions, keyed by the identity of the (immutable)
+batch alive across actions, keyed by the identity of the (immutable)
 arrow table, with LRU eviction over a device-byte budget that is read from
 the device (``derived_budget``), not from a constant.
+
+An entry is a ``DeviceBatch`` (one device holds all of it) or, under a
+mesh plan, a ``MeshBatch`` (each of the mesh's devices holds a shard). The
+budget is one device's, so an entry is charged what it puts on its fullest
+device (``charged_bytes``): a DeviceBatch all of its bytes, a MeshBatch its
+bytes over the mesh's devices. The first device of a mesh is the process's
+default device, so the sum of the charges is what that device holds.
 
 Reference analog: the device tier of the spillable buffer store
 (RapidsDeviceMemoryStore.scala / RapidsBufferCatalog.scala) which keeps hot
@@ -17,13 +24,20 @@ from __future__ import annotations
 import threading
 import weakref
 from collections import OrderedDict
-from typing import Optional, Tuple
+from typing import Optional
 
 from spark_rapids_tpu.utils import tracing as _tracing
 
 
+def charged_bytes(batch) -> int:
+    """What the batch puts on the fullest device that holds part of it."""
+    return -(-batch.device_size_bytes // getattr(batch, "n_dev", 1))
+
+
 class DeviceScanCache:
-    """LRU over (table identity, string width) -> DeviceBatch.
+    """LRU over (table identity, string width, mesh) -> DeviceBatch, or
+    MeshBatch where ``mesh`` is one (None: the single-device form), so a
+    mesh session and a single-device one never share an entry.
 
     Identity is checked with a weakref to the arrow table: a dead or replaced
     object at the same address can never produce a false hit, and a table
@@ -35,14 +49,16 @@ class DeviceScanCache:
     def __init__(self, max_bytes: int):
         self.max_bytes = max_bytes
         self._lock = threading.Lock()
-        # key -> (weakref to table, DeviceBatch, nbytes)
-        self._entries: "OrderedDict[Tuple[int, int], tuple]" = OrderedDict()
+        # (table id, string width, mesh or None) ->
+        # (weakref to table, batch, bytes charged)
+        self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()
         #: per-key in-flight upload latch: two queries missing on the same
         #: table concurrently must share ONE upload, not pay the host link
         #: twice (the concurrent-miss double-insert fix)
         self._inflight: dict = {}
 
-    def get_or_put(self, table, smax: int, builder, cancel_check=None):
+    def get_or_put(self, table, smax: int, builder, cancel_check=None,
+                   mesh=None):
         """Hit -> cached batch. Miss -> exactly one caller runs ``builder``
         (the upload) while concurrent missers wait on the key's latch and
         then read the inserted entry. If the builder fails, its exception
@@ -53,11 +69,11 @@ class DeviceScanCache:
         periodically while blocked on another query's upload, so a
         cancelled query unwinds instead of waiting out a transfer it will
         never use — the same contract as semaphore admission."""
-        key = (id(table), smax)
+        key = (id(table), smax, mesh)
         while True:
             mine = False
             with self._lock:
-                got = self._get_locked(table, smax)
+                got = self._get_locked(table, key)
                 if got is None:
                     ev = self._inflight.get(key)
                     if ev is None:
@@ -74,7 +90,7 @@ class DeviceScanCache:
             if mine:
                 try:
                     batch = builder()
-                    kept = self.put(table, smax, batch)
+                    kept = self.put(table, smax, batch, mesh)
                     # not_kept: built, but over the budget — the next
                     # query (and any waiter on this latch) uploads again
                     self._instant("scan_cache.miss" if kept
@@ -90,16 +106,16 @@ class DeviceScanCache:
                         cancel_check()
 
     def _instant(self, name: str, batch) -> None:
-        """``held`` (bytes in the cache after the call) beside ``budget``
-        says why an entry was or was not kept."""
+        """``held`` (bytes charged in the cache after the call) beside
+        ``budget`` says why an entry was or was not kept; ``bytes`` is the
+        entry's own charge."""
         if _tracing.TRACER.on:
             _tracing.instant(name, _tracing.LAYER_TRANSFER,
-                             {"bytes": batch.device_size_bytes,
+                             {"bytes": charged_bytes(batch),
                               "held": self.total_bytes(),
                               "budget": self.max_bytes})
 
-    def _get_locked(self, table, smax: int):
-        key = (id(table), smax)
+    def _get_locked(self, table, key):
         entry = self._entries.get(key)
         if entry is None:
             return None
@@ -110,22 +126,22 @@ class DeviceScanCache:
         self._entries.move_to_end(key)
         return batch
 
-    def get(self, table, smax: int):
+    def get(self, table, smax: int, mesh=None):
         with self._lock:
-            return self._get_locked(table, smax)
+            return self._get_locked(table, (id(table), smax, mesh))
 
-    def put(self, table, smax: int, batch) -> bool:
-        """Insert; False when the batch was not kept (over the whole
-        budget, or the table cannot be weakly referenced)."""
+    def put(self, table, smax: int, batch, mesh=None) -> bool:
+        """Insert; False when the batch was not kept (its charge is over
+        the whole budget, or the table cannot be weakly referenced)."""
         try:
             ref = weakref.ref(table)
         except TypeError:  # object not weakref-able: skip caching
             return False
-        nbytes = batch.device_size_bytes
+        nbytes = charged_bytes(batch)
         if nbytes > self.max_bytes:
             return False
         with self._lock:
-            self._entries[(id(table), smax)] = (ref, batch, nbytes)
+            self._entries[(id(table), smax, mesh)] = (ref, batch, nbytes)
             self._evict_locked()
         return True
 
@@ -145,15 +161,17 @@ class DeviceScanCache:
         return sum(n for _, _, n in self._entries.values())
 
     def total_bytes(self) -> int:
-        """Device bytes currently held — the device store counts these toward
-        its budget so proactive spill decisions see cached scans."""
+        """Bytes the entries are charged, which is what the fullest device
+        holds of them — the device store counts these toward its budget so
+        proactive spill decisions see cached scans of either kind."""
         with self._lock:
             return self._total()
 
     def shrink_by(self, nbytes: int) -> int:
-        """Evict LRU entries until at least nbytes are freed (or the cache is
-        empty); returns bytes freed. Called by the device store's admission
-        path — cached scans are re-uploadable, so they go before real spills."""
+        """Evict LRU entries, of either kind, until at least nbytes of
+        charge are freed (or the cache is empty); returns the charge freed.
+        Called by the device store's admission path — cached scans are
+        re-uploadable, so they go before real spills."""
         freed = 0
         with self._lock:
             while self._entries and freed < nbytes:
@@ -201,8 +219,9 @@ def peek_cache() -> Optional[DeviceScanCache]:
 
 
 def get_cache(max_bytes: int) -> DeviceScanCache:
-    """Process-wide cache (one device per process, like the executor-wide
-    device store); the budget follows the most recent session's conf. The
+    """Process-wide cache (one budget, a device's, like the executor-wide
+    device store; a mesh plan's entries are charged per device against
+    it); the budget follows the most recent session's conf. The
     eviction sweep runs here too, so dead tables and budget shrinks are
     reclaimed even on hit-only workloads."""
     global _cache

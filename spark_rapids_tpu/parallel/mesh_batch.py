@@ -14,6 +14,7 @@ instead of a UCX transfer (shuffle-plugin/.../ucx/UCX.scala:53).
 """
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from typing import List, Optional, Sequence, Tuple
 
@@ -29,6 +30,8 @@ from spark_rapids_tpu.columnar.batch import DeviceBatch, _arrow_to_staged
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.columnar.dtypes import DType, Schema, bucket_capacity
 from spark_rapids_tpu.parallel.mesh import DATA_AXIS
+from spark_rapids_tpu.utils import metrics as um
+from spark_rapids_tpu.utils import tracing as _tracing
 
 
 @dataclass(frozen=True)
@@ -58,6 +61,23 @@ class MeshBatch:
     @property
     def capacity(self) -> int:
         return self.columns[0].capacity if self.columns else 0
+
+    @property
+    def device_size_bytes(self) -> int:
+        """Bytes over all shards; each device holds 1/n_dev of them."""
+        return sum(c.device_size_bytes for c in self.columns)
+
+    @property
+    def row_bytes(self) -> int:
+        """One row's bytes over every column, validity byte and string
+        length included: what moving a row between shards must carry."""
+        total = 0
+        for c in self.columns:
+            width = int(np.prod(c.data.shape[1:])) if c.data.ndim > 1 else 1
+            total += c.data.dtype.itemsize * width + 1
+            if c.lengths is not None:
+                total += 4
+        return total
 
     def rows_dev(self):
         """rows_per_shard as a device array sharded one-per-shard (the shape
@@ -102,47 +122,82 @@ def staged_column_arrays(dtype: DType, col, string_max_bytes: int):
     return data, validity, lengths
 
 
-def scatter_arrow(table: pa.Table, mesh: Mesh, string_max_bytes: int
-                  ) -> MeshBatch:
+def _padded_global_arrays(staged, rows, per: int, local_cap: int) -> tuple:
+    """One column's staged (data, validity, lengths) -> the global arrays a
+    sharded device_put takes: shard d's ``rows[d]`` rows (``per`` to a
+    shard, in the table's order) at the head of its ``local_cap`` slots,
+    zeros behind them; no lengths array where the column has none."""
+    out = []
+    for a in staged:
+        if a is None:
+            continue
+        g = np.zeros((len(rows) * local_cap,) + a.shape[1:], dtype=a.dtype)
+        for d, live in enumerate(rows):
+            g[d * local_cap:d * local_cap + live] = a[d * per:d * per + live]
+        out.append(g)
+    return tuple(out)
+
+
+def scatter_arrow(table: pa.Table, mesh: Mesh, string_max_bytes: int,
+                  max_inflight: int = 2) -> MeshBatch:
     """Host arrow table -> mesh batch: rows split contiguously across shards
     (shard-major order preserves the table's row order end to end), each shard
     padded to a shared power-of-two local capacity, one sharded device_put per
-    column buffer."""
+    column buffer. At most ``max_inflight`` columns are in flight: the next
+    column stages on the host while the devices take the last.
+
+    Counted and recorded as ``columnar/transfer.upload_table`` is: the transfer
+    counters, ``transfer.upload`` over the whole call and under it
+    ``upload.stage`` per column (arrow -> numpy -> padded global arrays, and
+    the enqueue of their device_put) and ``upload.wait`` per bounded wait."""
     table = table.combine_chunks()
     schema = Schema.from_pa(table.schema)
     n = table.num_rows
     n_dev = int(mesh.devices.size)
     per = -(-n // n_dev) if n else 0
     local_cap = max(bucket_capacity(per), 1)
-    total = n_dev * local_cap
     rows = np.zeros(n_dev, dtype=np.int32)
     for d in range(n_dev):
         rows[d] = max(0, min(per, n - d * per))
 
     sharding = NamedSharding(mesh, P(DATA_AXIS))
     cols: List[DeviceColumn] = []
-    for i, f in enumerate(schema):
-        data, validity, lengths = staged_column_arrays(f.dtype,
-                                                       table.column(i),
-                                                       string_max_bytes)
-        gdata = np.zeros((total,) + data.shape[1:], dtype=data.dtype)
-        gvalid = np.zeros(total, dtype=bool)
-        glen = (np.zeros(total, dtype=np.int32) if lengths is not None
-                else None)
-        for d in range(n_dev):
-            if rows[d] == 0:
-                continue
-            src = slice(d * per, d * per + rows[d])
-            dst = slice(d * local_cap, d * local_cap + rows[d])
-            gdata[dst] = data[src]
-            gvalid[dst] = validity[src]
-            if glen is not None:
-                glen[dst] = lengths[src]
-        up = jax.device_put(
-            (gdata, gvalid) + ((glen,) if glen is not None else ()), sharding)
-        cols.append(DeviceColumn(f.dtype, up[0], up[1],
-                                 up[2] if glen is not None else None))
-    return MeshBatch(schema, tuple(cols), rows, mesh)
+    inflight: List[tuple] = []
+    busy_s = 0.0
+    tracing = _tracing.TRACER.on
+    with _tracing.span("transfer.upload", _tracing.LAYER_TRANSFER,
+                       {"rows": n, "chunks": len(schema), "shards": n_dev}
+                       if tracing else None) as upload:
+        for i, f in enumerate(schema):
+            t0 = time.perf_counter()
+            with _tracing.span("upload.stage", _tracing.LAYER_TRANSFER,
+                               {"rows": n, "column": f.name,
+                                "inflight": len(inflight)}
+                               if tracing else None) as stage:
+                staged = _padded_global_arrays(
+                    staged_column_arrays(f.dtype, table.column(i),
+                                         string_max_bytes),
+                    rows, per, local_cap)
+                up = jax.device_put(staged, sharding)
+                col = DeviceColumn(f.dtype, *up)
+                if stage is not None:
+                    stage.note(bytes=col.device_size_bytes)
+            cols.append(col)
+            inflight.append(up)
+            # bounded: block on the OLDEST column, so that the host holds
+            # the staged arrays of max_inflight columns and no more
+            while len(inflight) >= max_inflight:
+                with _tracing.span("upload.wait", _tracing.LAYER_TRANSFER):
+                    jax.block_until_ready(inflight.pop(0))
+            busy_s += time.perf_counter() - t0
+        out = MeshBatch(schema, tuple(cols), rows, mesh)
+        if upload is not None:
+            upload.note(bytes=out.device_size_bytes)
+    m = um.TRANSFER_METRICS
+    m[um.TRANSFER_UPLOAD_BYTES].add(out.device_size_bytes)
+    m[um.TRANSFER_UPLOAD_SECONDS].add(busy_s)
+    m[um.TRANSFER_UPLOAD_CHUNKS].add(len(cols))
+    return out
 
 
 def scatter_device_batch(db: DeviceBatch, mesh: Mesh) -> MeshBatch:
@@ -151,7 +206,6 @@ def scatter_device_batch(db: DeviceBatch, mesh: Mesh) -> MeshBatch:
     mesh pipeline). This is a deliberate host hop and counts as one —
     in-mesh exchanges must never route through here (host_hop_bytes == 0 on
     the all_to_all path is a CI assert)."""
-    from spark_rapids_tpu.utils import metrics as um
     um.TRANSFER_METRICS[um.TRANSFER_HOST_HOP_BYTES].add(db.device_size_bytes)
     return scatter_arrow(db.to_arrow(), mesh, _string_width(db))
 
@@ -196,11 +250,16 @@ def gather_mesh(mb: MeshBatch) -> DeviceBatch:
         return fn
 
     fn = _cached_jit(key, build)
-    res = fn(rows, *flatten_mesh(mb))
-    dev = jax.devices()[0]
-    placed = jax.device_put(list(res), dev)
-    cols = mesh_columns(mb.schema, placed)
-    return DeviceBatch(mb.schema, cols, total_rows)
+    with _tracing.span("mesh.gather", _tracing.LAYER_SHUFFLE) as sp:
+        res = fn(rows, *flatten_mesh(mb))
+        dev = jax.devices()[0]
+        placed = jax.device_put(list(res), dev)
+        out = DeviceBatch(mb.schema, mesh_columns(mb.schema, placed),
+                          total_rows)
+        if sp is not None:
+            sp.note(rows=total_rows, bytes=out.device_size_bytes,
+                    shards=n_dev)
+    return out
 
 
 def replicate_device_batch(db: DeviceBatch, mesh: Mesh) -> DeviceBatch:
@@ -209,10 +268,14 @@ def replicate_device_batch(db: DeviceBatch, mesh: Mesh) -> DeviceBatch:
     XLA broadcasts the buffers over ICI)."""
     sharding = NamedSharding(mesh, P())
     cols = []
-    for c in db.columns:
-        data = jax.device_put(c.data, sharding)
-        validity = jax.device_put(c.validity, sharding)
-        lengths = (jax.device_put(c.lengths, sharding)
-                   if c.lengths is not None else None)
-        cols.append(DeviceColumn(c.dtype, data, validity, lengths))
+    with _tracing.span("mesh.replicate", _tracing.LAYER_SHUFFLE,
+                       {"rows": db.num_rows, "bytes": db.device_size_bytes,
+                        "shards": int(mesh.devices.size)}
+                       if _tracing.TRACER.on else None):
+        for c in db.columns:
+            data = jax.device_put(c.data, sharding)
+            validity = jax.device_put(c.validity, sharding)
+            lengths = (jax.device_put(c.lengths, sharding)
+                       if c.lengths is not None else None)
+            cols.append(DeviceColumn(c.dtype, data, validity, lengths))
     return DeviceBatch(db.schema, tuple(cols), db.num_rows)
