@@ -547,7 +547,8 @@ class DataFrame:
         with _tracing.span("plan", _tracing.LAYER_PLAN) as sp:
             logical = (prepared if prepared is not None
                        else self.session.cache_manager.prepare(self._plan))
-            cpu_plan = plan_physical(logical, self.session.conf)
+            cpu_plan = plan_physical(logical, self.session.conf,
+                                     note=sp.note if sp is not None else None)
             overrides = TpuOverrides(self.session.conf)
             final = overrides.apply(cpu_plan)
             if self.session.conf.get(_cfg.MESH_ENABLED):

@@ -21,7 +21,9 @@ from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.columnar.dtypes import DType, Field, Schema
 from spark_rapids_tpu.columnar.host import HostBatch, HostColumn
-from spark_rapids_tpu.exprs.core import ColV, EvalCtx, Expression
+from spark_rapids_tpu.exprs.core import (BoundReference, ColV, EvalCtx,
+                                         Expression)
+from spark_rapids_tpu.exprs.misc import Alias
 
 
 def batch_to_colvs(xp, batch) -> List[ColV]:
@@ -55,6 +57,16 @@ def output_schema(exprs: Sequence[Expression]) -> Schema:
         names.append(n)
     return Schema([Field(n, e.dtype(), e.nullable())
                    for n, e in zip(names, exprs)])
+
+
+def reference_ordinals(exprs: Sequence[Expression]) -> Optional[List[int]]:
+    """The input ordinals when every expression is a plain column reference
+    (renamed or not), else None: such a projection selects columns and needs
+    no program (the planner's column pruning puts them on top of scans)."""
+    refs = [e.c if isinstance(e, Alias) else e for e in exprs]
+    if not all(isinstance(r, BoundReference) for r in refs):
+        return None
+    return [r.ordinal for r in refs]
 
 
 # ------------------------------------------------------------------ CPU (eager)
@@ -132,6 +144,11 @@ def eval_exprs_device(exprs: Sequence[Expression], batch: DeviceBatch,
                       ctx_attrs: Optional[dict] = None) -> DeviceBatch:
     """Jitted evaluation of an expression list over a device batch."""
     exprs = tuple(exprs)
+    ordinals = reference_ordinals(exprs)
+    if ordinals is not None:
+        return DeviceBatch(output_schema(exprs),
+                           tuple(batch.columns[i] for i in ordinals),
+                           batch.num_rows)
     attrs = tuple(sorted((ctx_attrs or {}).items()))
     key = (exprs, batch.schema, batch.capacity, string_max_bytes, attrs)
     fn = _PROGRAM_CACHE.get_or_build(

@@ -37,7 +37,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.columnar.dtypes import DType, Schema, bucket_capacity
 from spark_rapids_tpu.execs.base import ExecContext, PhysicalExec
-from spark_rapids_tpu.execs.evaluator import colv_to_column, output_schema
+from spark_rapids_tpu.execs.evaluator import (colv_to_column, output_schema,
+                                             reference_ordinals)
 from spark_rapids_tpu.execs.tpu_execs import _PROGRAM_CACHE
 from spark_rapids_tpu.serving.program_cache import named_jit
 from spark_rapids_tpu.exprs.core import (ColV, EvalCtx, Expression, flat_len,
@@ -503,7 +504,16 @@ class MeshProjectExec(MeshExec):
         self.exprs = exprs
 
     def execute(self, ctx: ExecContext) -> Iterator[MeshBatch]:
+        ordinals = reference_ordinals(self.exprs)
         for mb in self.children[0].execute(ctx):
+            if ordinals is not None:
+                # plain references select the shards' columns: no program
+                out = MeshBatch(self.output,
+                                tuple(mb.columns[i] for i in ordinals),
+                                mb.rows_per_shard, self.mesh)
+                self.count_output(out.num_rows)
+                yield out
+                continue
             cap = mb.local_capacity
             schema = self.children[0].output
             smax = ctx.string_max_bytes

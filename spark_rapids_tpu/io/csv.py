@@ -43,7 +43,9 @@ def _read_table(path: str, schema: Schema, options: Dict[str, str]) -> pa.Table:
     read, parse, convert = _read_options(options)
     convert = pacsv.ConvertOptions(
         null_values=convert.null_values, strings_can_be_null=True,
-        column_types={f.name: f.dtype.pa_type() for f in schema})
+        column_types={f.name: f.dtype.pa_type() for f in schema},
+        # the scan's columns only: pruning may have narrowed the schema
+        include_columns=schema.names())
     t = pacsv.read_csv(path, read_options=read, parse_options=parse,
                        convert_options=convert)
     return t.cast(schema.to_pa())

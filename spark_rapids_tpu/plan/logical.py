@@ -7,7 +7,7 @@ acceleration is a *physical plan* rewrite, not a frontend.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields, replace
 from typing import Optional, Sequence, Tuple
 
 import pyarrow as pa
@@ -24,6 +24,22 @@ class LogicalPlan:
 
     def schema(self) -> Schema:
         raise NotImplementedError
+
+
+def with_children(node: LogicalPlan, kids: Sequence[LogicalPlan]
+                  ) -> LogicalPlan:
+    """``node`` (a dataclass) with its child plans replaced by ``kids``, in
+    the order of its fields, which is the order of ``children``."""
+    it = iter(kids)
+    reps = {}
+    for f in fields(node):
+        v = getattr(node, f.name)
+        if isinstance(v, LogicalPlan):
+            reps[f.name] = next(it)
+        elif isinstance(v, tuple) and v and all(
+                isinstance(x, LogicalPlan) for x in v):
+            reps[f.name] = tuple(next(it) for _ in v)
+    return replace(node, **reps)
 
 
 @dataclass

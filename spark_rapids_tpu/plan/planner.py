@@ -18,10 +18,19 @@ from spark_rapids_tpu.io.parquet import CpuParquetScanExec
 from spark_rapids_tpu.plan import logical as lp
 
 
-def plan_physical(plan: lp.LogicalPlan, conf: TpuConf) -> PhysicalExec:
-    """Plan + EnsureRequirements (distribution requirements are satisfied by
-    inserting single-partition exchanges, Spark's EnsureRequirements role)."""
+def plan_physical(plan: lp.LogicalPlan, conf: TpuConf,
+                  note=None) -> PhysicalExec:
+    """Column pruning + plan + EnsureRequirements (distribution requirements
+    are satisfied by inserting single-partition exchanges, Spark's
+    EnsureRequirements role). ``note``: the ``plan`` span's, told how far the
+    pruning engaged."""
     from spark_rapids_tpu import config as cfg
+    from spark_rapids_tpu.plan.pruning import prune_columns
+    # before the UDF compiler: it binds UDF arguments to ordinals of the
+    # child's schema, which must be the narrowed one
+    plan, scan_columns, kept = prune_columns(plan)
+    if note is not None:
+        note(scan_columns=scan_columns, scan_columns_kept=kept)
     if conf.get(cfg.UDF_COMPILER_ENABLED):
         from spark_rapids_tpu.udf import compile_plan_udfs
         plan = compile_plan_udfs(plan)
@@ -107,16 +116,7 @@ def _resolve_input_file_meta(plan: lp.LogicalPlan) -> lp.LogicalPlan:
         if not extended and all(
                 a is b for a, b in zip(kids, node.children)):
             return node
-        reps = {}
-        ki = iter(kids)
-        for f in dataclasses.fields(node):
-            v = getattr(node, f.name)
-            if isinstance(v, lp.LogicalPlan):
-                reps[f.name] = next(ki)
-            elif isinstance(v, tuple) and v and all(
-                    isinstance(x, lp.LogicalPlan) for x in v):
-                reps[f.name] = tuple(next(ki) for _ in v)
-        return dataclasses.replace(node, **reps)
+        return lp.with_children(node, kids)
 
     out = flip(plan)
     # the hidden columns must never surface in user-visible output (they

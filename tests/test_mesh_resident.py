@@ -36,7 +36,9 @@ MESH4 = {
     "spark.rapids.tpu.sql.mesh.numDevices": str(N_DEV),
 }
 #: at this scale every table is under the broadcast threshold: 1 byte keeps
-#: the shuffled joins that the plan holds at SF1
+#: both joins shuffled. At SF1 only the second is (6 M-row ``lineitem``):
+#: ``customer`` pruned to its two columns estimates under the threshold, so
+#: the first is a ``MeshBroadcastHashJoinExec`` there
 SHUFFLED = {"spark.rapids.tpu.sql.broadcastJoinThreshold.bytes": "1"}
 TRACE = {"spark.rapids.tpu.trace.enabled": "true"}
 
@@ -131,6 +133,26 @@ def test_every_exchange_of_q3_says_what_it_moved(two_q3):
                    if r.name.startswith("mesh."))
     (gather,) = _spans(second, "mesh.gather")
     assert gather.args["rows"] == 10 and gather.args["shards"] == N_DEV
+
+
+def test_q3_moves_pruned_rows_and_its_projections_call_no_program(two_q3):
+    second = two_q3[1]
+    # a row's bytes on the wire: 8 + 1 a long or a double, 4 + 1 a date or
+    # an int. customer: its key; orders: two keys, date, priority; their
+    # join: what the aggregate and the next join read of it; lineitem: key,
+    # price, discount; the sort: the answer's four columns
+    widths = {}
+    for s in _spans(second, "mesh.exchange"):
+        assert s.args["bytes"] % s.args["moved_rows"] == 0
+        widths.setdefault(s.args["op"], []).append(
+            s.args["bytes"] // s.args["moved_rows"])
+    assert {op: sorted(w) for op, w in widths.items()} == {
+        "mjoin_lpart": [9, 19], "mjoin_rpart": [27, 28], "msort_part": [28]}
+    # the pass's projections, and the select that reorders the answer, are
+    # plain references: the shards' columns are selected, nothing is called
+    assert second["plan"].count("MeshProjectExec") >= 6
+    assert not _spans(second, "program.mproject")
+    assert _spans(second, "program.mfilter")
 
 
 def test_nothing_of_the_mesh_is_recorded_with_tracing_off(tables,
