@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Nightly pipeline (jenkins/spark-tests.sh analog): the FULL suite including
 # the benchmark-correctness runs (TPC-H/DS/xBB/Mortgage, mesh TPC-H/scale,
-# cluster two-process), then device benchmarks when a TPU is attached.
+# cluster two-process), then the on-chip smoke when RUN_TPU_BENCH=1.
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -588,159 +588,6 @@ print(f"out-of-core chaos alloc_fail: partitions="
       f"pressure={mm['memory.pressure_events']}")
 DeviceManager.shutdown()
 print("out-of-core chaos ok")
-PY
-
-echo "== bench smoke (transfer-pipeline + compression breakdown, cpu backend) =="
-BENCH_ITERS=1 BENCH_SCALE=0.05 python bench.py | tail -n 1 > /tmp/bench_smoke.json
-python - /tmp/bench_smoke.json <<'PY'
-import json, sys
-out = json.load(open(sys.argv[1]))
-pipe = out["breakdown"]["pipeline"]
-for key in ("chunk_rows", "upload_chunked_s", "per_chunk_upload_s",
-            "upload_overlap_efficiency", "inflight_high_water",
-            "end_to_end_cold_collect_s"):
-    assert key in pipe, f"missing pipeline breakdown key {key}: {pipe}"
-assert pipe["upload_overlap_efficiency"] > 0, pipe
-comp = out["breakdown"]["compression"]
-for key in ("link_bytes_encoded", "link_bytes_decoded", "link_bytes_ratio",
-            "effective_gb_per_sec", "encoded_domain_ops"):
-    assert key in comp, f"missing compression breakdown key {key}: {comp}"
-assert comp["link_bytes_ratio"] < 1.0, comp
-assert comp["encoded_domain_ops"] >= 1, comp
-fusion = out["breakdown"]["fusion"]
-for key in ("q1_fused_stage_count", "q1_ops_per_fused_stage",
-            "batches_not_materialized", "q1_fused_vs_unfused_x",
-            "bit_identical", "repeat_hit_rate", "coverage"):
-    assert key in fusion, f"missing fusion breakdown key {key}: {fusion}"
-# whole-stage fusion acceptance: Q1 gets >= 1 fused stage whose interior
-# batches never materialized, fused collect is bit-identical, repeat
-# submission serves fused programs from the cross-query cache, and the
-# 129-query plan sweep keeps coverage a number (93/129 at introduction)
-assert fusion["q1_fused_stage_count"] >= 1, fusion
-assert fusion["batches_not_materialized"] > 0, fusion
-assert fusion["bit_identical"] is True, fusion
-assert fusion["repeat_hit_rate"] >= 0.99, fusion
-cov = fusion["coverage"]
-assert cov["queries"] >= 129, cov
-assert cov["fused_queries"] >= 60 and cov["fraction"] >= 0.5, cov
-ooc = out["breakdown"]["out_of_core"]
-for qname in ("q1", "q3_shaped"):
-    sec = ooc[qname]
-    for key in ("ample_rows_per_sec", "quarter_budget_rows_per_sec",
-                "spill_partitions", "recursion_depth_peak",
-                "bytes_spilled_to_host", "bytes_spilled_to_disk",
-                "results_match"):
-        assert key in sec, f"missing out_of_core {qname} key {key}: {sec}"
-    # out-of-core acceptance: the quarter-budget run grace-partitions,
-    # actually spills, completes, and matches the ample-budget results
-    assert sec["results_match"] is True, sec
-    assert sec["spill_partitions"] >= 2, sec
-    assert sec["quarter_budget_rows_per_sec"] > 0, sec
-assert (ooc["q1"]["bytes_spilled_to_host"]
-        + ooc["q3_shaped"]["bytes_spilled_to_host"]) > 0, ooc
-ad = out["breakdown"]["adaptive"]
-for key in ("skewed_join_off_s", "skewed_join_on_s", "speedup_x",
-            "bit_identical", "skew_splits", "coalesced_partitions",
-            "refused_stages", "broadcast_switches"):
-    assert key in ad, f"missing adaptive breakdown key {key}: {ad}"
-# adaptive-v2 acceptance (ROADMAP item 2): the Zipf-skewed join under a
-# constrained budget runs >= 1.5x faster with skew-split + observed-size
-# grace fanout ON, bit-identical; the skew split, post-AQE re-fusion and
-# dynamic broadcast switch each fired on their probe queries
-assert ad["bit_identical"] is True, ad
-assert ad["speedup_x"] >= 1.5, ad
-assert ad["skew_splits"] >= 1, ad
-assert ad["coalesced_partitions"] >= 1, ad
-assert ad["refused_stages"] >= 1, ad
-assert ad["broadcast_switches"] >= 1, ad
-obs = out["breakdown"]["observability"]
-for key in ("q1_warm_off_s", "q1_warm_on_s", "tracing_on_overhead_x",
-            "disabled_hook_ns", "tracing_off_overhead_pct", "spans_total",
-            "spans_by_layer", "export_valid", "explain_analyze_ok"):
-    assert key in obs, f"missing observability breakdown key {key}: {obs}"
-# observability acceptance: the traced Q1 exports valid Chrome trace JSON
-# with spans from the exec/transfer/serving layers (memory spans need the
-# grace path — premerge's forced-partition smoke covers that layer), the
-# EXPLAIN ANALYZE render carries observed rows+wall, and tracing DISABLED
-# costs < 2% of the warm wall by the deterministic per-hook bound
-assert obs["export_valid"] is True, obs
-assert obs["explain_analyze_ok"] is True, obs
-assert obs["spans_total"] >= 3, obs
-for layer in ("exec", "transfer", "serving"):
-    assert obs["spans_by_layer"].get(layer, 0) >= 1, obs
-assert obs["tracing_off_overhead_pct"] < 2.0, obs
-sn = out["breakdown"]["serving_net"]
-for key in ("wire_wall_s", "wire_bytes_out", "stream_batches",
-            "first_batch_before_done", "stream_bit_identical",
-            "interactive_p99_preempt_off_s", "interactive_p99_preempt_on_s",
-            "preempt_speedup_x", "preemptions", "whale_results_match"):
-    assert key in sn, f"missing serving_net breakdown key {key}: {sn}"
-# network serving acceptance: >= 1 partial batch streams before DONE and
-# assembles bit-identically; with one whale + interactive tenants on a
-# single device permit, preemption yields >= 1 time, the whale completes
-# with identical results, and interactive p99 improves
-assert sn["stream_batches"] >= 2, sn
-assert sn["first_batch_before_done"] is True, sn
-assert sn["stream_bit_identical"] is True, sn
-assert sn["wire_bytes_out"] > 0, sn
-assert sn["preemptions"] >= 1, sn
-assert sn["whale_results_match"] is True, sn
-assert sn["interactive_p99_preempt_on_s"] < \
-    sn["interactive_p99_preempt_off_s"], sn
-conc = out["breakdown"]["concurrent"]
-for key in ("queries", "sequential_rows_per_sec", "aggregate_rows_per_sec",
-            "aggregate_vs_sequential_x", "p50_latency_s", "p99_latency_s",
-            "program_cache_hit_rate", "warm_start"):
-    assert key in conc, f"missing concurrent breakdown key {key}: {conc}"
-assert conc["queries"] >= 16, conc
-# serving acceptance: 16 interleaved queries hold >= 0.9x sequential
-# aggregate throughput, the repeat mix hits the program cache >= 50%, and
-# a second server process warm-starts from the on-disk index
-assert conc["aggregate_vs_sequential_x"] >= 0.9, conc
-assert conc["program_cache_hit_rate"] >= 0.5, conc
-assert conc["warm_start"]["disk_hits"] >= 1, conc
-assert conc["p99_latency_s"] >= conc["p50_latency_s"] > 0, conc
-mesh = out["breakdown"]["mesh"]
-for key in ("devices", "in_mesh_exchange_gb_per_sec",
-            "single_device_exchange_gb_per_sec",
-            "host_hop_exchange_gb_per_sec", "in_mesh_vs_host_hop_x",
-            "host_hop_bytes", "per_device_rows_per_sec",
-            "collect_bit_identical", "q1_exact_cols_bit_identical",
-            "q1_float_max_rel_err"):
-    assert key in mesh, f"missing mesh breakdown key {key}: {mesh}"
-# the all_to_all exchange path must move NOTHING through the host
-assert mesh["host_hop_bytes"] == 0, mesh
-# acceptance bar: in-mesh exchange >= 2x the host-hop exchange path
-assert mesh["in_mesh_vs_host_hop_x"] >= 2.0, mesh
-# exchange bit-identity: the permute-only sharded collect is bitwise equal
-assert mesh["collect_bit_identical"] is True, mesh
-assert mesh["q1_exact_cols_bit_identical"] is True, mesh
-assert any(v for v in mesh["in_mesh_exchange_gb_per_sec"].values()), mesh
-print("bench smoke OK:", {k: pipe[k] for k in
-                          ("upload_chunked_s", "upload_overlap_efficiency",
-                           "inflight_high_water")},
-      {k: comp[k] for k in ("link_bytes_ratio", "encoded_domain_ops")},
-      {k: fusion[k] for k in ("q1_fused_stage_count",
-                              "batches_not_materialized",
-                              "q1_fused_vs_unfused_x", "repeat_hit_rate")},
-      {"fusion_coverage": fusion["coverage"]["fraction"]},
-      {k: conc[k] for k in ("aggregate_vs_sequential_x",
-                            "program_cache_hit_rate", "p50_latency_s",
-                            "p99_latency_s")},
-      {k: sn[k] for k in ("stream_batches", "preempt_speedup_x",
-                          "preemptions")},
-      {"out_of_core_q1": {k: ooc["q1"][k] for k in
-                          ("spill_partitions", "recursion_depth_peak",
-                           "quarter_vs_ample_x")}},
-      {"adaptive": {k: ad[k] for k in
-                    ("speedup_x", "skew_splits", "coalesced_partitions",
-                     "refused_stages", "broadcast_switches")}},
-      {"observability": {k: obs[k] for k in
-                         ("tracing_on_overhead_x",
-                          "tracing_off_overhead_pct", "spans_total")}},
-      {"warm_start_disk_hits": conc["warm_start"]["disk_hits"]},
-      {k: mesh[k] for k in ("in_mesh_exchange_gb_per_sec",
-                            "in_mesh_vs_host_hop_x", "host_hop_bytes")})
 PY
 
 if [ "${RUN_TPU_BENCH:-0}" = "1" ]; then

@@ -487,12 +487,4 @@ assert handle.metrics["recursion_depth_peak"] >= 1, handle.metrics
 print("tracing smoke ok:", layers)
 PY
 
-echo "== multichip dry-run (8 virtual devices) =="
-python - << 'PY'
-import importlib.util
-spec = importlib.util.spec_from_file_location("__graft_entry__", "__graft_entry__.py")
-g = importlib.util.module_from_spec(spec); spec.loader.exec_module(g)
-g.dryrun_multichip(8)
-print("ok")
-PY
 echo "PREMERGE OK"

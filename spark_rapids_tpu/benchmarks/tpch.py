@@ -77,6 +77,7 @@ def q6(lineitem: DataFrame) -> DataFrame:
                  .alias("revenue")))
 
 
+# default confs + float aggregation, for the tests that run these queries
 BENCH_CONF = {
     # float sums are required by TPC-H aggregates (same switch the reference
     # flips for benchmarks: spark.rapids.sql.variableFloatAgg.enabled)

@@ -27,7 +27,7 @@ Counters land in the process-global ``TRANSFER_METRICS``
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, List
 
 import pyarrow as pa
 
@@ -91,31 +91,27 @@ def upload_table(table: pa.Table,
                  string_max_bytes: int = DEFAULT_STRING_MAX_BYTES,
                  chunk_rows: int = 0, max_inflight: int = 2,
                  device: Any = None,
-                 stats: Optional[Dict[str, Any]] = None,
                  with_bits: bool = True) -> DeviceBatch:
     """Host arrow table -> DeviceBatch via the chunked overlapped pipeline.
 
     chunk_rows <= 0 (or a table at most one chunk big) takes the single-shot
-    ``DeviceBatch.from_arrow`` path. ``stats``, when given, is filled with the
-    per-chunk timing breakdown bench.py publishes (per_chunk_upload_s,
-    stage_s, upload_overlap_efficiency, inflight_high_water).
+    ``DeviceBatch.from_arrow`` path. Chunk count, in-flight peak and the
+    host's blocking seconds go to ``TRANSFER_METRICS``.
 
     Spans: ``transfer.upload`` over the whole call, and under it
     ``upload.stage`` per chunk, ``upload.wait`` per bounded wait and
     ``upload.assemble``; they take the plan id of the exec that uploads.
     """
     m = um.TRANSFER_METRICS
-    t_start = time.perf_counter()
     bounds = chunk_bounds(table, chunk_rows)
     n = table.num_rows
     ends = bounds[1:] + [n]
     chunks: List[DeviceBatch] = []
     inflight: List[DeviceBatch] = []
-    per_chunk: List[float] = []
     stage_total = wait_total = 0.0
     peak = 0
     # args dicts build only when tracing is live — the per-upload and
-    # per-chunk disabled cost stays one bool read (the <2% nightly bound)
+    # per-chunk disabled cost stays one bool read
     with _tracing.span("transfer.upload", _tracing.LAYER_TRANSFER,
                        {"rows": n, "chunks": len(bounds)}
                        if _tracing.TRACER.on else None) as upload:
@@ -142,7 +138,6 @@ def upload_table(table: pa.Table,
                     stage.note(bytes=b.device_size_bytes)
             t1 = time.perf_counter()
             stage_total += t1 - t0
-            per_chunk.append(round(t1 - t0, 4))
             chunks.append(b)
             inflight.append(b)
             peak = max(peak, len(inflight))
@@ -168,11 +163,6 @@ def upload_table(table: pa.Table,
             out = chunks[0]
         if upload is not None:
             upload.note(inflight_peak=peak, bytes=out.device_size_bytes)
-    if stats is not None:
-        # bench instrumentation wants the honest transfer wall including
-        # the assembly; the engine path must NOT sync
-        _wait_uploaded(out)
-    wall = time.perf_counter() - t_start
     m[um.TRANSFER_UPLOAD_BYTES].add(out.device_size_bytes)
     # the host's time in the upload's two blocking parts, staging and the
     # bounded waits: what the link rate of session.last_metrics divides by.
@@ -181,15 +171,6 @@ def upload_table(table: pa.Table,
     m[um.TRANSFER_UPLOAD_SECONDS].add(stage_total + wait_total)
     m[um.TRANSFER_UPLOAD_CHUNKS].add(len(chunks))
     m[um.TRANSFER_INFLIGHT_PEAK].set_max(peak)
-    if stats is not None:
-        # fraction of the upload wall covered by productive host staging:
-        # 1.0 = every transfer fully hidden behind staging; a serial
-        # stage-then-wait loop scores stage/(stage+transfer)
-        stats.update(chunks=len(chunks), wall_s=wall, stage_s=stage_total,
-                     per_chunk_upload_s=per_chunk,
-                     upload_overlap_efficiency=round(
-                         min(1.0, stage_total / wall) if wall > 0 else 0.0, 4),
-                     inflight_high_water=peak)
     return out
 
 

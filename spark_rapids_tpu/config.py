@@ -1106,8 +1106,7 @@ TRACE_ENABLED = _conf(
     "buffer, and emit a named jax.profiler range PER OPERATOR (analog of "
     "the NVTX ranges). Feeds EXPLAIN ANALYZE (tree_string(analyze=True) "
     "/ QueryHandle.explain_analyze()) and the Chrome/Perfetto trace "
-    "export. Off: every hook reduces to one boolean read (overhead "
-    "gated in the nightly bench).")
+    "export. Off: every hook reduces to one boolean read.")
 
 TRACE_EXPORT_PATH = _conf(
     "trace.export.path", str, "",

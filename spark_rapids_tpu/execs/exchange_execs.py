@@ -1091,10 +1091,10 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
 
     def _fused_pids_split(self, ctx, part, db: DeviceBatch, offset: int,
                           n: int, interpret: bool):
-        """ONE program for pids + pack + Pallas reorder (the engine analog
-        of bench.py's fused kernel measurement — separate pids/pack/kernel
-        dispatches were the warm exchange's dominant residue). Returns
-        _NOT_FUSABLE when the partitioning hashes a DOUBLE key: the fused
+        """ONE program for pids + pack + Pallas reorder (separate
+        pids/pack/kernel dispatches were the warm exchange's dominant
+        residue). Returns _NOT_FUSABLE when the partitioning hashes a
+        DOUBLE key: the fused
         form would hash bitcast(bits) where the two-dispatch path hashes
         the column's (emulated) f64 data, and those can disagree in the
         low mantissa on this backend."""
@@ -1411,9 +1411,7 @@ class CpuBroadcastExchangeExec(BroadcastExchangeExecBase):
 
 
 class TpuBroadcastExchangeExec(BroadcastExchangeExecBase):
-    """Device-side broadcast: the concatenated build batch stays in HBM. In
-    distributed execution the build child is all-gathered over the mesh
-    (parallel/distributed.py) instead of serialized through a driver."""
+    """Device-side broadcast: the concatenated build batch stays in HBM."""
 
     is_device = True
 

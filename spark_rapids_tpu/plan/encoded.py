@@ -5,7 +5,7 @@ whose columns still carry their dictionary encoding. This pass walks the
 FINAL physical plan (after conversion, transitions, and pipeline insertion)
 and flags the filter/aggregate/join execs whose input chain can actually
 deliver such batches — so the runtime rewrite (exprs/encoded.py) only ever
-runs where an encoding can exist, and ``explain``/bench can report how many
+runs where an encoding can exist, and ``explain`` can report how many
 operators were planned onto the encoded domain.
 
 The flag is an upper bound, not a promise: the exec still checks each
@@ -77,7 +77,7 @@ def mark_encoded_domain(plan: PhysicalExec, conf: TpuConf) -> PhysicalExec:
 
 
 def count_encoded_domain(plan: PhysicalExec) -> int:
-    """Operators planned onto the encoded domain (bench/introspection)."""
+    """Operators planned onto the encoded domain (introspection)."""
     n = 0
 
     def walk(node: PhysicalExec) -> None:

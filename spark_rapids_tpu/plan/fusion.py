@@ -140,7 +140,7 @@ def _saved_per_input_batch(ops: List[PhysicalExec]) -> int:
     Expand multiplies the batches every op above it sees). A fused
     CoalesceBatches is excluded — its concat batch still materializes as
     the stage input (FusedStageExec._coalesced), so counting it as saved
-    would overstate the metric nightly gates on."""
+    would overstate ``fusion_stats``' saved-batch count."""
     real = [n for n in ops
             if not isinstance(n, te.TpuCoalesceBatchesExec)]
     batches, saved = 1, 0
@@ -224,7 +224,7 @@ def fused_stages(plan: PhysicalExec) -> List[PhysicalExec]:
 
 
 def fusion_stats(plan: PhysicalExec) -> dict:
-    """Static per-plan fusion accounting (bench/introspection)."""
+    """Static per-plan fusion accounting (introspection)."""
     stages = fused_stages(plan)
     ops = [len(s.fused_ops) + (1 if isinstance(s, FusedAggregateStageExec)
                                else 0) for s in stages]

@@ -19,8 +19,8 @@ Two persistence layers compose:
   previous incarnation of it) has compiled, in a small JSON file next to
   the compilation cache. A restarted server that misses in memory but
   hits the index counts a ``disk_hit``: the program warms from disk
-  instead of compiling cold — the observable warm-start the bench
-  ``concurrent`` section asserts.
+  instead of compiling cold — the observable warm-start
+  (tests/test_serving.py::test_program_cache_disk_index_warm_start).
 
 Concurrency: one in-flight latch per key — when two queries miss on the
 same key simultaneously, one builds while the other waits, mirroring the

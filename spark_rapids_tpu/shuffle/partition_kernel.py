@@ -18,7 +18,7 @@ does it in ONE streaming HBM pass with a Pallas kernel:
            ordinary DeviceBatch (shuffles do not promise intra-partition
            row order)
 
-Backend constraints discovered by probing (experiments/pallas_probe.py):
+Backend constraints discovered by probing (docs/perf-notes.md):
 cumsum/sort/gather do not lower in Mosaic TC kernels; the X64 rewriter
 cannot lower any 64-bit-element bitcast (f64->u64, i64->u32, signbit,
 frexp); f64 ARITHMETIC is ~49-bit sloppy while f64 STORAGE is true 64-bit;

@@ -27,7 +27,7 @@ import socket
 import struct
 import threading
 import time
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Set, Tuple
 
 from spark_rapids_tpu.shuffle.retry import backoff_ms
 from spark_rapids_tpu.shuffle.transport import (AddressLengthTag,
@@ -109,6 +109,7 @@ class _Peer:
         self.reader = threading.Thread(target=self._read_loop,
                                        name=f"tcp-shuffle-reader-{peer_id}",
                                        daemon=True)
+        transport._track(self)
         self.reader.start()
 
     def _read_loop(self) -> None:
@@ -235,6 +236,13 @@ class TcpTransport(ShuffleTransport):
         # survive the old reader's eviction) — R012
         self._peers: Dict[str, _Peer] = {}
         self._peers_lock = threading.Lock()
+        # every peer with an open socket, named or not: an inbound peer
+        # enters _peers only when its reader has read the hello, and
+        # shutdown()/kill() must close the ones still before that too
+        # (a remote that dialled just before would keep a live socket to
+        # a dead executor and hang to its fetch timeout)
+        self._live: Set[_Peer] = set()
+        self._closed = False
         self._clients: Dict[str, TcpClientConnection] = {}
         self._clients_lock = threading.Lock()
         self._server_conn = TcpServerConnection(self)
@@ -328,6 +336,20 @@ class TcpTransport(ShuffleTransport):
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             _Peer(self, sock)
 
+    def _track(self, peer: _Peer) -> None:
+        with self._peers_lock:
+            self._live.add(peer)
+            closed = self._closed
+        if closed:      # accepted or dialled across shutdown()/kill()
+            peer.close()
+
+    def _close_peers(self) -> None:
+        with self._peers_lock:
+            self._closed = True
+            peers = list(self._live)
+        for p in peers:
+            p.close()
+
     def _register_peer(self, peer_id: str, peer: _Peer) -> None:
         with self._peers_lock:
             self._peers[peer_id] = peer
@@ -356,6 +378,7 @@ class TcpTransport(ShuffleTransport):
         # lock: a NEWER peer registered between the check and the pop
         # must survive the old reader's eviction (R012).
         with self._peers_lock:
+            self._live.discard(peer)
             was_current = self._peers.get(peer.peer_id) is peer
             if was_current:
                 self._peers.pop(peer.peer_id, None)
@@ -561,10 +584,7 @@ class TcpTransport(ShuffleTransport):
         exactly the stale entry ``scan_registry``'s GC must absorb."""
         self._killed = True
         self._close_listener()
-        with self._peers_lock:
-            peers = list(self._peers.values())
-        for p in peers:
-            p.close()
+        self._close_peers()
 
     def _close_listener(self) -> None:
         # SHUT_RDWR first, same discipline as _Peer.close: a bare close()
@@ -589,10 +609,7 @@ class TcpTransport(ShuffleTransport):
             except OSError:
                 pass
         self._close_listener()
-        with self._peers_lock:
-            peers = list(self._peers.values())
-        for p in peers:
-            p.close()
+        self._close_peers()
         for _ in range(self._num_workers):
             self._work.put(None)
         self._progress.put(None)

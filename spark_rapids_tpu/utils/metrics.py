@@ -63,8 +63,8 @@ TRANSFER_ENCODED_DOMAIN_OPS = "transfer.encoded_domain_ops"
 #: device) instead of riding an in-mesh collective: the scatter of a
 #: single-device intermediate onto the mesh, and TCP shuffle payloads (the
 #: DCN path). The in-mesh all_to_all exchange keeps this at EXACTLY 0 —
-#: only per-shard row COUNTS sync to the host, never row data (the bench
-#: `mesh` section and CI assert the zero).
+#: only per-shard row COUNTS sync to the host, never row data
+#: (tests/test_named_sharding.py::test_in_mesh_exchange_zero_host_hop).
 TRANSFER_HOST_HOP_BYTES = "transfer.host_hop_bytes"
 #: shuffle exchanges that carried a column through partition/repack as
 #: dictionary indices + shared dictionary instead of decoded values
@@ -215,7 +215,7 @@ QUERY_METRIC_NAMES = (QUERY_QUEUE_WAIT_S, QUERY_ADMISSION_WAIT_S,
 
 def percentile(sorted_vals, q: float) -> float:
     """Nearest-rank percentile over an ascending list (p50/p99 latency
-    reporting for the serving bench and scheduler stats)."""
+    reporting for scheduler stats)."""
     if not sorted_vals:
         return 0.0
     if q <= 0:

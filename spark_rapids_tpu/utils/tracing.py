@@ -39,8 +39,7 @@ Design constraints (the R002 contract):
   the ring alone.
 - disabled mode is near-zero-cost: every hook is gated on one module-bool
   read (``enabled()``); ``span()`` returns a shared no-op context manager
-  without allocating. The disabled overhead is microbenchmarked in
-  bench.py's ``observability`` section and gated in nightly CI.
+  without allocating.
 - the ring buffer is bounded (``trace.maxBufferedSpans``): a long-running
   traced server overwrites its oldest spans instead of growing without
   bound. ``mark()``/``since()`` give an action-scoped window; per-query

@@ -206,6 +206,32 @@ def test_mesh_global_agg_no_keys(eight_devices):
         expect_tpu_execs=["MeshHashAggregateExec"])
 
 
+@pytest.mark.parametrize("threshold", ["1024", "4"],
+                         ids=["gathered-partials", "repartitioned-partials"])
+def test_mesh_agg_nullable_int_values(eight_devices, threshold):
+    """Ten int keys over a nullable int value column, one key all null:
+    sum/count/min/max/avg of the shards' partials merge to the CPU engine's
+    answer, whether the partials are gathered or hash-repartitioned."""
+    rng = np.random.default_rng(3)
+    n = 900
+    k = rng.integers(0, 10, n).astype(np.int64)
+    v = rng.integers(0, 100, n).astype(np.int64)
+    null = (rng.random(n) >= 0.9) | (k == 7)
+    t = pa.table({"k": k, "v": pa.array(v, pa.int64(), mask=null)})
+    cpu = assert_tpu_and_cpu_equal(
+        lambda s: s.create_dataframe(t).groupBy("k").agg(
+            F.sum("v").alias("sv"), F.count("v").alias("cv"),
+            F.min("v").alias("mn"), F.max("v").alias("mx"),
+            F.avg("v").alias("av")),
+        conf={**MESH_CONF,
+              "spark.rapids.tpu.sql.mesh.aggRepartitionThreshold": threshold},
+        ignore_order=True, approx_float=1e-12,
+        expect_tpu_execs=["MeshHashAggregateExec"])
+    row7 = cpu.filter(pa.compute.equal(cpu["k"], 7)).to_pylist()
+    assert row7 == [{"k": 7, "sv": None, "cv": 0, "mn": None, "mx": None,
+                     "av": None}]
+
+
 # ---------------------------------------------------------- shard-local scan
 def _write_parts(tmp_path, n_files=6, rows=1500, seed=53, fmt="parquet"):
     import pyarrow.parquet as pq

@@ -1,8 +1,8 @@
 """Fused partition-reorder kernel (shuffle/partition_kernel.py): pack ->
-Pallas kernel -> consolidate, in interpreter mode on the CPU backend (the
-real-chip numbers live in bench.py). The reorder must move every live row to
-exactly one partition piece bit-exactly; intra-partition ORDER is not
-promised (shuffle semantics)."""
+Pallas kernel -> consolidate, in interpreter mode on the CPU backend (on
+the chip chip_smoke.py runs the compiled kernels). The reorder must move
+every live row to exactly one partition piece bit-exactly; intra-partition
+ORDER is not promised (shuffle semantics)."""
 import datetime
 
 import numpy as np
@@ -237,7 +237,7 @@ def test_dma_index_plan_matches_take_order():
     """Code review (round 5): the DMA consolidation's host-side index math
     must place every row exactly where the take()-path puts it — simulated
     here in numpy, so CI covers it without a TPU. The DMA path itself is
-    validated on-chip (experiments/consolidate_dma_all.py: EXACT match)."""
+    validated on-chip (docs/perf-notes.md, item 7; chip_smoke.py runs it)."""
     import numpy as np
     from spark_rapids_tpu.shuffle.partition_kernel import (BLOCK,
                                                            KernelGeom,

@@ -43,6 +43,20 @@ def fresh_fabric():
     _Fabric.reset()
 
 
+#: the default is 2 x 32 x 4 MiB a transport; these tests move a few bytes
+SMALL_BOUNCE = {"spark.rapids.tpu.shuffle.bounceBuffers.size": 1024,
+                "spark.rapids.tpu.shuffle.bounceBuffers.count": 16}
+
+
+def wait_for(cond, timeout=30):
+    """Poll for what another thread makes true: a loaded host may take
+    seconds to schedule it."""
+    deadline = time.monotonic() + timeout
+    while not cond() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert cond()
+
+
 def fault_cluster(tmp_path, plan="", seed=7, n=2, extra=None):
     """n ShuffleEnvs riding the fault wrapper around the in-process fabric.
     Small bounce buffers force multi-chunk transfers (faults need frames to
@@ -54,8 +68,7 @@ def fault_cluster(tmp_path, plan="", seed=7, n=2, extra=None):
         "spark.rapids.tpu.shuffle.transport.class": FAULT_TRANSPORT,
         "spark.rapids.tpu.shuffle.faults.plan": plan,
         "spark.rapids.tpu.shuffle.faults.seed": seed,
-        "spark.rapids.tpu.shuffle.bounceBuffers.size": 1024,
-        "spark.rapids.tpu.shuffle.bounceBuffers.count": 16,
+        **SMALL_BOUNCE,
         "spark.rapids.tpu.shuffle.retryBackoffMs": 5,
         "spark.rapids.tpu.shuffle.compression.codec":
             os.environ.get("SHUFFLE_FAULTS_CODEC", "none"),
@@ -298,7 +311,8 @@ def test_peer_loss_scoped_to_failing_peer(tmp_path):
     conf = TpuConf({
         "spark.rapids.tpu.shuffle.transport.class":
             "spark_rapids_tpu.shuffle.tcp.TcpTransport",
-        "spark.rapids.tpu.shuffle.tcp.registryDir": str(tmp_path / "reg")})
+        "spark.rapids.tpu.shuffle.tcp.registryDir": str(tmp_path / "reg"),
+        **SMALL_BOUNCE})
     a = TcpTransport("exec-a", conf)
     b = TcpTransport("exec-b", conf)
     c = TcpTransport("exec-c", conf)
@@ -318,15 +332,41 @@ def test_peer_loss_scoped_to_failing_peer(tmp_path):
         assert "lost" in rb.error_message
         # c's receive is untouched and still completes
         assert rc.status is TransactionStatus.IN_PROGRESS
+        # the server sends on the socket exec-a opened, known to c once
+        # c's reader has read exec-a's hello
+        wait_for(lambda: c._peer_by_id("exec-a") is not None)
         c.server.send("exec-a", AddressLengthTag.for_bytes(b"hello", 0x20),
                       lambda t: None).wait(10)
         rc.wait(10)
         assert rc.status is TransactionStatus.SUCCESS
         assert bytes(alt_c.buffer) == b"hello"
+        # _peer_lost queues the transactions' failure, then notifies
+        wait_for(lambda: lost)
         assert lost == ["exec-b"]
     finally:
         a.shutdown()
         c.shutdown()
+
+
+@pytest.mark.parametrize("stop", ["shutdown", "kill"])
+def test_stopped_transport_closes_connection_without_hello(tmp_path, stop):
+    """A peer that has dialled but whose hello the transport has not read
+    yet (here: never sent) is in no peer table; shutdown() and kill() close
+    its socket all the same, so the remote sees the loss at once."""
+    import socket
+    from spark_rapids_tpu.shuffle.tcp import TcpTransport
+    conf = TpuConf({
+        "spark.rapids.tpu.shuffle.tcp.registryDir": str(tmp_path / "reg"),
+        **SMALL_BOUNCE})
+    b = TcpTransport("exec-b", conf)
+    sock = socket.create_connection(b.address, timeout=30)
+    try:
+        wait_for(lambda: b._live)           # accepted
+        getattr(b, stop)()
+        assert sock.recv(1) == b""          # closed by b, not timed out
+    finally:
+        sock.close()
+        b.shutdown()
 
 
 def test_dead_client_evicted_and_reconnect_possible(tmp_path):

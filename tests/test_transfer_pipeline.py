@@ -107,9 +107,9 @@ def test_chunked_upload_all_null_and_empty_chunks():
 
 def test_upload_small_table_takes_single_shot_path():
     t = _mixed_table(64)
-    stats = {}
-    b = transfer.upload_table(t, 16, chunk_rows=1000, stats=stats)
-    assert stats["chunks"] == 1
+    before = um.transfer_snapshot()
+    b = transfer.upload_table(t, 16, chunk_rows=1000)
+    assert um.transfer_delta(before)[um.TRANSFER_UPLOAD_CHUNKS] == 1
     _assert_batches_bit_equal(DeviceBatch.from_arrow(t, 16), b)
 
 
@@ -124,12 +124,14 @@ def test_upload_counts_transfer_metrics():
 
 
 def test_stats_overlap_efficiency_bounds():
-    stats = {}
-    transfer.upload_table(_mixed_table(3000), 16, chunk_rows=400,
-                          max_inflight=3, stats=stats)
-    assert 0 < stats["upload_overlap_efficiency"] <= 1
-    assert 1 <= stats["inflight_high_water"] <= 3
-    assert len(stats["per_chunk_upload_s"]) == stats["chunks"]
+    t = _mixed_table(3000)
+    before = um.transfer_snapshot()
+    transfer.upload_table(t, 16, chunk_rows=400, max_inflight=3)
+    delta = um.transfer_delta(before)
+    assert 1 <= delta[um.TRANSFER_INFLIGHT_PEAK] <= 3
+    assert delta[um.TRANSFER_UPLOAD_CHUNKS] == len(
+        transfer.chunk_bounds(t, 400))
+    assert delta[um.TRANSFER_UPLOAD_SECONDS] > 0
 
 
 # ------------------------------------------------------- concat bits handling
