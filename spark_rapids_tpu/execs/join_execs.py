@@ -304,7 +304,7 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
                 sized = jk.join_size(jnp, lk, rk, l_alive, r_alive, how)
                 return (sized["emit_counts"], sized["emit_offsets"],
                         sized["total"], sized["border"], sized["start_b"],
-                        sized["sgid"], sized["matches_l"])
+                        sized["matches_l"])
             return fn
 
         fn1 = _cached_jit(key1, build1)
@@ -313,7 +313,7 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
                     + list(cenc.flatten_encodings(rb, r_used)))
         if enc_pairs:
             mt.TRANSFER_METRICS[mt.TRANSFER_ENCODED_DOMAIN_OPS].add(1)
-        (emit_counts, emit_offsets, total, border, start_b, sgid,
+        (emit_counts, emit_offsets, total, border, start_b,
          matches_l) = fn1(np.int32(lb.num_rows), np.int32(rb.num_rows),
                           *flat_in, *enc_flat)
         n_out = int(total)
@@ -327,13 +327,13 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
                    inc_right=self.includes_right_columns, smax=smax):
             nl = _n_flat(lschema)
 
-            def fn(emit_counts, emit_offsets, total, border, start_b, sgid,
+            def fn(emit_counts, emit_offsets, total, border, start_b,
                    matches_l, *flat):
                 l_cols = _unflatten_colvs(lschema, flat[:nl])
                 r_cols = _unflatten_colvs(rschema, flat[nl:])
                 sized = dict(emit_counts=emit_counts,
                              emit_offsets=emit_offsets, total=total,
-                             border=border, start_b=start_b, sgid=sgid,
+                             border=border, start_b=start_b,
                              matches_l=matches_l)
                 lrow, lvalid, rrow, rvalid, _ = jk.join_gather(
                     jnp, sized, S, B, out_cap, how)
@@ -352,7 +352,7 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
             return fn
 
         fn2 = _cached_jit(key2, build2)
-        res = fn2(emit_counts, emit_offsets, total, border, start_b, sgid,
+        res = fn2(emit_counts, emit_offsets, total, border, start_b,
                   matches_l, *flat_in)
         n = int(res[-1])
         out = _to_batch(self.output, res[:-1], n)

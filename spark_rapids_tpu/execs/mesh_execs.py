@@ -1272,13 +1272,13 @@ class MeshHashJoinBase(MeshExec):
                 sized = jk.join_size(jnp, lk, rk, l_alive, r_alive, how)
                 return (sized["emit_counts"], sized["emit_offsets"],
                         sized["total"][None], sized["border"],
-                        sized["start_b"], sized["sgid"], sized["matches_l"])
+                        sized["start_b"], sized["matches_l"])
             return fn
 
         fn1 = _shard_jit(mesh, key1, build1,
                          (lspec, rspec) + _specs(nl, lspec)
                          + _specs(nr, rspec),
-                         _specs(7))
+                         _specs(6))
         res1 = fn1(l_rows, r_rows, *lb_flat, *rb_flat)
         totals = np.asarray(res1[2]).astype(np.int64)
         out_cap = max(bucket_capacity(int(totals.max(initial=0))), 1)
@@ -1290,13 +1290,13 @@ class MeshHashJoinBase(MeshExec):
         def build2(how=self.how, lschema=lschema, rschema=rschema, S=S, B=B,
                    out_cap=out_cap, cond=self.condition,
                    inc_right=self.includes_right_columns, smax=smax):
-            def fn(emit_counts, emit_offsets, total, border, start_b, sgid,
+            def fn(emit_counts, emit_offsets, total, border, start_b,
                    matches_l, *flat):
                 l_cols = unflatten_colvs(lschema, flat[:nl])
                 r_cols = unflatten_colvs(rschema, flat[nl:])
                 sized = dict(emit_counts=emit_counts,
                              emit_offsets=emit_offsets, total=total[0],
-                             border=border, start_b=start_b, sgid=sgid,
+                             border=border, start_b=start_b,
                              matches_l=matches_l)
                 lrow, lvalid, rrow, rvalid, _ = jk.join_gather(
                     jnp, sized, S, B, out_cap, how)
@@ -1317,7 +1317,7 @@ class MeshHashJoinBase(MeshExec):
 
         nout = flat_len(self.output)
         fn2 = _shard_jit(mesh, key2, build2,
-                         _specs(7) + _specs(nl, lspec) + _specs(nr, rspec),
+                         _specs(6) + _specs(nl, lspec) + _specs(nr, rspec),
                          (P(DATA_AXIS),) + _specs(nout))
         res2 = fn2(*res1, *lb_flat, *rb_flat)
         rows = np.asarray(res2[0]).astype(np.int32)

@@ -1,9 +1,9 @@
 """Equi-join kernels (reference: shims/spark300/GpuHashJoin.scala:220-230 —
 cudf Table.innerJoin/leftJoin/leftSemiJoin/leftAntiJoin/fullJoin).
 
-TPU re-design: instead of a device hash table (dynamic shapes), both sides' keys
-are assigned *dense group ids* by one shared sort over the union of keys — rows
-join iff they share a gid. Join cardinality is dynamic, so the kernel is split:
+TPU re-design: no device hash table (dynamic shapes). Rows of the two sides
+join iff they fall into the same *key group* of one sort over the union of
+both sides' keys. Join cardinality is dynamic, so the kernel is split:
 
   phase 1 (size):   one jit program computes per-emit-group counts, offsets and
                     the total output size (a traced scalar, synced to host once);
@@ -14,6 +14,24 @@ This is the two-pass size-then-gather pattern for dynamic cardinality on XLA.
 Spark semantics: null keys never match (any-null rows are excluded from
 grouping); NaN keys match each other; supported: inner, left, right, full,
 left_semi, left_anti, cross.
+
+Phase 1 is sorts that carry their operands, and scans, and nothing else
+(``join_size``): sort 1 brings the union into key order with the key words
+and the row index riding along, group boundaries are the places where a
+sorted word differs from its neighbour's, per-group counts are differences of
+a running count that one cummax carries forward from the group's first row
+and one cummin carries back from its last, and sort 2 takes the counts to
+the output layout (stream rows in row order, then the build rows in key
+order). What decided it, on the v5e, per Q3 (its two joins: S+B = 4.46 M
+and 1.08 M rows; device trace of the kernel before PR 31, which held five
+sorts and 17 gathers of S+B rows, 24 once the TPU split the 64-bit ones): the
+gathers took 1.745 s of the kernel's 1.854 (nine of 0.10-0.16 s each, fifteen
+of 0.040-0.054), the ten sorts of four or five operands 0.083 s together,
+the scans 0.025; a scatter-add costs several sorts (docs/perf-notes.md). One
+gather costs two to ten sorts, so everything a gather used to fetch is
+carried by a sort or propagated by a scan; positions, counts and row indices
+are 32 bits wide (64-bit words are emulated) and only the emit offsets and
+the total are 64.
 
 Emit-group layout: groups [0, S) are stream (left) rows — each emits its match
 count (or 1 null-padded row for left/full when unmatched, or 0/1 for
@@ -27,6 +45,7 @@ from typing import List, Sequence
 
 import numpy as np
 
+from spark_rapids_tpu.columnar.dtypes import DType
 from spark_rapids_tpu.exprs.core import ColV
 from spark_rapids_tpu.ops import batch_kernels as bk
 
@@ -59,12 +78,110 @@ def _exclusive_cumsum(xp, x):
     return c - x
 
 
+def _sort_carried(xp, operands: Sequence, num_keys: int) -> List:
+    """Sort by the first ``num_keys`` operands (lexicographic, the first most
+    significant) and carry the others along: every operand comes back in the
+    sorted order, so nothing is gathered through a permutation afterwards.
+    Never stable (a stable sort compiles three times as long for the TPU):
+    ``join_size``'s key tuples are unique, and where ``_string_rank``'s tie
+    the tied rows get one rank whatever their order, so both engines agree."""
+    if xp is np:
+        order = np.lexsort(tuple(reversed(
+            [np.asarray(o) for o in operands[:num_keys]])))
+        return [np.asarray(o)[order] for o in operands]
+    import jax
+    return list(jax.lax.sort(tuple(operands), num_keys=num_keys,
+                             is_stable=False))
+
+
+def _cummax(xp, x):
+    if xp is np:
+        return np.maximum.accumulate(x)
+    import jax
+    return jax.lax.cummax(x)
+
+
+def _cummin_from_end(xp, x):
+    if xp is np:
+        return np.minimum.accumulate(x[::-1])[::-1]
+    import jax
+    return jax.lax.cummin(x, reverse=True)
+
+
+def _shift_down(xp, x):
+    """x[i-1] at position i (x[0] at 0)."""
+    return xp.concatenate([x[:1], x[:-1]])
+
+
+def _string_rank(xp, v: ColV):
+    """A STRING key as ONE int32 word: the dense rank of (bytes, length)
+    among the rows, equal for equal strings, in byte order. Folded from the
+    least significant uint64 chunk up, two narrow sorts a chunk inside one
+    loop: the compiler sees two sorts whatever the width. (A sort keyed by
+    every chunk at once compiles for minutes a key word on the TPU: over 25
+    for a 32-byte key, PERF.md section 6, PR 31.)"""
+    stack = xp.stack([xp.asarray(w, dtype=np.int64)
+                      for w in bk._key_passes(xp, v, True, True)[1:]])
+    n, G = stack.shape
+    pos = xp.arange(G, dtype=np.int32)
+
+    def fold(i, rank):
+        # rank orders the chunks below this one; (chunk, rank) orders these
+        w = stack[n - 1 - i]
+        w_s, rank_s, row_s = _sort_carried(xp, [w, rank, pos], num_keys=2)
+        starts = xp.logical_or(w_s != _shift_down(xp, w_s),
+                               rank_s != _shift_down(xp, rank_s))
+        dense = xp.cumsum(xp.logical_or(starts, pos == 0), dtype=np.int32)
+        return _sort_carried(xp, [row_s, dense], num_keys=1)[1]
+
+    rank = xp.zeros(G, dtype=np.int32)
+    if xp is np:
+        for i in range(n):
+            rank = fold(i, rank)
+        return rank
+    import jax
+    return jax.lax.fori_loop(0, n, fold, rank)
+
+
+def _match_words(xp, v: ColV) -> List:
+    """The words whose equality is Spark's key equality and whose order
+    groups equal keys: ``bk._key_passes`` without the null rank (a null key
+    never matches; its row is sorted behind with the dead ones). Every word
+    is a key operand of sort 1, so the key's dtype decides how many there
+    are: a string is ranked to one, integers stay as narrow as they are
+    stored (64-bit compares are emulated on the TPU)."""
+    if v.dtype is DType.STRING:
+        return [_string_rank(xp, v)]
+    if np.dtype(v.data.dtype).kind in "iu":
+        return [v.data]
+    return bk._key_passes(xp, v, True, True)[1:]
+
+
 def join_size(xp, l_keys: Sequence[ColV], r_keys: Sequence[ColV],
               l_alive, r_alive, how: str):
     """Phase 1. Returns a dict of device arrays:
-    emit_counts [S+B], emit_offsets [S+B], total (scalar), border [B],
-    start_b [S] (PER STREAM ROW: the row's group's first build-row index
-    within `border`), sgid [S], matches_l [S].
+    emit_counts [S+B], emit_offsets [S+B], total (scalar), border [B] (the
+    build rows in key-group order, ties by row index, rows that cannot match
+    last), start_b [S] (PER STREAM ROW: the row's group's first build-row
+    index within ``border``), matches_l [S].
+
+    Two carried-operand sorts of the S+B union and three scans (five for
+    right/full); no gather, no scatter, no inverse permutation:
+
+      sort 1  (cannot match, key words..., row index) -> the same in key order
+      shift   group starts/ends: a sorted word differs from its neighbour's
+      scans   cb = cumsum(is a build row); the group's first exclusive cb
+              carried forward (cummax), its last inclusive cb carried back
+              (cummin from the end): their difference is the group's build
+              count, the first is start_b. Stream counts the same way from
+              position - cb, only where right/full read them.
+      sort 2  by destination: a stream row to its row index, a build row to
+              S + its rank in key order -> [:S] is per stream row in row
+              order, [S:] is ``border``.
+
+    Lowered for one LONG key (S = 256, B = 4,096; tests/test_join_kernel.py
+    pins it): 2 sorts, 0 gathers, 0 scatters. The formulation before PR 31
+    held 5 sorts and 17 gathers of S+B rows after CSE, nine of 64-bit words.
     """
     S = l_keys[0].validity.shape[0] if l_keys else l_alive.shape[0]
     B = r_keys[0].validity.shape[0] if r_keys else r_alive.shape[0]
@@ -82,100 +199,89 @@ def join_size(xp, l_keys: Sequence[ColV], r_keys: Sequence[ColV],
         return dict(emit_counts=emit_counts, emit_offsets=emit_offsets,
                     total=total, border=border.astype(np.int32),
                     start_b=xp.zeros(S, dtype=np.int64),
-                    sgid=xp.zeros(S, dtype=np.int32),
                     matches_l=xp.where(l_alive, B_count, 0).astype(np.int64))
-
-    l_null = _any_null(xp, l_keys)
-    r_null = _any_null(xp, r_keys)
-    l_match_ok = xp.logical_and(l_alive, xp.logical_not(l_null))
-    r_match_ok = xp.logical_and(r_alive, xp.logical_not(r_null))
-
-    keys_all = [_concat_colv(xp, lk, rk) for lk, rk in zip(l_keys, r_keys)]
-    alive_all = xp.concatenate([l_match_ok, r_match_ok])
-    order = bk.sort_indices(xp, [(k, True, True) for k in keys_all], alive_all)
-    starts = bk.rows_equal_adjacent(xp, keys_all, order, alive_all)
-    gids_sorted = xp.cumsum(starts.astype(np.int32)) - 1
-    # scatter gids back to row order; dead rows get -1
-    inv = bk._stable_argsort(xp, order)      # inverse permutation
-    gid_by_row = gids_sorted[inv]
-    gid_by_row = xp.where(alive_all, gid_by_row, -1).astype(np.int32)
-    sgid = gid_by_row[:S]
-    bgid = gid_by_row[S:]
-
-    # per-row group counts WITHOUT scatters (1.16 s per scatter-segment_sum
-    # at 8.4M rows on this chip vs ~30 ms per scan): compute in SORTED
-    # space — group-start/end positions from cummax/cummin over the start
-    # marks, member counts as inclusive-cumsum differences — then gather
-    # back to row order through the inverse permutation.
-    pos = xp.arange(G, dtype=np.int64)
-    alive_sorted = alive_all[order]
-    is_b_sorted = xp.logical_and(order >= S, alive_sorted)
-    is_s_sorted = xp.logical_and(order < S, alive_sorted)
-    csum_b = xp.cumsum(is_b_sorted.astype(np.int64))
-    csum_s = xp.cumsum(is_s_sorted.astype(np.int64))
-    if xp is np:
-        st = np.maximum.accumulate(xp.where(starts, pos, 0))
-        nxt = xp.where(starts, pos, G)
-        nxt_rev = np.minimum.accumulate(nxt[::-1])[::-1]
-    else:
-        import jax
-        st = jax.lax.cummax(xp.where(starts, pos, np.int64(0)))
-        nxt = xp.where(starts, pos, np.int64(G))
-        nxt_rev = jax.lax.cummin(nxt[::-1])[::-1]
-    # next group's start strictly after i = min start at/after i+1
-    en = xp.concatenate([nxt_rev[1:], xp.full((1,), G, np.int64)]) - 1
-    en = xp.clip(en, 0, G - 1)
-    b_at_st = is_b_sorted[st].astype(np.int64)
-    s_at_st = is_s_sorted[st].astype(np.int64)
-    cnt_b_sorted = csum_b[en] - csum_b[st] + b_at_st
-    cnt_s_sorted = csum_s[en] - csum_s[st] + s_at_st
-    startb_sorted = csum_b[st] - b_at_st       # build rows before my group
-    cnt_b_row = cnt_b_sorted[inv]
-    cnt_s_row = cnt_s_sorted[inv]
-    startb_row = startb_sorted[inv]
-
-    matches_l = xp.where(sgid >= 0, cnt_b_row[:S], 0)
-    matched_b = xp.where(bgid >= 0, cnt_s_row[S:] > 0, False)
-    #: per-STREAM-row index of the group's first build row within `border`
-    start_b_stream = xp.where(sgid >= 0, startb_row[:S], 0).astype(np.int64)
-
-    if how == "inner":
-        emit_l = matches_l
-        emit_r = xp.zeros(B, dtype=np.int64)
-    elif how in ("left",):
-        emit_l = xp.where(l_alive, xp.maximum(matches_l, 1), 0)
-        emit_r = xp.zeros(B, dtype=np.int64)
-    elif how == "right":
-        emit_l = matches_l
-        emit_r = xp.where(xp.logical_and(r_alive, xp.logical_not(matched_b)),
-                          1, 0).astype(np.int64)
-    elif how == "full":
-        emit_l = xp.where(l_alive, xp.maximum(matches_l, 1), 0)
-        emit_r = xp.where(xp.logical_and(r_alive, xp.logical_not(matched_b)),
-                          1, 0).astype(np.int64)
-    elif how == "left_semi":
-        emit_l = xp.where(matches_l > 0, 1, 0).astype(np.int64)
-        emit_r = xp.zeros(B, dtype=np.int64)
-    elif how == "left_anti":
-        emit_l = xp.where(xp.logical_and(l_alive, matches_l == 0), 1, 0
-                          ).astype(np.int64)
-        emit_r = xp.zeros(B, dtype=np.int64)
-    else:
+    if how not in JOIN_KINDS:
         raise ValueError(how)
+    needs_matched_b = how in ("right", "full")
+
+    l_ok = xp.logical_and(l_alive, xp.logical_not(_any_null(xp, l_keys)))
+    r_ok = xp.logical_and(r_alive, xp.logical_not(_any_null(xp, r_keys)))
+    ok = xp.concatenate([l_ok, r_ok])
+    words = []
+    for lk, rk in zip(l_keys, r_keys):
+        words.extend(_match_words(xp, _concat_colv(xp, lk, rk)))
+    # rows that cannot match carry constant words: behind, by row index
+    words = [xp.where(ok, w, xp.zeros((), w.dtype)) for w in words]
+    pos = xp.arange(G, dtype=np.int32)
+
+    # ---- sort 1: key order; the words, the row index and ok ride along
+    srt = _sort_carried(
+        xp, [xp.logical_not(ok).astype(np.int8)] + words + [pos],
+        num_keys=len(words) + 2)
+    nok_s, words_s, row_s = srt[0], srt[1:-1], srt[-1]
+    ok_s = nok_s == 0
+    is_b = row_s >= S
+
+    # ---- group marks from the sorted words themselves
+    starts = pos == 0
+    for w in [nok_s] + list(words_s):
+        starts = xp.logical_or(starts, w != _shift_down(xp, w))
+    ends = xp.concatenate([starts[1:], xp.ones(1, dtype=bool)])
+
+    # ---- counts by scans. cb counts every build row: the rows that cannot
+    # match lie behind every group, so inside a group cb counts live ones
+    is_b32 = is_b.astype(np.int32)
+    cb = xp.cumsum(is_b32, dtype=np.int32)
+
+    def group_span(incl, excl):
+        first = _cummax(xp, xp.where(starts, excl, np.int32(0)))
+        last = _cummin_from_end(xp, xp.where(ends, incl, np.int32(G + 1)))
+        return first, last - first
+
+    start_b_s, cnt_b_s = group_span(cb, cb - is_b32)
+    cnt_b_s = xp.where(ok_s, cnt_b_s, np.int32(0))
+    start_b_s = xp.where(ok_s, start_b_s, np.int32(0))
+    if needs_matched_b:
+        # stream rows up to a position: the position's rank less cb
+        cs = pos + np.int32(1) - cb
+        _, cnt_s_s = group_span(cs, cs - (np.int32(1) - is_b32))
+        cnt_s_s = xp.where(ok_s, cnt_s_s, np.int32(0))
+    else:
+        cnt_s_s = np.int32(0)
+
+    # ---- sort 2: back to the output layout. dest is a permutation of
+    # [0, G): stream rows to their row index, build rows behind them by rank
+    # in key order (cb - 1; the rows that cannot match follow by row index,
+    # as sort 1 left them). Two payload words: a stream row brings its
+    # (matches, start_b), a build row its (row index, group's stream count)
+    dest = xp.where(is_b, np.int32(S - 1) + cb, row_s)
+    _, pay0, pay1 = _sort_carried(
+        xp, [dest, xp.where(is_b, row_s - np.int32(S), cnt_b_s),
+             xp.where(is_b, cnt_s_s, start_b_s)], num_keys=1)
+    matches_l = pay0[:S]
+    start_b_stream = pay1[:S]
+    border = pay0[S:]
+
+    if needs_matched_b:
+        # per build row in ROW order: one more sort, of the B-slice alone
+        _, cnt_s_row = _sort_carried(xp, [border, pay1[S:]], num_keys=1)
+        emit_r = xp.logical_and(r_alive, cnt_s_row == 0).astype(np.int64)
+    else:
+        emit_r = xp.zeros(B, dtype=np.int64)
+    if how in ("left", "full"):
+        emit_l = xp.where(l_alive, xp.maximum(matches_l, 1), 0)
+    elif how == "left_semi":
+        emit_l = matches_l > 0
+    elif how == "left_anti":
+        emit_l = xp.logical_and(l_alive, matches_l == 0)
+    else:   # inner, right
+        emit_l = matches_l
 
     emit_counts = xp.concatenate([emit_l.astype(np.int64), emit_r])
     emit_offsets = _exclusive_cumsum(xp, emit_counts)
     total = xp.sum(emit_counts)
-
-    # build rows sorted by gid (dead rows last); start_b is PER STREAM ROW
-    # (the first border-index of the row's group), replacing the dense
-    # per-group segment_min with the sorted-space prefix computed above
-    bkey = xp.where(bgid >= 0, bgid, G).astype(np.int64)
-    border = bk._stable_argsort(xp, bkey).astype(np.int32)
-
     return dict(emit_counts=emit_counts, emit_offsets=emit_offsets, total=total,
-                border=border, start_b=start_b_stream, sgid=sgid,
-                matches_l=matches_l.astype(np.int64))
+                border=border, start_b=start_b_stream, matches_l=matches_l)
 
 
 def join_gather(xp, sized: dict, S: int, B: int, out_cap: int, how: str):
@@ -189,7 +295,6 @@ def join_gather(xp, sized: dict, S: int, B: int, out_cap: int, how: str):
     emit_counts = sized["emit_counts"]
     border = sized["border"]
     start_b = sized["start_b"]
-    sgid = sized["sgid"]
     matches_l = sized["matches_l"]
     total = sized["total"]
 
