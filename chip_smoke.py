@@ -29,8 +29,10 @@ CONF = {"spark.rapids.tpu.sql.variableFloatAgg.enabled": "true",
 CPU_ENGINE = {"spark.rapids.tpu.sql.enabled": "false"}
 
 #: the collect leg's default. Q18 (1.5M-group aggregate, three joins) runs
-#: and passes on the chip but its programs alone compile for ~14 minutes cold
-#: (PERF.md section 5), so it is asked for: --queries 1 6 3 18
+#: and passes on the chip but its thirteen programs' first calls take 842 s
+#: cold (916 s to the first timed query; my chip run, PR 32, PERF.md section
+#: 6), so it is asked for: --queries 1 6 3 18. Its cell in the benchmark is
+#: tpch_sf1_highcard.q18
 COLLECT_QUERIES = (1, 6, 3)
 SERVED_QUERIES = (1, 6, 3)
 #: final sort keys that can tie (tests/test_tpch_full.py): unordered compare

@@ -1090,25 +1090,33 @@ class MeshHashAggregateExec(MeshExec):
         modes = (grouping_modes(self.grouping, fns) if self.grouping
                  else ["sort"])
         for mode in modes:
-            flagged_specs = ((P(),) if mode in ("hash", "onehot") else ())
+            fast = mode in ("hash", "onehot")
             fn = _shard_jit(
                 self.mesh, key + ("partial", mode), build_partial(mode),
                 in_specs,
-                (P(DATA_AXIS),) + _specs(npartial) + flagged_specs)
-            res = fn(mb.rows_dev(), *flatten_mesh(mb))
-            if mode in ("hash", "onehot"):
+                (P(DATA_AXIS),) + _specs(npartial) + ((P(),) if fast else ()))
+            # one span per attempted mode, as the single-device aggregate's:
+            # the program's call to the host's read of the flag or, on the
+            # attempt that is kept, of the shards' partial group counts
+            # (``groups`` is their sum); both reads were here before the span
+            with _tracing.span("agg.attempt", _tracing.LAYER_EXEC) as attempt:
+                res = fn(mb.rows_dev(), *flatten_mesh(mb))
                 # justified sync: the mesh-wide collision flag decides
                 # whether this grouping mode's result stands or the next
                 # mode runs — one scalar per attempted mode
-                if not bool(res[-1]):  # tpu-lint: disable=R002
-                    res = res[:-1]
-                    break
-            else:
+                flagged = fast and bool(res[-1])  # tpu-lint: disable=R002
+                if not flagged:
+                    ng = np.asarray(res[0]).astype(np.int32)
+                    total = int(ng.sum())
+                if attempt is not None:
+                    attempt.note(mode=mode, flagged=flagged, capacity=cap,
+                                 keys=len(self.grouping),
+                                 **({} if flagged else {"groups": total}))
+            if not flagged:
                 break
-        ng = np.asarray(res[0]).astype(np.int32)
-        partial = MeshBatch(pschema, mesh_columns(pschema, res[1:]), ng,
-                            self.mesh)
-        total = int(ng.sum())
+        partial = MeshBatch(
+            pschema, mesh_columns(pschema, res[1:-1] if fast else res[1:]),
+            ng, self.mesh)
 
         threshold = ctx.conf.get(cfg.MESH_AGG_REPARTITION_THRESHOLD)
         if self.grouping and total > threshold:

@@ -34,6 +34,7 @@ from spark_rapids_tpu.ops.aggregate import group_aggregate
 
 from spark_rapids_tpu.serving.program_cache import (global_program_cache,
                                                     named_jit)
+from spark_rapids_tpu.utils import tracing as _tracing
 
 _PROGRAM_CACHE = global_program_cache()
 #: legacy alias for the serving cache's program table: tests introspect its
@@ -503,20 +504,28 @@ class TpuHashAggregateExec(PhysicalExec):
         res = None
         for mode in modes:
             fn = _cached_jit(key + (mode,), build(mode))
-            res = fn(np.int32(batch.num_rows), *_flatten(batch), *enc_flat)
-            # justified sync: the escalation flag must be read on host to
-            # decide whether the faster grouping's result is exact or the
-            # next mode runs — one scalar per attempted mode, not per batch
-            flagged = (mode in ("hash", "onehot") and self.grouping
-                       and bool(res[-1]))  # tpu-lint: disable=R002
+            fast = mode in ("hash", "onehot")
+            # one span per attempted mode, from the program's call to the
+            # host's read of the flag (or, on the attempt that is kept, of
+            # the group count): both reads were here before the span
+            with _tracing.span("agg.attempt", _tracing.LAYER_EXEC) as attempt:
+                res = fn(np.int32(batch.num_rows), *_flatten(batch),
+                         *enc_flat)
+                # justified sync: the escalation flag must be read on host
+                # to decide whether the faster grouping's result is exact or
+                # the next mode runs — one scalar per attempted mode, not
+                # per batch
+                flagged = (fast and bool(self.grouping)
+                           and bool(res[-1]))  # tpu-lint: disable=R002
+                if not flagged:
+                    n = int(res[-2] if fast else res[-1])
+                if attempt is not None:
+                    attempt.note(mode=mode, flagged=flagged, capacity=cap,
+                                 keys=len(self.grouping),
+                                 **({} if flagged else {"groups": n}))
             if not flagged:
                 break
-        if mode in ("hash", "onehot"):
-            n = int(res[-2])
-            out = _to_batch(self.output, res[:-2], n)
-        else:
-            n = int(res[-1])
-            out = _to_batch(self.output, res[:-1], n)
+        out = _to_batch(self.output, res[:-2] if fast else res[:-1], n)
         self.count_output(n)
         yield out
 

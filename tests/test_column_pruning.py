@@ -10,7 +10,7 @@ import pyarrow.parquet as pq
 import pytest
 
 from benchmark.datagen import gen_tables
-from benchmark.queries import q1, q3, q6
+from benchmark.queries import q1, q3, q6, q18
 from spark_rapids_tpu.api import TpuSession
 from spark_rapids_tpu.api import functions as F
 from spark_rapids_tpu.columnar.dtypes import DType, Field, Schema
@@ -261,9 +261,10 @@ def _collect_unpruned(df):
     return pa.concat_tables(df._run_partitions(final, publish_trace=False))
 
 
+# Q18 scans lineitem twice, two columns each time
 @pytest.mark.parametrize("query,have,kept", [(q1, 16, 7), (q6, 16, 4),
-                                             (q3, 33, 10)],
-                         ids=["q1", "q6", "q3"])
+                                             (q3, 33, 10), (q18, 49, 10)],
+                         ids=["q1", "q6", "q3", "q18"])
 def test_the_benchmarks_queries_keep_the_columns_they_name(
         query, have, kept, tables, session):
     dfs = {n: session.createDataFrame(t) for n, t in tables.items()}
