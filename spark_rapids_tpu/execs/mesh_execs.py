@@ -1044,7 +1044,8 @@ class MeshHashAggregateExec(MeshExec):
 
     def execute(self, ctx: ExecContext) -> Iterator[MeshBatch]:
         from spark_rapids_tpu.ops.aggregate import (group_aggregate,
-                                                    grouping_modes)
+                                                    grouping_modes,
+                                                    reduce_form)
         from spark_rapids_tpu import config as cfg
         mb = self._one_child_batch(ctx)
         cap = mb.local_capacity
@@ -1111,6 +1112,7 @@ class MeshHashAggregateExec(MeshExec):
                 if attempt is not None:
                     attempt.note(mode=mode, flagged=flagged, capacity=cap,
                                  keys=len(self.grouping),
+                                 reduce=reduce_form(mode, cap),
                                  **({} if flagged else {"groups": total}))
             if not flagged:
                 break

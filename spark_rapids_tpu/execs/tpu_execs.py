@@ -496,7 +496,8 @@ class TpuHashAggregateExec(PhysicalExec):
         # the grouping rewrite (R016)
         key = ("agg", grouping, fns, pre_filter, used, tuple(subs.items()),
                schema, cap, ctx.string_max_bytes)
-        from spark_rapids_tpu.ops.aggregate import grouping_modes
+        from spark_rapids_tpu.ops.aggregate import (grouping_modes,
+                                                    reduce_form)
         modes = grouping_modes(grouping, fns)
         enc_flat = cenc.flatten_encodings(batch, used)
         if used:
@@ -522,6 +523,7 @@ class TpuHashAggregateExec(PhysicalExec):
                 if attempt is not None:
                     attempt.note(mode=mode, flagged=flagged, capacity=cap,
                                  keys=len(self.grouping),
+                                 reduce=reduce_form(mode, cap),
                                  **({} if flagged else {"groups": n}))
             if not flagged:
                 break

@@ -3,9 +3,12 @@
 The TPU replacement for cuDF's hash groupby (reference: aggregate.scala:227
 GpuHashAggregateExec -> Table.groupBy().aggregate()): keys are sorted (XLA's TPU
 sort is excellent and shape-static), group boundaries become segment ids, and
-aggregation buffers reduce via segment ops. The whole pipeline — key evaluation,
-buffer projection, sort, boundary detection, reduction, final evaluation — traces
-into ONE XLA program; group count is a traced scalar (row-count sidecar).
+aggregation buffers reduce over the sorted segments by segmented scans and one
+compaction sort that carries keys and reduced buffers, never by a scatter or a
+gather of the batch's capacity (bk.SortedSegmentStacker). The whole pipeline — key
+evaluation, buffer projection, sort, boundary detection, reduction, final
+evaluation — traces into ONE XLA program; group count is a traced scalar
+(row-count sidecar).
 
 Used eagerly with numpy by the CPU engine and traced with jax.numpy by the TPU
 exec, so both paths share one semantics definition.
@@ -119,20 +122,17 @@ def group_aggregate(xp, ctx: EvalCtx, key_exprs, agg_fns: Sequence[AggregateFunc
         sorted_alive = alive
         sorted_keys = []
         sorted_projs = projections
+        starts = None
 
     if keys and grouping == "hash":
-        # bounded group space: boundary-scan reduction emits GROUP_CAP-sized
-        # outputs; more groups than that re-runs through the exact sort path
-        # (flagged exactly like a hash collision)
+        # bounded group space: the reduction emits GROUP_CAP-sized outputs;
+        # more groups than that re-runs through the exact sort path (flagged
+        # exactly like a hash collision)
         out_cap = min(capacity, GROUP_CAP)
         collision = xp.logical_or(collision, num_groups > out_cap)
-        key_cols, reduced_per_fn = _reduce_phase_scan(
-            xp, sorted_keys, list(zip(agg_fns, sorted_projs)), gids,
-            num_groups, capacity, out_cap, sorted_alive)
-    else:
-        key_cols, reduced_per_fn = _reduce_phase(
-            xp, sorted_keys, list(zip(agg_fns, sorted_projs)), gids, capacity,
-            sorted_alive)
+    key_cols, reduced_per_fn = _reduce_phase(
+        xp, sorted_keys, list(zip(agg_fns, sorted_projs)), gids, capacity,
+        sorted_alive, starts, out_cap)
 
     group_alive = xp.arange(out_cap, dtype=np.int32) < num_groups
     result_cols = []
@@ -153,7 +153,7 @@ def group_aggregate(xp, ctx: EvalCtx, key_exprs, agg_fns: Sequence[AggregateFunc
     return key_cols, result_cols, num_groups
 
 
-#: static group-space bound of the boundary-scan reduction; queries producing
+#: static group-space bound of the hash-ordered mode's output; queries producing
 #: more groups re-run through the exact sort path
 GROUP_CAP = 65536
 
@@ -343,144 +343,42 @@ def _onehot_reduce_buffer(xp, spec, b: ColV, E, idx, capacity: int,
                 seg_valid)
 
 
-def _reduce_phase_scan(xp, sorted_keys, fn_bufs, gids, num_groups,
-                       capacity: int, out_cap: int, sorted_alive):
-    """Boundary-scan reduction over hash-ordered rows.
+def _reduce_phase(xp, sorted_keys, fn_bufs, gids, capacity: int, sorted_alive,
+                  starts, out_cap: int):
+    """Representative-key pick + per-fn buffer reduction over rows sorted by
+    group, for the first ``out_cap`` groups. Returns (key columns, reduced
+    buffers per fn).
 
-    TPU scatters cost ~100ns/row regardless of the segment space, while
-    cumsum and gathers run at memory bandwidth. With rows sorted by group,
-    INTEGER sums/counts reduce as cumsum differences at the group boundaries
-    (found with two searchsorted calls over the non-decreasing gids —
-    wrapping int arithmetic keeps them exact through any cumsum overflow) and
-    first/last/keys are single gathers at the boundary rows. FLOAT sums must
-    not use cumsum differences: the accumulator mixes other groups' values,
-    so a group that cancels to exactly 0.0 picks up an epsilon residue and
-    flips predicates like `HAVING sum(x) > 0` — they go through the stacked
-    scatter instead (one scatter per dtype, shared with min/max)."""
-    g = xp.arange(out_cap, dtype=np.int32)
-    start_pos = xp.searchsorted(gids, g, side="left")
-    end_pos = xp.searchsorted(gids, g, side="right") - 1
-    # dead rows keep the final gid: clamp the last group's end to alive rows
-    n_alive = xp.sum(sorted_alive).astype(np.int32)
-    end_pos = xp.minimum(end_pos, xp.maximum(n_alive - 1, 0))
-    has = g < num_groups
-    start_c = xp.clip(start_pos, 0, capacity - 1).astype(np.int32)
-    end_c = xp.clip(end_pos, 0, capacity - 1).astype(np.int32)
-    gids_b = xp.minimum(gids, np.int32(out_cap - 1))
-
-    key_cols = [_gather_key(xp, k, start_c, has) for k in sorted_keys]
-
-    def seg_sum(contrib):
-        c = xp.cumsum(contrib)
-        tail = c[end_c]
-        head = xp.where(start_c > 0, c[xp.clip(start_c - 1, 0, capacity - 1)],
-                        xp.zeros_like(tail))
-        return tail - head
-
-    stacker = (bk.SortedSegmentStacker(xp, gids_b, out_cap) if xp is not np
-               else None)
-    idx64 = xp.arange(capacity, dtype=np.int64)
-    thunk_lists = []
-    for fn, bufs in fn_bufs:
-        thunks = []
-        for spec, b in zip(fn.buffer_specs(), bufs):
-            if b.dtype is DType.STRING and spec.kind in ("min", "max"):
-                if stacker is not None:
-                    thunks.append(_register_minmax_string(
-                        xp, b, spec.kind, stacker, sorted_alive))
-                else:
-                    thunks.append(lambda b=b, spec=spec:
-                                  _segment_minmax_string(
-                                      xp, b, gids_b, out_cap, spec.kind,
-                                      sorted_alive))
-            elif spec.kind in ("first", "last") and spec.ignore_nulls:
-                if stacker is not None:
-                    thunks.append(_register_pick(
-                        xp, b, spec.kind, stacker, idx64, capacity,
-                        xp.logical_and(sorted_alive, b.validity)))
-                else:
-                    def pick(b=b, spec=spec):
-                        p2, h2 = bk.segment_pick(xp, b.validity, gids_b,
-                                                 out_cap, spec.kind,
-                                                 alive=sorted_alive,
-                                                 ignore_nulls=True)
-                        valid = xp.logical_and(h2, b.validity[p2])
-                        return bk.take_colv(xp, b, p2).with_validity(valid)
-                    thunks.append(pick)
-            elif spec.kind in ("first", "last"):
-                pos = start_c if spec.kind == "first" else end_c
-                thunks.append(lambda b=b, pos=pos: bk.take_colv(xp, b, pos)
-                              .with_validity(xp.logical_and(has,
-                                                            b.validity[pos])))
-            elif spec.kind == "sum" and not np.issubdtype(
-                    np.dtype(b.data.dtype), np.floating):
-                def int_sum(b=b):
-                    contrib = xp.where(b.validity, b.data,
-                                       0).astype(b.data.dtype)
-                    s = seg_sum(contrib)
-                    cnt = seg_sum(b.validity.astype(np.int32))
-                    return ColV(b.dtype, s, cnt > 0)
-                thunks.append(int_sum)
-            elif spec.kind == "sum":  # float: scatter, stacked on device
-                if stacker is not None:
-                    contrib = xp.where(b.validity, b.data,
-                                       0).astype(b.data.dtype)
-                    h = stacker.add("sum", contrib)
-                    hc = stacker.add("sum", b.validity.astype(np.int32))
-                    thunks.append(lambda b=b, h=h, hc=hc: ColV(
-                        b.dtype, stacker.get(h), stacker.get(hc) > 0))
-                else:
-                    def np_sum(b=b):
-                        data, valid = bk.segment_reduce(
-                            xp, b.data, b.validity, gids_b, out_cap, "sum")
-                        return ColV(b.dtype, data, valid)
-                    thunks.append(np_sum)
-            else:  # numeric/bool min-max
-                if stacker is not None:
-                    thunks.append(_register_minmax(xp, b, spec.kind, stacker))
-                else:
-                    def np_mm(b=b, spec=spec):
-                        data, valid = bk.segment_reduce(
-                            xp, b.data, b.validity, gids_b, out_cap,
-                            spec.kind)
-                        return ColV(b.dtype, data, valid)
-                    thunks.append(np_mm)
-        thunk_lists.append(thunks)
-    if stacker is not None and stacker._buckets:
-        stacker.run()
-    reduced = [[t() for t in thunks] for thunks in thunk_lists]
-    return key_cols, reduced
-
-
-def _reduce_phase(xp, sorted_keys, fn_bufs, gids, capacity: int, sorted_alive):
-    """Representative-key pick + per-fn buffer reduction.
-
-    numpy path: eager per-buffer segment ops. Device path: every segment
-    contribution — the key pick's index min and each buffer's reduction —
-    registers with ONE SegmentStacker, so all reductions of a kind/dtype run
-    as a single stacked scatter."""
+    numpy path: eager per-buffer segment ops, the definition of the
+    semantics. Device path: every buffer's contributions register with ONE
+    bk.SortedSegmentStacker, which reduces them by segmented scans and moves
+    each group's first row, key and reductions, to the front by one
+    compaction sort: no scatter and no gather of the batch's capacity, and a
+    float sum adds a group's own rows only (a group that cancels to exactly
+    0.0 keeps `HAVING sum(x) > 0` false whatever its neighbours hold)."""
     if xp is np:
+        gids = np.minimum(gids, out_cap - 1)
         pick, has = bk.segment_pick(xp, xp.ones_like(sorted_alive), gids,
-                                    capacity, "first", alive=sorted_alive)
+                                    out_cap, "first", alive=sorted_alive)
         key_cols = [_gather_key(xp, k, pick, has) for k in sorted_keys]
-        reduced = [_reduce_buffers(xp, fn, bufs, gids, capacity, sorted_alive)
+        reduced = [_reduce_buffers(xp, fn, bufs, gids, out_cap, sorted_alive)
                    for fn, bufs in fn_bufs]
         return key_cols, reduced
 
-    stacker = bk.SortedSegmentStacker(xp, gids, capacity)
-    idx = xp.arange(capacity, dtype=np.int64)
-    hpick = stacker.add("min", xp.where(sorted_alive, idx,
-                                        np.int64(capacity + 1)))
-    thunk_lists = [_register_reduce(xp, fn, bufs, gids, capacity,
-                                    sorted_alive, stacker)
+    stacker = bk.SortedSegmentStacker(xp, gids, out_cap)
+    thunk_lists = [_register_reduce(xp, fn, bufs, capacity, sorted_alive,
+                                    stacker)
                    for fn, bufs in fn_bufs]
-    stacker.run()
-    key = stacker.get(hpick)
-    has = key < capacity
-    pick = xp.clip(key, 0, capacity - 1)
-    key_cols = [_gather_key(xp, k, pick, has) for k in sorted_keys]
+    key_cols = stacker.run(sorted_keys, starts, sorted_alive)
     reduced = [[t() for t in thunks] for thunks in thunk_lists]
     return key_cols, reduced
+
+
+def reduce_form(mode: str, capacity: int) -> str:
+    """What an ``agg.attempt`` span says of how a grouping mode reduces at
+    this capacity: ``"onehot"`` (no sorted segments), else the stacker's
+    ``"scan"`` or ``"plain"``."""
+    return "onehot" if mode == "onehot" else bk.segment_reduce_form(capacity)
 
 
 def _gather_key(xp, k: ColV, pick, has) -> ColV:
@@ -545,11 +443,12 @@ def _reduce_buffers(xp, fn: AggregateFunction, bufs: Sequence[ColV], gids,
     return reduced
 
 
-def _register_reduce(xp, fn: AggregateFunction, bufs: Sequence[ColV], gids,
-                     capacity: int, sorted_alive, stacker: "bk.SegmentStacker"):
+def _register_reduce(xp, fn: AggregateFunction, bufs: Sequence[ColV],
+                     capacity: int, sorted_alive,
+                     stacker: "bk.SortedSegmentStacker"):
     """Device-path reduction, phase 1: register every segment contribution
-    with the stacker; returns a thunk producing the reduced ColVs after
-    stacker.run(). One stacked scatter per (kind, dtype) replaces the
+    with the stacker; returns thunks producing the reduced ColVs after
+    stacker.run(). One stacked reduction per (kind, dtype) replaces the
     per-buffer segment calls of _reduce_buffers."""
     idx = xp.arange(capacity, dtype=np.int64)
     thunks = []
@@ -573,10 +472,11 @@ def _register_reduce(xp, fn: AggregateFunction, bufs: Sequence[ColV], gids,
     return thunks
 
 
-def _register_pick(xp, b: ColV, kind: str, stacker: "bk.SegmentStacker",
-                   idx, capacity: int, candidate):
+def _register_pick(xp, b: ColV, kind: str,
+                   stacker: "bk.SortedSegmentStacker", idx, capacity: int,
+                   candidate):
     """first/last pick through the stacker: masked row-index min/max, then a
-    tiny gather — replaces the full-row segment_pick scatter."""
+    gather of one row a group."""
     if kind == "first":
         h = stacker.add("min", xp.where(candidate, idx,
                                         np.int64(capacity + 1)))
@@ -593,16 +493,16 @@ def _register_pick(xp, b: ColV, kind: str, stacker: "bk.SegmentStacker",
 
 
 def _register_minmax_string(xp, b: ColV, kind: str,
-                            stacker: "bk.SegmentStacker", sorted_alive):
+                            stacker: "bk.SortedSegmentStacker", sorted_alive):
     """String min/max through the stacker: the per-segment lowest/highest-
-    ranked pick rides the stacked int reduction instead of a full-row
-    scatter."""
+    ranked pick rides the stacked int reduction."""
     order, masked, n = _string_rank(xp, b, kind, sorted_alive)
     h = stacker.add(kind, masked)
     return lambda: _string_pick(xp, b, order, stacker.get(h), n)
 
 
-def _register_minmax(xp, b: ColV, kind: str, stacker: "bk.SegmentStacker"):
+def _register_minmax(xp, b: ColV, kind: str,
+                     stacker: "bk.SortedSegmentStacker"):
     """Stacked numeric/bool min-max with Spark NaN ordering (mirrors
     bk._segment_minmax_jax semantics)."""
     hc = stacker.add("sum", b.validity.astype(np.int32))
@@ -675,6 +575,7 @@ def merge_aggregate(xp, key_cols: Sequence[ColV], buffer_cols: Sequence[ColV],
         sorted_alive = alive
         sorted_keys = []
         sorted_bufs = list(buffer_cols)
+        starts = None
 
     fn_bufs = []
     i = 0
@@ -682,8 +583,9 @@ def merge_aggregate(xp, key_cols: Sequence[ColV], buffer_cols: Sequence[ColV],
         specs = fn.buffer_specs()
         fn_bufs.append((fn, sorted_bufs[i:i + len(specs)]))
         i += len(specs)
-    out_keys, reduced_per_fn = _reduce_phase(xp, sorted_keys, fn_bufs, gids,
-                                             capacity, sorted_alive)
+    out_keys, reduced_per_fn = _reduce_phase(
+        xp, sorted_keys, fn_bufs, gids, capacity, sorted_alive, starts,
+        capacity)
 
     group_alive = xp.arange(capacity, dtype=np.int32) < num_groups
     result_cols = []

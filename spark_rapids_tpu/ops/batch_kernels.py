@@ -712,48 +712,6 @@ def _np_minmax(data, validity, seg_ids, num_segments, kind):
     return out
 
 
-class SegmentStacker:
-    """Batches many same-kind per-segment reductions into ONE segment op.
-
-    TPU scatters pay a cost proportional to the row count per CALL, so k
-    separate segment_sum/min/max calls over the same seg_ids cost ~k scatters;
-    stacking the contributions as an [n, k] payload makes them ONE scatter
-    (measured ~8x on a v5 chip for 12 columns). Register contributions with
-    :meth:`add` (caller applies its own neutral-element masking), call
-    :meth:`run` once, then fetch columns via the returned handles.
-    """
-
-    def __init__(self, xp, seg_ids, num_segments: int):
-        self.xp = xp
-        self.seg_ids = seg_ids
-        self.num_segments = num_segments
-        self._buckets = {}
-        self._results = {}
-        self._ran = False
-
-    def add(self, kind: str, contrib):
-        assert not self._ran
-        key = (kind, str(contrib.dtype))
-        bucket = self._buckets.setdefault(key, [])
-        bucket.append(contrib)
-        return (key, len(bucket) - 1)
-
-    def run(self) -> None:
-        import jax
-        self._ran = True
-        ops = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
-               "max": jax.ops.segment_max}
-        for key, arrs in self._buckets.items():
-            kind, _ = key
-            m = self.xp.stack(arrs, axis=1)
-            self._results[key] = ops[kind](m, self.seg_ids,
-                                           num_segments=self.num_segments)
-
-    def get(self, handle):
-        key, idx = handle
-        return self._results[key][:, idx]
-
-
 def key_words(xp, v: ColV) -> List:
     """Injective uint64 encoding of one grouping-key column: a static-length
     word list such that two rows are grouping-equal (Spark semantics:
@@ -812,81 +770,182 @@ def validity_word(xp, keys: Sequence[ColV]):
     return w
 
 
-#: block shape of the sorted-segment reduction: B consecutive sorted rows
-#: reduce into L block-local one-hot slots. A block spanning >= L distinct
-#: segments trips the traced overflow flag and the program falls back to the
-#: full scatter (correct at the old speed).
+#: block width of the segmented scan: rows scan block-locally first, then the
+#: per-block carries; and the least the scan form is worth setting up for
 _SEG_BLOCK_B = 512
-_SEG_BLOCK_L = 16
+
+_SEG_OPS = {"sum": "add", "min": "minimum", "max": "maximum"}
 
 
-class SortedSegmentStacker(SegmentStacker):
-    """SegmentStacker over SORTED (non-decreasing) seg_ids.
+def segment_reduce_form(capacity: int) -> str:
+    """Which form SortedSegmentStacker takes at this capacity: ``"scan"``, or
+    ``"plain"`` under 4*B rows or where B does not divide them (such batches
+    are tiny). Static, so an exec can say it on its span without a read."""
+    B = _SEG_BLOCK_B
+    return "plain" if capacity % B or capacity < 4 * B else "scan"
 
-    TPU scatters cost ~100ns/row regardless of segment count, which made the
-    stacked scatter the dominant kernel of every aggregation (~0.6s for 6M
-    rows on v5e). With sorted ids, rows reduce block-locally first: each
-    block of B rows builds a [B, L] one-hot against its local id offsets and
-    reduces to L partials, then only nb*L partials (a ~B/L-fold reduction in
-    scattered rows) go through the real scatter. Blocks spanning >= L
-    segments flip a traced overflow flag; a lax.cond then routes the stacked
-    contributions through the plain full scatter instead, so skewed/tiny-group
-    inputs stay correct. Measured ~9x over the full scatter at 6M rows.
-    """
 
-    def run(self) -> None:
+def _shift_left(xp, a, d: int, fill):
+    """``a`` moved ``d`` places towards index 0 along its last axis, the
+    vacated tail filled with ``fill``."""
+    pad = xp.full(a.shape[:-1] + (d,), fill, dtype=a.dtype)
+    return xp.concatenate([a[..., d:], pad], axis=-1)
+
+
+def _scan_steps(xp, f, vs, kinds):
+    """log2(n) shift-and-combine steps of a reverse inclusive segmented scan
+    along the last axis: on return ``vs[c][..., i]`` reduces rows ``i..`` up
+    to the first row ``j >= i`` with ``f[..., j]`` set (or the axis' end),
+    and ``f[..., i]`` says that such a row exists. Elementwise passes over
+    shifted copies only: no gather, no scatter, no strided slice."""
+    d, n = 1, f.shape[-1]
+    while d < n:
+        # past the axis' end a neutral element comes in: combining it
+        # changes nothing
+        vs = [xp.where(f, v, getattr(xp, _SEG_OPS[k])(
+            v, _shift_left(xp, v, d, _neutral(xp, k, v.dtype))))
+            for k, v in zip(kinds, vs)]
+        f = xp.logical_or(f, _shift_left(xp, f, d, False))
+        d *= 2
+    return f, vs
+
+
+def segmented_scan(xp, ends, kinds: Sequence[str], cols: Sequence):
+    """Reverse inclusive segmented scan of every column at once: row ``i``
+    receives the sum/min/max (``kinds[c]``) of ``cols[c]`` over rows ``i``
+    to the last row of its segment, ``ends`` marking each segment's last
+    row — so a segment's FIRST row holds the whole segment's reduction.
+
+    A scan that resets at the marks reduces a segment's own rows only
+    (never a difference of running totals: a float group that cancels to
+    0.0 must not pick up its neighbours' residue, nor an ``inf`` next door
+    turn it into NaN). Long axes scan block-locally first (B columns: 9
+    steps over the whole array), then the per-block carries (n/B rows)
+    recursively, then one pass applies each block's carry to the rows its
+    open segment covers."""
+    B = _SEG_BLOCK_B
+    n = ends.shape[0]
+    cols = list(cols)
+    if segment_reduce_form(n) == "plain":     # short: one flat pass
+        return _scan_steps(xp, ends, cols, kinds)[1]
+    nb = n // B
+    lf, lv = _scan_steps(xp, ends.reshape(nb, B),
+                         [c.reshape(nb, B) for c in cols], kinds)
+    # a block's reduction lands on its first column; the carry INTO block b
+    # is the scan of the blocks after it
+    below = segmented_scan(xp, lf[:, 0], kinds, [v[:, 0] for v in lv])
+    out = []
+    for k, v, c in zip(kinds, lv, below):
+        carry = _shift_left(xp, c, 1, _neutral(xp, k, c.dtype))[:, None]
+        out.append(xp.where(lf, v, getattr(xp, _SEG_OPS[k])(v, carry))
+                   .reshape(n))
+    return out
+
+
+class SortedSegmentStacker:
+    """Every per-group reduction of one aggregation over rows SORTED by
+    group (non-decreasing ``gids``, dead rows last), in one go, together
+    with each group's representative key: its first sorted row.
+
+    Register contributions with :meth:`add` (the caller applies its own
+    neutral-element masking, so dead rows and nulls reduce to nothing),
+    call :meth:`run` once with the key columns, then fetch the reduced
+    columns via the returned handles. Output row g is group g, for the
+    first ``num_segments`` groups.
+
+    TPU scatters and gathers cost ~100 ns a row whatever the segment space
+    (0.75-0.80 s for one 64-bit column of 8.4 M rows on a v5e), so the
+    **scan** form has neither: :func:`segmented_scan` leaves every group's
+    reduction on its first row, and ONE compaction sort keyed on "not a
+    group start" moves those rows to the front in group order, carrying the
+    key columns and every reduced column as operands (sort_colvs). Without
+    keys there is one group and nothing to compact. On a v5e at 8.4 M rows:
+    4 ms the scans of a float64 and an int32 column, 58 ms the sort.
+
+    The **plain** form (segment_reduce_form: tiny batches) is one stacked
+    scatter per (kind, dtype) over all rows and a gather of the keys."""
+
+    def __init__(self, xp, gids, num_segments: int):
+        self.xp = xp
+        self.gids = gids
+        self.num_segments = num_segments
+        self._kinds = []
+        self._cols = []
+        self._results = None
+
+    def add(self, kind: str, contrib):
+        assert self._results is None
+        self._kinds.append(kind)
+        self._cols.append(contrib)
+        return len(self._cols) - 1
+
+    def get(self, handle):
+        return self._results[handle]
+
+    def run(self, keys: Sequence[ColV] = (), starts=None,
+            alive=None) -> List[ColV]:
+        """Reduce what was registered; returns the key columns of the
+        groups (``starts`` marks each group's first row, ``alive`` the live
+        rows; neither is read without keys)."""
+        form = segment_reduce_form(self.gids.shape[0])
+        run = self._scan if form == "scan" else self._scatter
+        out_keys, self._results = run(keys, starts, alive)
+        return out_keys
+
+    def _scan(self, keys, starts, alive):
+        xp = self.xp
+        cap = self.gids.shape[0]
+        # a group ends where the next one starts; the last row ends the last
+        ends = (_shift_left(xp, starts, 1, True) if keys
+                else xp.arange(cap, dtype=np.int32) == cap - 1)
+        reduced = segmented_scan(xp, ends, self._kinds, self._cols)
+        out_keys = []
+        if keys:
+            out_keys, reduced = sort_colvs(
+                xp, [xp.logical_not(starts).astype(np.int8)], keys, reduced)
+        n = self.num_segments
+        return [ColV(k.dtype, k.data[:n], k.validity[:n],
+                     None if k.lengths is None else k.lengths[:n])
+                for k in out_keys], [a[:n] for a in reduced]
+
+    def _scatter(self, keys, starts, alive):
         import jax
         xp = self.xp
-        gids = self.seg_ids
-        cap = gids.shape[0]
-        B, L = _SEG_BLOCK_B, _SEG_BLOCK_L
-        if xp is np or cap % B or cap < 4 * B:
-            super().run()
-            return
-        nb = cap // B
-        g2 = gids.reshape(nb, B)
-        first = g2[:, :1]
-        overflow = xp.any((g2[:, -1:] - first) >= L)
-        loc = xp.clip(g2 - first, 0, L - 1)
-        onehot = loc[:, :, None] == xp.arange(L, dtype=gids.dtype)[None, None, :]
-        pg = xp.clip(first + xp.arange(L, dtype=np.int32)[None, :], 0,
-                     self.num_segments - 1).reshape(-1)
-
+        cap = self.gids.shape[0]
+        n = self.num_segments
+        seg_ids = xp.minimum(self.gids, n - 1)
         ops = {"sum": jax.ops.segment_sum, "min": jax.ops.segment_min,
                "max": jax.ops.segment_max}
-        self._ran = True
-        for key, arrs in self._buckets.items():
-            kind, _ = key
-            m = xp.stack(arrs, axis=1)          # [cap, k]
-            dt = m.dtype
-            if kind == "sum":
-                neutral = xp.zeros((), dtype=dt)
-            elif kind == "min":
-                neutral = (xp.asarray(np.inf, dt)
-                           if np.issubdtype(dt, np.floating)
-                           else xp.asarray(np.iinfo(dt).max, dt))
-            else:
-                neutral = (xp.asarray(-np.inf, dt)
-                           if np.issubdtype(dt, np.floating)
-                           else xp.asarray(np.iinfo(dt).min, dt))
 
-            def blocked(m, kind=kind, neutral=neutral):
-                k = m.shape[1]
-                mb = m.reshape(nb, B, 1, k)
-                masked = xp.where(onehot[:, :, :, None], mb, neutral)
-                if kind == "sum":
-                    part = xp.sum(masked, axis=1, dtype=m.dtype)
-                elif kind == "min":
-                    part = xp.min(masked, axis=1)
-                else:
-                    part = xp.max(masked, axis=1)
-                return ops[kind](part.reshape(nb * L, k).astype(m.dtype), pg,
-                                 num_segments=self.num_segments)
+        def reduce(kind, arrs):
+            res = ops[kind](xp.stack(arrs, axis=1), seg_ids, num_segments=n)
+            return [res[:, j] for j in range(len(arrs))]
 
-            def full(m, kind=kind):
-                return ops[kind](m, gids, num_segments=self.num_segments)
+        # a scatter costs by the call: one per (kind, dtype), stacked
+        buckets = {}
+        for h, (kind, c) in enumerate(zip(self._kinds, self._cols)):
+            buckets.setdefault((kind, str(c.dtype)), []).append(h)
+        results = [None] * len(self._cols)
+        for (kind, _), hs in buckets.items():
+            for h, col in zip(hs, reduce(kind, [self._cols[h] for h in hs])):
+                results[h] = col
+        out_keys = []
+        if keys:
+            idx = xp.arange(cap, dtype=np.int64)
+            (at,) = reduce("min", [xp.where(alive, idx, np.int64(cap + 1))])
+            pick = xp.clip(at, 0, cap - 1)
+            out_keys = [v.with_validity(xp.logical_and(at < cap, v.validity))
+                        for v in (take_colv(xp, k, pick) for k in keys)]
+        return out_keys, results
 
-            self._results[key] = jax.lax.cond(overflow, full, blocked, m)
+
+def _neutral(xp, kind: str, dt):
+    if kind == "sum":
+        return xp.zeros((), dtype=dt)
+    if np.issubdtype(dt, np.floating):
+        return xp.asarray(np.inf if kind == "min" else -np.inf, dt)
+    return xp.asarray(np.iinfo(dt).max if kind == "min"
+                      else np.iinfo(dt).min, dt)
 
 
 def take_columns(xp, columns: Sequence[ColV], indices) -> List[ColV]:

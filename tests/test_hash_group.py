@@ -1,5 +1,5 @@
 """Hash-ordered grouping fast path: row-hash semantics, collision detection,
-boundary-scan reduction, and the filter/project fusion into aggregation."""
+bounded-group reduction, and the filter/project fusion into aggregation."""
 import numpy as np
 import pyarrow as pa
 import pytest

@@ -8,7 +8,7 @@ import pytest
 
 from benchmark import correct
 from benchmark.datagen import gen_tables
-from benchmark.queries import q1, q18
+from benchmark.queries import q1, q6, q18
 from benchmark.reference import q18 as q18_reference
 from spark_rapids_tpu.api import TpuSession
 from spark_rapids_tpu.api import functions as F
@@ -142,6 +142,34 @@ def test_q1_leaves_one_onehot_attempt_and_discards_nothing(tables):
                                                               False)
     assert attempt.args["groups"] == got.num_rows == 4
     assert attempt.args["keys"] == 2
+    assert attempt.args["reduce"] == "onehot"
+
+
+def _reduces(attempts, keys):
+    return [(r.args["mode"], r.args["reduce"]) for r in attempts
+            if r.args["keys"] == keys]
+
+
+def test_the_spans_say_which_form_the_reduction_took(tables, small_group_cap):
+    """Q18's first aggregate: ``hash`` and ``sort`` both reduce their sorted
+    rows by scans (the one-hot path has no sorted segments to reduce); the
+    last one, a row or two here and some 70 at SF1, stays under the least
+    capacity of the scans (the plain form, which
+    tests/test_dense_segment_reduce.py drives). Q6 has no keys: one
+    segment, scanned."""
+    session, dfs = _traced(tables)
+    q18.build(dfs).collect()
+    attempts = _attempts(session)
+    assert _reduces(attempts, 1) == [("onehot", "onehot"), ("hash", "scan"),
+                                     ("sort", "scan")]
+    assert attempts[0].args["capacity"] >= 2048
+    assert _reduces(attempts, 5) == [("onehot", "onehot")]
+    assert attempts[-1].args["capacity"] < 2048
+    q6.build(dfs).collect()
+    (attempt,) = _attempts(session)
+    assert (attempt.args["mode"], attempt.args["reduce"],
+            attempt.args["keys"]) == ("hash", "scan", 0)
+    assert attempt.args["capacity"] >= 2048
 
 
 def test_without_tracing_the_ladder_leaves_nothing(seeded):
@@ -171,6 +199,8 @@ def test_the_mesh_aggregate_leaves_the_same_spans(
     assert "MeshHashAggregateExec" in session.last_plan.tree_string()
     attempts = _attempts(session)
     assert _ladder(attempts, 1) == ladder
+    assert _reduces(attempts, 1) == [("onehot", "onehot")] + [
+        (mode, "scan") for mode, _ in ladder[1:]]
     distinct = _distinct_orderkeys(tables)
     assert distinct <= attempts[-1].args["groups"] <= distinct + 3
     assert all("groups" not in r.args for r in attempts[:-1])
