@@ -2,7 +2,10 @@
 
 One process, in order: device check, a collect leg (Q1, Q6, Q3 through
 ``TpuSession.collect``), an exchange leg (both Pallas kernels, compiled by
-Mosaic), a served leg (``QueryServer`` + ``QueryServiceClient`` over TCP
+Mosaic; every row compared, partition by partition: the placement that the
+benchmark's cell of the exchange, ``tpch_sf1_exchange.repartition``, cannot
+see in Q1's answer, while the cell times what this leg only walks), a served
+leg (``QueryServer`` + ``QueryServiceClient`` over TCP
 localhost) and, on four or more devices, a mesh leg. Every answer is compared
 with the CPU engine's on the same tables, outside the timed calls. The first
 failed check ends the process with a non-zero code; without a TPU it fails

@@ -70,6 +70,15 @@ class DeviceColumn:
             total += self.bits.size * 8
         return total
 
+    @property
+    def row_bytes(self) -> int:
+        """One row's bytes at the stored width, validity byte and string
+        length included: what moving a row (between partitions, between
+        shards) must carry. From the arrays' shapes alone."""
+        width = int(np.prod(self.data.shape[1:]))
+        return (self.data.dtype.itemsize * width + 1
+                + (4 if self.lengths is not None else 0))
+
     def __post_init__(self):
         if self.dtype is DType.STRING and self.lengths is None:
             raise ValueError("string column requires lengths vector")

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import itertools
 import threading
+import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -47,12 +48,14 @@ from spark_rapids_tpu.exprs.core import (ColV, EvalCtx, Expression,
                                          flatten_colvs)
 from spark_rapids_tpu.exprs.misc import SortOrder
 from spark_rapids_tpu.ops import batch_kernels as bk
+from spark_rapids_tpu.utils import tracing as _tracing
 
 
 # ------------------------------------------------------------------ partitionings
 @dataclass(frozen=True)
 class Partitioning:
-    """Base partitioning spec (GpuPartitioning analog)."""
+    """Base partitioning spec (GpuPartitioning analog). ``kind`` names it
+    in the ``exchange.map`` span's ``partitioning`` arg."""
     num_partitions: int
 
     @property
@@ -64,12 +67,14 @@ class Partitioning:
 class SinglePartitioning(Partitioning):
     """Everything into one partition (GpuSinglePartitioning analog)."""
     num_partitions: int = 1
+    kind = "single"
 
 
 @dataclass(frozen=True)
 class RoundRobinPartitioning(Partitioning):
     """Row-cycling distribution (GpuRoundRobinPartitioning analog; start
     offset varies per map partition/batch like Spark's per-partition start)."""
+    kind = "roundrobin"
 
 
 @dataclass(frozen=True)
@@ -77,6 +82,7 @@ class HashPartitioning(Partitioning):
     """Key-hash distribution (GpuHashPartitioning analog — murmur3-style
     finalizer over the key columns instead of cudf's murmur3 kernel)."""
     keys: Tuple[Expression, ...] = ()
+    kind = "hash"
 
     @property
     def expressions(self) -> Tuple[Expression, ...]:
@@ -89,6 +95,7 @@ class RangePartitioning(Partitioning):
     GpuRangePartitioner analog). Bounds are computed at map time from a
     deterministic sample of the input (SamplingUtils role)."""
     orders: Tuple[SortOrder, ...] = ()
+    kind = "range"
 
     @property
     def expressions(self) -> Tuple[Expression, ...]:
@@ -841,17 +848,31 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         no data moves or re-splits)."""
         self._ensure_map(ctx)
         env = _local_shuffle_env(ctx)
-        for block in env.shuffle_catalog.blocks_for_partition(
-                self._shuffle_id, ctx.partition_id):
-            if map_filter is not None and block.map_id not in map_filter:
-                continue
-            for buf, _meta in env.shuffle_catalog.acquire_buffers(block):
-                try:
-                    batch = buf.get_batch()
-                finally:
-                    buf.close()
-                self.count_output(batch.num_rows)
-                yield batch
+        t0 = time.perf_counter_ns() if _tracing.enabled() else 0
+        blocks = rows = 0
+        try:
+            for block in env.shuffle_catalog.blocks_for_partition(
+                    self._shuffle_id, ctx.partition_id):
+                if map_filter is not None and block.map_id not in map_filter:
+                    continue
+                blocks += 1
+                for buf, _meta in env.shuffle_catalog.acquire_buffers(block):
+                    try:
+                        batch = buf.get_batch()
+                    finally:
+                        buf.close()
+                    self.count_output(batch.num_rows)
+                    rows += batch.num_rows
+                    yield batch
+        finally:
+            if t0:
+                # the reduce side of one partition: first catalog lookup to
+                # the consumer's last pull (its work between pulls included)
+                _tracing.record(
+                    "exchange.read", _tracing.LAYER_SHUFFLE, t0,
+                    time.perf_counter_ns() - t0,
+                    {"partition": ctx.partition_id, "blocks": blocks,
+                     "rows": rows})
 
     # ---- map side ------------------------------------------------------------
     def iter_map_pieces(self, ctx: ExecContext,
@@ -904,29 +925,69 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         if ctx.cleanups is not None:
             ctx.cleanups.append(
                 lambda: env.shuffle_catalog.remove_shuffle(sid))
-        sketch = isinstance(self.partitioning, HashPartitioning)
-        for map_p, j, sub in self.iter_map_pieces(ctx):
-            if sketch and sub.num_rows > 0:
-                self._sketch_keys_device(ctx, sub)
-            sub = uniform_string_batch(sub)
-            layout = DevicePackLayout.for_batch_shape(
-                sub.schema, sub.capacity, batch_string_max(sub))
-            meta = layout_to_meta(layout, sub.num_rows)
-            env.shuffle_catalog.add_batch(
-                ShuffleBlockId(sid, map_p, j), sub, meta)
-            self._part_rows[j] = self._part_rows.get(j, 0) + sub.num_rows
-            self._map_part_rows[(map_p, j)] = \
-                self._map_part_rows.get((map_p, j), 0) + sub.num_rows
+        part = self.partitioning
+        sketch = isinstance(part, HashPartitioning)
+        # one span per run of the map side; its args come from what the host
+        # holds anyway (piece row counts, array shapes, the exec's metrics)
+        with _tracing.span("exchange.map", _tracing.LAYER_SHUFFLE) as span:
+            if span is not None:
+                split0 = (self.metrics[KERNEL_SPLIT_BATCHES].value,
+                          self.metrics[SORT_SPLIT_BATCHES].value)
+            pieces = rows = nbytes = 0
+            for map_p, j, sub in self.iter_map_pieces(ctx):
+                if span is not None:
+                    pieces += 1
+                    rows += sub.num_rows
+                    nbytes += sub.num_rows * sum(
+                        c.row_bytes for c in sub.columns)
+                if sketch and sub.num_rows > 0:
+                    self._sketch_keys_device(ctx, sub)
+                sub = uniform_string_batch(sub)
+                layout = DevicePackLayout.for_batch_shape(
+                    sub.schema, sub.capacity, batch_string_max(sub))
+                meta = layout_to_meta(layout, sub.num_rows)
+                env.shuffle_catalog.add_batch(
+                    ShuffleBlockId(sid, map_p, j), sub, meta)
+                self._part_rows[j] = self._part_rows.get(j, 0) + sub.num_rows
+                self._map_part_rows[(map_p, j)] = \
+                    self._map_part_rows.get((map_p, j), 0) + sub.num_rows
+            if span is not None:
+                span.note(
+                    partitioning=part.kind,
+                    partitions=part.num_partitions, rows=rows, bytes=nbytes,
+                    pieces=pieces,
+                    kernel_batches=(self.metrics[KERNEL_SPLIT_BATCHES].value
+                                    - split0[0]),
+                    sort_batches=(self.metrics[SORT_SPLIT_BATCHES].value
+                                  - split0[1]))
 
     def _split_batch(self, ctx, part, db: DeviceBatch, offset: int, n: int,
                      bounds):
-        """One jitted program: pids + partition-major reorder + counts."""
+        """One map-side batch -> its (reduce pid, piece) pairs. The
+        ``exchange.split`` span runs from the split program's call to the
+        host's read of its counts (a read the slicing needs anyway) and the
+        dispatch of each piece's consolidation; the pieces are handed on
+        after it closes."""
+        with _tracing.span("exchange.split", _tracing.LAYER_SHUFFLE) as span:
+            path, widenings, pieces = self._split_pieces(ctx, part, db,
+                                                         offset, n, bounds)
+            if span is not None:
+                span.note(path=path, widenings=widenings, rows=db.num_rows,
+                          cap=db.capacity)
+        yield from pieces
+
+    def _split_pieces(self, ctx, part, db: DeviceBatch, offset: int, n: int,
+                      bounds) -> Tuple[str, int, List[Tuple[int, DeviceBatch]]]:
+        """(path, widenings, pieces): which split ran (``single``: the batch
+        passes through; ``encoded`` / ``kernel`` / ``sort``: one jitted
+        program of pids + partition-major reorder + counts), how many runs
+        of the reorder kernel were thrown away for a window overflow before
+        the one that stood, and every non-empty piece."""
         schema = db.schema
         cap = db.capacity
         smax = ctx.string_max_bytes
         if isinstance(part, SinglePartitioning) or n == 1:
-            yield 0, db
-            return
+            return "single", 0, [(0, db)]
         enc = _exchange_encodings(ctx, db)
         if enc and _encoded_split_preferred(ctx, part, db, enc):
             # dictionary-encoded columns ride the exchange as int32 INDICES
@@ -935,18 +996,16 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
             # bytes/row where a decoded string column moves its full
             # byte-matrix row
             self.metrics[SORT_SPLIT_BATCHES].add(1)
-            yield from self._split_batch_encoded(ctx, part, db, offset, n,
-                                                 bounds, enc)
-            return
+            return "encoded", 0, self._split_batch_encoded(
+                ctx, part, db, offset, n, bounds, enc)
         # fused Pallas reorder (shuffle/partition_kernel.py): one streaming
         # HBM pass instead of the variadic sort; quota overflow, non-packable
         # schemas or inexact f64 expansion fall back to the sort path below
         if bounds is None:
-            pieces = self._kernel_split(ctx, part, db, offset, n)
-            if pieces is not None:
+            res = self._kernel_split(ctx, part, db, offset, n)
+            if res is not None:
                 self.metrics[KERNEL_SPLIT_BATCHES].add(1)
-                yield from pieces
-                return
+                return ("kernel",) + res
         self.metrics[SORT_SPLIT_BATCHES].add(1)
         bounds_flat = tuple(flatten_colvs(bounds)) if bounds else ()
         nb = bounds[0].validity.shape[0] if bounds else 0
@@ -987,11 +1046,10 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         counts = np.asarray(res[-1])
         sorted_cols = _unflatten_colvs(schema, res[:-1])
         offsets = np.concatenate([[0], np.cumsum(counts)])
-        for j in range(n):
-            cnt = int(counts[j])
-            if cnt == 0:
-                continue
-            yield j, _slice_padded(sorted_cols, schema, int(offsets[j]), cnt)
+        return "sort", 0, [
+            (j, _slice_padded(sorted_cols, schema, int(offsets[j]),
+                              int(counts[j])))
+            for j in range(n) if counts[j]]
 
     def _split_batch_encoded(self, ctx, part, db: DeviceBatch, offset: int,
                              n: int, bounds, enc):
@@ -1081,13 +1139,11 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         counts = np.asarray(res[-1])
         sorted_wire = _unflatten_colvs(wire_schema, res[:-1])
         offsets = np.concatenate([[0], np.cumsum(counts)])
-        for j in range(n):
-            cnt = int(counts[j])
-            if cnt == 0:
-                continue
-            piece = _slice_padded(sorted_wire, wire_schema, int(offsets[j]),
-                                  cnt)
-            yield j, _materialize_encoded_piece(piece, schema, enc)
+        return [
+            (j, _materialize_encoded_piece(
+                _slice_padded(sorted_wire, wire_schema, int(offsets[j]),
+                              int(counts[j])), schema, enc))
+            for j in range(n) if counts[j]]
 
     def _fused_pids_split(self, ctx, part, db: DeviceBatch, offset: int,
                           n: int, interpret: bool):
@@ -1162,8 +1218,9 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
     def _kernel_split(self, ctx, part, db: DeviceBatch, offset: int, n: int):
         """The fused-kernel split: compute pids (same hash/round-robin math
         as the sort path), run pack+kernel, consolidate each partition into
-        one DeviceBatch. Returns None when the fast path does not apply —
-        the caller falls back to the sort-based reorder."""
+        one DeviceBatch. Returns (kernel runs thrown away for a window
+        overflow, [(pid, piece), ...]), or None when the fast path does not
+        apply — the caller falls back to the sort-based reorder."""
         from spark_rapids_tpu import config as _cfg
         from spark_rapids_tpu.shuffle import partition_kernel as pk
         mode = ctx.conf.get(_cfg.SHUFFLE_KERNEL_MODE)
@@ -1201,13 +1258,13 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         if ctx.conf.get(_cfg.SHUFFLE_DMA_CONSOLIDATE):
             subs = pk.consolidate_all(out, stats, spec, schema, geom)
             if subs is not None:
-                return [(j, sub) for j, sub in enumerate(subs)
-                        if sub is not None]
+                return geom.widen, [(j, sub) for j, sub in enumerate(subs)
+                                    if sub is not None]
         for j in range(n):
             sub = pk.consolidate(out, stats, j, spec, schema, geom)
             if sub is not None:
                 pieces.append((j, sub))
-        return pieces
+        return geom.widen, pieces
 
     def _device_bounds(self, ctx, part: RangePartitioning,
                        staged, n: int) -> Optional[List[ColV]]:

@@ -71,13 +71,7 @@ class MeshBatch:
     def row_bytes(self) -> int:
         """One row's bytes over every column, validity byte and string
         length included: what moving a row between shards must carry."""
-        total = 0
-        for c in self.columns:
-            width = int(np.prod(c.data.shape[1:])) if c.data.ndim > 1 else 1
-            total += c.data.dtype.itemsize * width + 1
-            if c.lengths is not None:
-                total += 4
-        return total
+        return sum(c.row_bytes for c in self.columns)
 
     def rows_dev(self):
         """rows_per_shard as a device array sharded one-per-shard (the shape

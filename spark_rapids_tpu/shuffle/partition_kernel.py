@@ -36,7 +36,7 @@ fast path applying.
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -237,6 +237,10 @@ class KernelGeom:
     q_w: int          # per-window per-partition segment bound
     quota: int        # per-(group, partition) piece rows
     L: int
+    #: doublings of the per-window bound this plan was asked for: the kernel
+    #: runs `split_widening` threw away before it. Not part of the geometry
+    #: (two plans that clamp to the same bound are one program)
+    widen: int = field(default=0, compare=False)
 
     @staticmethod
     def plan(rows: int, n: int, L: int, widen: int = 0) -> "KernelGeom":
@@ -254,7 +258,7 @@ class KernelGeom:
         quota = max(seg + 32,
                     math.ceil(1.25 * gw / n) + q_w - base)
         quota = (quota + 511) // 512 * 512
-        return KernelGeom(cap, groups, G, n, q_w, quota, L)
+        return KernelGeom(cap, groups, G, n, q_w, quota, L, widen)
 
 
 def padded_lanes(L: int) -> int:
@@ -476,8 +480,11 @@ def reorder_program(spec: PackSpec, geom: KernelGeom, cap: int,
 def split_widening(batch: DeviceBatch, n: int, interpret: bool, run):
     """Drive one batch through the reorder, widening the per-window bound
     while only that overflows. ``run(spec, geom) -> (out, summary)`` runs
-    the pack+kernel program for a geometry. Returns (out, stats_host, spec,
-    geom), or None when the fan-out, the schema, the kernel's VMEM
+    the pack+kernel program for a geometry. Every batch starts at the
+    narrowest bound: a run that overflows a window is thrown away and the
+    next runs at twice the bound (``geom.widen`` of the result counts them,
+    the ``exchange.split`` span's ``widenings``). Returns (out, stats_host,
+    spec, geom), or None when the fan-out, the schema, the kernel's VMEM
     footprint (compiled for the chip), an inexact f64 expansion or a quota
     overflow puts the batch outside the fast path (caller falls back to
     the sort path). Shared by the standalone entry below and the engine's

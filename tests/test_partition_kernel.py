@@ -225,11 +225,13 @@ def test_fused_program_shared_across_round_robin_offsets():
     r2 = ex._kernel_split(ctx, ex.partitioning, batch, 3, 4)
     assert len(fused_keys()) == n_after_first, \
         "new offset recompiled the fused exchange program"
-    assert sum(b.num_rows for _, b in r1) == batch.num_rows
-    assert sum(b.num_rows for _, b in r2) == batch.num_rows
+    (w1, p1), (w2, p2) = r1, r2
+    assert w1 == w2 == 0, "uniform round-robin pids overflowed a window"
+    assert sum(b.num_rows for _, b in p1) == batch.num_rows
+    assert sum(b.num_rows for _, b in p2) == batch.num_rows
     # offset shifts rows between partitions but preserves the multiset
-    all1 = sorted(sum((_rows_key(b.to_arrow()) for _, b in r1), []), key=repr)
-    all2 = sorted(sum((_rows_key(b.to_arrow()) for _, b in r2), []), key=repr)
+    all1 = sorted(sum((_rows_key(b.to_arrow()) for _, b in p1), []), key=repr)
+    all2 = sorted(sum((_rows_key(b.to_arrow()) for _, b in p2), []), key=repr)
     assert all1 == all2
 
 
