@@ -128,6 +128,8 @@ def exchange_leg(tables, cpu):
     compaction kernel. Every row of every column must come back bit for bit,
     the same number per partition as from the CPU engine, and the compiled
     kernel — not the sort, not the interpreter — must have split the batch.
+    The exchange's key sketch (64 masked minima in the chip's own uint32
+    arithmetic) must give the distinct-count estimate numpy's gives.
 
     The check is on whole rows because an aggregate to check it by would cost
     minutes: a cold 1.5M-group `agg` program compiles for 2-4 minutes on the
@@ -136,7 +138,9 @@ def exchange_leg(tables, cpu):
     import pyarrow as pa
     from spark_rapids_tpu.api import TpuSession
     from spark_rapids_tpu.api.dataframe import _iter_execs
+    from spark_rapids_tpu.columnar.dtypes import DType
     from spark_rapids_tpu.execs import exchange_execs as xe
+    from spark_rapids_tpu.exprs.core import ColV
     from spark_rapids_tpu.shuffle import partition_kernel as pk
 
     def run(sess):
@@ -159,6 +163,13 @@ def exchange_leg(tables, cpu):
     reference, _ = run(cpu)
     check(reference.num_rows == tables["lineitem"].num_rows,
           "exchange: the reference lost rows")
+    orderkeys = tables["lineitem"].column("l_orderkey").to_numpy()
+    with np.errstate(over="ignore"):
+        hashes = xe._column_hash(np, ColV(
+            DType.LONG, orderkeys, np.ones(len(orderkeys), dtype=bool)))
+    host_ndv = xe._kmv_estimate(
+        xe._kmv_merge(np.zeros(0, dtype=np.uint32), hashes))
+    true_ndv = len(np.unique(orderkeys))
     walls, expected, expected_sizes = {}, None, None
     dma = "spark.rapids.tpu.shuffle.kernel.dmaConsolidate.enabled"
     for name, extra in (("gather", {}), ("dma", {dma: "true"})):
@@ -169,11 +180,16 @@ def exchange_leg(tables, cpu):
         device_only_plan(sess, f"exchange/{name}")
         by_kernel = exchange.metrics[xe.KERNEL_SPLIT_BATCHES].value
         by_sort = exchange.metrics[xe.SORT_SPLIT_BATCHES].value
-        per_partition = exchange.stage_stats().partition_rows
+        stats = exchange.stage_stats()
+        per_partition = stats.partition_rows
         print(f"exchange/{name}: rows={got.num_rows} "
               f"per_partition={per_partition} kernel_batches={by_kernel} "
-              f"sort_batches={by_sort} smoke_wall_s={walls[name]}",
-              flush=True)
+              f"sort_batches={by_sort} ndv={stats.key_distinct} "
+              f"smoke_wall_s={walls[name]}", flush=True)
+        check(stats.key_distinct == (host_ndv,)
+              and abs(host_ndv - true_ndv) <= 0.4 * true_ndv,
+              f"exchange/{name}: key_distinct {stats.key_distinct}, numpy's "
+              f"sketch of the same keys {host_ndv}, distinct keys {true_ndv}")
         check(by_kernel >= 1 and by_sort == 0,
               f"exchange/{name}: kernel split {by_kernel} batch(es), "
               f"the sort {by_sort}")

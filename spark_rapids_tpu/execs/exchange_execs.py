@@ -23,6 +23,7 @@ the partitioning math itself.
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import threading
 import time
@@ -446,6 +447,33 @@ def _kmv_merge(pool: "np.ndarray", hashes: "np.ndarray") -> "np.ndarray":
     return np.unique(np.concatenate([pool, np.unique(hashes)]))[:_KMV_K]
 
 
+def _kmv_pool_device(h, live, k: int):
+    """(pool, n) of one device batch: the ``k`` smallest DISTINCT values of
+    the uint32 vector ``h`` under the mask ``live``, ascending, and how many
+    were found. ``k`` rounds of a masked minimum over the loop-invariant
+    vector (round i: the least hash above round i-1's), each ONE
+    memory-bound reduce: no sort, no scatter, no gather over the rows. A
+    round that finds nothing repeats the one before; the host merges
+    ``pool[:n]``."""
+    top = np.uint32(0xFFFFFFFF)
+    # a round's minimum is `top` when nothing is left AND when a live hash
+    # of 0xFFFFFFFF is all that is left: one pass, once, tells the two apart
+    has_top = jnp.any(live & (h == top))
+    h = jnp.where(live, h, top)
+
+    def step(carry, _):
+        prev, n = carry
+        first = n == 0
+        least = jnp.min(jnp.where(first | (h > prev), h, top))
+        found = (least != top) | (has_top & (first | (prev != top)))
+        pick = jnp.where(found, least, prev)
+        return (pick, n + found.astype(np.int32)), pick
+
+    (_, n), pool = jax.lax.scan(step, (np.uint32(0), np.int32(0)), None,
+                                length=k)
+    return pool, n
+
+
 def _key_hashes(xp, keys, ectx: EvalCtx) -> List:
     """Per-row uint32 hash of each key expression, nulls hashed alike."""
     out = []
@@ -529,6 +557,10 @@ class ShuffleExchangeExecBase(PhysicalExec):
         #: KMV pool per hash-partitioning key column (sorted uint32
         #: ndarrays), folded at map time; None until the map side ran
         self._key_sketches: Optional[List["np.ndarray"]] = None
+        #: the device engine's sketches not folded yet: per map-side batch
+        #: one (pool, found) pair of device arrays per key column, read
+        #: when the statistics are (`stage_stats`), never waited for alone
+        self._pending_sketches: List[Tuple] = []
 
     def __getstate__(self):
         # cluster tasks receive pickled exchanges; map state is per-process
@@ -538,6 +570,7 @@ class ShuffleExchangeExecBase(PhysicalExec):
         state["_part_rows"] = {}
         state["_map_part_rows"] = {}
         state["_key_sketches"] = None
+        state["_pending_sketches"] = []
         return state
 
     def __setstate__(self, state):
@@ -588,7 +621,7 @@ class ShuffleExchangeExecBase(PhysicalExec):
         width = _row_width(self.output)
         rows = tuple(self._part_rows.get(p, 0)
                      for p in range(self.num_partitions))
-        ndv = tuple(_kmv_estimate(pool) for pool in (self._key_sketches or ()))
+        ndv = tuple(_kmv_estimate(pool) for pool in self._folded_sketches())
         return StageStats(rows, tuple(r * width for r in rows), ndv)
 
     def _sketch_keys(self, ectx: EvalCtx, num_rows: int) -> None:
@@ -600,12 +633,12 @@ class ShuffleExchangeExecBase(PhysicalExec):
         self._merge_sketches([ch[:num_rows]
                               for ch in _key_hashes(np, part.keys, ectx)])
 
-    def _sketch_keys_device(self, ctx: ExecContext, db: DeviceBatch) -> None:
-        """`_sketch_keys` for a device batch: ONE cached program per (keys,
+    def _sketch_program(self, ctx: ExecContext, db: DeviceBatch):
+        """The device sketch of one batch: ONE cached program per (keys,
         schema, capacity) — the row count rides as a runtime argument, so
-        pieces of different sizes share it — hashes each key column and
-        top-k sorts it. Only the k smallest DISTINCT hash VALUES download
-        (bounded, _KMV_K uint32s per column per batch), never key data."""
+        batches of different sizes share it — hashes each key column once
+        and takes the k smallest DISTINCT hash VALUES by k masked minima
+        (`_kmv_pool_device`): a (pool, found) pair per key column."""
         part = self.partitioning
         schema, cap, smax = db.schema, db.capacity, ctx.string_max_bytes
         key = ("exchange-sketch", part.keys, schema, cap, smax)
@@ -614,19 +647,32 @@ class ShuffleExchangeExecBase(PhysicalExec):
             def fn(num_rows, *flat):
                 ectx = EvalCtx(jnp, _unflatten_colvs(schema, flat), cap, smax)
                 live = jnp.arange(cap, dtype=np.int32) < num_rows
-                out = []
-                for ch in _key_hashes(jnp, keys, ectx):
-                    # dead rows repeat row 0 (live: num_rows > 0); unique
-                    # sorts, then truncates to k; the host merge collapses
-                    # the repeats
-                    ch = jnp.where(live, jnp.broadcast_to(ch, (cap,)), ch[0])
-                    out.append(jnp.unique(ch, size=min(_KMV_K, cap),
-                                          fill_value=ch[0]))
-                return tuple(out)
+                return tuple(
+                    _kmv_pool_device(jnp.broadcast_to(ch, (cap,)), live,
+                                     min(_KMV_K, cap))
+                    for ch in _key_hashes(jnp, keys, ectx))
             return fn
 
-        self._merge_sketches(
-            _cached_jit(key, build)(np.int32(db.num_rows), *_flatten(db)))
+        return _cached_jit(key, build)
+
+    def _sketch_keys_device(self, ctx: ExecContext, db: DeviceBatch) -> None:
+        """`_sketch_keys` for a device batch, dispatched and not read: the
+        pools stay on the device in `_pending_sketches` (bounded: _KMV_K
+        uint32s per column per batch, never key data) until `stage_stats`
+        folds them, so the host never waits for a sketch alone."""
+        self._pending_sketches.append(self._sketch_program(ctx, db)(
+            np.int32(db.num_rows), *_flatten(db)))
+
+    def _folded_sketches(self) -> List["np.ndarray"]:
+        """The KMV pools, the pending device pools read (one transfer for
+        all of them) and folded in first. A copy of the exec shares the
+        pending list and may fold it again: the merge is idempotent."""
+        with self._lock:
+            pending, self._pending_sketches = self._pending_sketches, []
+            fetched = jax.device_get(pending)
+            for pools in fetched:
+                self._merge_sketches([pool[:n] for pool, n in pools])
+            return list(self._key_sketches or ())
 
     def _merge_sketches(self, hashes) -> None:
         if self._key_sketches is None:
@@ -765,6 +811,7 @@ class CpuShuffleExchangeExec(ShuffleExchangeExecBase):
         self._part_rows = {}
         self._map_part_rows = {}
         self._key_sketches = None
+        self._pending_sketches = []
         self._map_done = False
 
 
@@ -875,14 +922,18 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
                      "rows": rows})
 
     # ---- map side ------------------------------------------------------------
-    def iter_map_pieces(self, ctx: ExecContext,
-                        partition_ids=None) -> Iterator[Tuple[int, int, DeviceBatch]]:
+    def iter_map_pieces(self, ctx: ExecContext, partition_ids=None,
+                        sketch=None) -> Iterator[Tuple[int, int, DeviceBatch]]:
         """(source_partition, reduce_pid, sub_batch) triples — THE map-side
         partition protocol, shared by the single-process engine and cluster
         map tasks. Range partitioning stages the requested partitions and
         samples bounds first (the SamplingUtils pass); everything else
         splits each batch as it is produced, so peak footprint is one batch
-        plus the spillable shuffle cache."""
+        plus the spillable shuffle cache. ``sketch`` (the local engine's,
+        under hash partitioning; a cluster map task passes none) is called
+        with each non-empty batch once its split has been dispatched and
+        its ``exchange.split`` span has closed: the pools of a batch's
+        pieces merge to the batch's own, so one sketch per batch does."""
         part = self.partitioning
         n = part.num_partitions
         child = self.children[0]
@@ -909,8 +960,10 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
                 if db.num_rows == 0:
                     continue
                 offset = _round_robin_offset(part, cctx.partition_id, bi)
-                for j, sub in self._split_batch(ctx, part, db, offset, n,
-                                                None):
+                pieces = self._split_batch(ctx, part, db, offset, n, None)
+                if sketch is not None:
+                    sketch(db)
+                for j, sub in pieces:
                     yield cctx.partition_id, j, sub
 
     def _run_map(self, ctx: ExecContext) -> None:
@@ -926,7 +979,8 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
             ctx.cleanups.append(
                 lambda: env.shuffle_catalog.remove_shuffle(sid))
         part = self.partitioning
-        sketch = isinstance(part, HashPartitioning)
+        sketch = (functools.partial(self._sketch_keys_device, ctx)
+                  if isinstance(part, HashPartitioning) else None)
         # one span per run of the map side; its args come from what the host
         # holds anyway (piece row counts, array shapes, the exec's metrics)
         with _tracing.span("exchange.map", _tracing.LAYER_SHUFFLE) as span:
@@ -934,14 +988,13 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
                 split0 = (self.metrics[KERNEL_SPLIT_BATCHES].value,
                           self.metrics[SORT_SPLIT_BATCHES].value)
             pieces = rows = nbytes = 0
-            for map_p, j, sub in self.iter_map_pieces(ctx):
+            sketches0 = len(self._pending_sketches)
+            for map_p, j, sub in self.iter_map_pieces(ctx, sketch=sketch):
                 if span is not None:
                     pieces += 1
                     rows += sub.num_rows
                     nbytes += sub.num_rows * sum(
                         c.row_bytes for c in sub.columns)
-                if sketch and sub.num_rows > 0:
-                    self._sketch_keys_device(ctx, sub)
                 sub = uniform_string_batch(sub)
                 layout = DevicePackLayout.for_batch_shape(
                     sub.schema, sub.capacity, batch_string_max(sub))
@@ -956,6 +1009,7 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
                     partitioning=part.kind,
                     partitions=part.num_partitions, rows=rows, bytes=nbytes,
                     pieces=pieces,
+                    sketches=len(self._pending_sketches) - sketches0,
                     kernel_batches=(self.metrics[KERNEL_SPLIT_BATCHES].value
                                     - split0[0]),
                     sort_batches=(self.metrics[SORT_SPLIT_BATCHES].value
@@ -967,14 +1021,14 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         ``exchange.split`` span runs from the split program's call to the
         host's read of its counts (a read the slicing needs anyway) and the
         dispatch of each piece's consolidation; the pieces are handed on
-        after it closes."""
+        (and the batch sketched) after it closes."""
         with _tracing.span("exchange.split", _tracing.LAYER_SHUFFLE) as span:
             path, widenings, pieces = self._split_pieces(ctx, part, db,
                                                          offset, n, bounds)
             if span is not None:
                 span.note(path=path, widenings=widenings, rows=db.num_rows,
                           cap=db.capacity)
-        yield from pieces
+        return pieces
 
     def _split_pieces(self, ctx, part, db: DeviceBatch, offset: int, n: int,
                       bounds) -> Tuple[str, int, List[Tuple[int, DeviceBatch]]]:
