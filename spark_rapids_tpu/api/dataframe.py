@@ -598,15 +598,13 @@ class DataFrame:
                         query.emit_batch(t)
                 return tables
             # plan not stageable (CPU exchanges): single-process fallback
-        dm = DeviceManager.initialize(self.session.conf)
-        cleanups: List = []
-        tables = []
         # spark.rapids.tpu.trace.enabled: structured span tracing for the
         # whole action (utils/tracing.py — per-exec spans, transfer/memory/
         # serving layers, EXPLAIN ANALYZE and the Chrome export) plus the
         # action-level jax.profiler range (NVTX analog); when metrics are
         # on, per-operator counters land in session.last_metrics
         import contextlib
+        import time as _time
         from spark_rapids_tpu.utils import tracing as _tracing
         from spark_rapids_tpu.utils.metrics import (action_depth_scope,
                                                     adaptive_delta,
@@ -619,31 +617,37 @@ class DataFrame:
                                                     serving_snapshot,
                                                     transfer_delta,
                                                     transfer_snapshot)
-        trace = self.session.conf.get(_cfg.TRACE_ENABLED)
-        trace_scope = self._trace_scope()
-        transfer_before = transfer_snapshot()
-        memory_before = memory_snapshot()
-        serving_before = serving_snapshot()
-        recompute_before = recompute_snapshot()
-        adaptive_before = adaptive_snapshot()
-        import time as _time
-        # stable node ordinals: the span/EXPLAIN-ANALYZE key (pre-order,
-        # matching the f"{i}:{name}" keys of session.last_metrics)
-        for i, nd in enumerate(_iter_execs(final)):
-            nd.plan_id = i
-        tenant = query.tenant if query is not None else "default"
-        cancel = query.check_cancelled if query is not None else None
-        # one stack for the action-scoped contexts (depth attribution +
-        # tracer activation): entered before the admission wait so the
-        # wait is traced, unwound in the finally below even when a
-        # cleanup fn raises — a stuck activation would leave the
-        # process-wide tracer on for every later query
-        scopes = contextlib.ExitStack()
-        depth_holder = scopes.enter_context(action_depth_scope())
-        scopes.enter_context(trace_scope)
-        trace_mark = _tracing.TRACER.mark()
-        t_wall = _time.perf_counter()
-        t_admit = _time.perf_counter()
+        # the query's own work outside the action is under query.* spans,
+        # the action's outside its children under action.*: their self time
+        # is what no span holds (docs/observability.md)
+        with _tracing.span("query.prepare", _tracing.LAYER_ACTION):
+            dm = DeviceManager.initialize(self.session.conf)
+            cleanups: List = []
+            tables = []
+            trace = self.session.conf.get(_cfg.TRACE_ENABLED)
+            trace_scope = self._trace_scope()
+            transfer_before = transfer_snapshot()
+            memory_before = memory_snapshot()
+            serving_before = serving_snapshot()
+            recompute_before = recompute_snapshot()
+            adaptive_before = adaptive_snapshot()
+            # stable node ordinals: the span/EXPLAIN-ANALYZE key (pre-order,
+            # matching the f"{i}:{name}" keys of session.last_metrics)
+            for i, nd in enumerate(_iter_execs(final)):
+                nd.plan_id = i
+            tenant = query.tenant if query is not None else "default"
+            cancel = query.check_cancelled if query is not None else None
+            # one stack for the action-scoped contexts (depth attribution +
+            # tracer activation): entered before the admission wait so the
+            # wait is traced, unwound in the finally below even when a
+            # cleanup fn raises — a stuck activation would leave the
+            # process-wide tracer on for every later query
+            scopes = contextlib.ExitStack()
+            depth_holder = scopes.enter_context(action_depth_scope())
+            scopes.enter_context(trace_scope)
+            trace_mark = _tracing.TRACER.mark()
+            t_wall = _time.perf_counter()
+            t_admit = _time.perf_counter()
         try:
             # the action span (profiler range tpu-sql-action) opens first,
             # so the admission wait is a child inside it; then the
@@ -716,7 +720,9 @@ class DataFrame:
                         for db in child.execute(ctx):
                             ctx.check_cancelled()
                             final.count_output(db.num_rows)
-                            pending.append(start_download(db))
+                            with _tracing.span("action.download_dispatch",
+                                               _tracing.LAYER_ACTION):
+                                pending.append(start_download(db))
                             while len(pending) > max_inflight:
                                 t = pending.pop(0).result()
                                 tables.append(t)
@@ -747,47 +753,52 @@ class DataFrame:
                                 query.emit_batch(t)
         finally:
             try:
-                for fn in cleanups:
-                    fn()
+                with _tracing.span("query.cleanup", _tracing.LAYER_ACTION):
+                    for fn in cleanups:
+                        fn()
             finally:
                 self.session.last_action_wall_s = (_time.perf_counter()
                                                    - t_wall)
                 scopes.close()
             if self.session.conf.get(_cfg.METRICS_ENABLED):
-                # build the whole snapshot FIRST, then publish with ONE
-                # attribute store: two interleaved actions used to mutate
-                # the shared dict after assignment, so a reader could see
-                # the other query's half-written metrics. The per-query
-                # handle is the first-class record; the session global
-                # stays as a last-action alias for compatibility.
-                snap = {f"{i}:{nd.name}": nd.metrics.snapshot()
-                        for i, nd in enumerate(_iter_execs(final))}
-                # host-link story for the whole action, incl. derived GB/s
-                # (process-global counters: under concurrent queries the
-                # per-action delta includes overlapping queries' traffic)
-                snap["transfer"] = transfer_delta(transfer_before)
-                # out-of-core story for the action: pressure events, grace
-                # partitions, recursion peak, bytes spilled per tier. The
-                # recursion peak is the ACTION-SCOPED maximum (thread/
-                # query-bound attribution, not the shared re-armed global
-                # whose concurrent-overlap misattribution PR 11 documented)
-                snap["memory"] = memory_delta(memory_before,
-                                              recursion_peak=(
-                                                  depth_holder.peak))
-                # serving story: wire bytes/batches streamed, preemptions,
-                # footprint-admission rejections over the action's window
-                snap["serving"] = serving_delta(serving_before)
-                # fault-recovery story for the action: lineage-scoped stage
-                # recomputes the cluster driver ran (and escalations to the
-                # failover path) while this action was collecting
-                snap["shuffle"] = recompute_delta(recompute_before)
-                # adaptive story: runtime rewrites this action's AQE pass
-                # applied (skew splits, coalesced partitions, broadcast
-                # switches, re-fused stages)
-                snap["adaptive"] = adaptive_delta(adaptive_before)
-                if query is not None:
-                    query.record_exec_metrics(snap)
-                self.session.last_metrics = snap
+                with _tracing.span("query.metrics",
+                                   _tracing.LAYER_ACTION) as sp:
+                    # build the whole snapshot FIRST, then publish with ONE
+                    # attribute store: two interleaved actions used to mutate
+                    # the shared dict after assignment, so a reader could see
+                    # the other query's half-written metrics. The per-query
+                    # handle is the first-class record; the session global
+                    # stays as a last-action alias for compatibility.
+                    snap = {f"{i}:{nd.name}": nd.metrics.snapshot()
+                            for i, nd in enumerate(_iter_execs(final))}
+                    if sp is not None:
+                        sp.note(execs=len(snap))
+                    # host-link story for the whole action, incl. derived GB/s
+                    # (process-global counters: under concurrent queries the
+                    # per-action delta includes overlapping queries' traffic)
+                    snap["transfer"] = transfer_delta(transfer_before)
+                    # out-of-core story for the action: pressure events, grace
+                    # partitions, recursion peak, bytes spilled per tier. The
+                    # recursion peak is the ACTION-SCOPED maximum (thread/
+                    # query-bound attribution, not the shared re-armed global
+                    # whose concurrent-overlap misattribution PR 11 documented)
+                    snap["memory"] = memory_delta(memory_before,
+                                                  recursion_peak=(
+                                                      depth_holder.peak))
+                    # serving story: wire bytes/batches streamed, preemptions,
+                    # footprint-admission rejections over the action's window
+                    snap["serving"] = serving_delta(serving_before)
+                    # fault-recovery story for the action: lineage-scoped stage
+                    # recomputes the cluster driver ran (and escalations to the
+                    # failover path) while this action was collecting
+                    snap["shuffle"] = recompute_delta(recompute_before)
+                    # adaptive story: runtime rewrites this action's AQE pass
+                    # applied (skew splits, coalesced partitions, broadcast
+                    # switches, re-fused stages)
+                    snap["adaptive"] = adaptive_delta(adaptive_before)
+                    if query is not None:
+                        query.record_exec_metrics(snap)
+                    self.session.last_metrics = snap
             if trace and publish_trace:
                 self._publish_trace(trace_mark)
         return tables
@@ -832,7 +843,8 @@ class DataFrame:
                     final = self._executed_plan()
                 tables = self._run_partitions(final, query=query,
                                               publish_trace=False)
-                schema = self._plan.schema().to_pa()
+                with _tracing.span("query.schema", _tracing.LAYER_ACTION):
+                    schema = self._plan.schema().to_pa()
                 if not tables:
                     return schema.empty_table()
                 with _tracing.span("result.concat",

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from spark_rapids_tpu.columnar.column import DeviceColumn, null_column
 from spark_rapids_tpu.columnar.dtypes import DType, Field, Schema, bucket_capacity
 from spark_rapids_tpu.utils import metrics as um
+from spark_rapids_tpu.utils import tracing as _tracing
 
 DEFAULT_STRING_MAX_BYTES = 256
 
@@ -86,183 +87,201 @@ class DeviceBatch:
         (Host-side re-encoding of plain columns was tried and cut: on the
         1-core bench rig np.unique staging cost exceeds the link saving.)"""
         from spark_rapids_tpu.columnar import encoding as ce
-        table = table.combine_chunks()
-        schema = Schema.from_pa(table.schema)
-        n = table.num_rows
-        cap = bucket_capacity(n, bucketed)
-        # stage every column on host at its EXACT row count, then ship ONE
-        # device_put tree (per-buffer transfers each pay a fixed host-link
-        # round trip). Capacity padding and the validity masks of null-free
-        # columns are built on device — no reason to move zeros over the link.
-        staged = []
-        encoded = {}     # column index -> "string" | "fixed" | "ree"
-        enc_meta = {}    # column index -> (token, unique) for dict columns
-        enc_bytes = 0    # bytes actually staged for the link
-        dec_bytes = 0    # bytes the decoded forms would have staged
+        # three leaf spans under upload.stage: the host conversion, the
+        # put, and the eager device-side decode (dispatches, not awaited)
+        with _tracing.span("stage.host", _tracing.LAYER_TRANSFER) as sp:
+            table = table.combine_chunks()
+            schema = Schema.from_pa(table.schema)
+            n = table.num_rows
+            cap = bucket_capacity(n, bucketed)
+            # stage every column on host at its EXACT row count, then ship ONE
+            # device_put tree (per-buffer transfers each pay a fixed host-link
+            # round trip). Capacity padding and the validity masks of null-free
+            # columns are built on device — no reason to move zeros over the link.
+            staged = []
+            encoded = {}     # column index -> "string" | "fixed" | "ree"
+            enc_meta = {}    # column index -> (token, unique) for dict columns
+            enc_bytes = 0    # bytes actually staged for the link
+            dec_bytes = 0    # bytes the decoded forms would have staged
 
-        def _nb(*arrs) -> int:
-            return sum(a.nbytes for a in arrs if a is not None)
+            def _nb(*arrs) -> int:
+                return sum(a.nbytes for a in arrs if a is not None)
 
-        for i, f in enumerate(schema):
-            arr = table.column(i).combine_chunks()
-            if isinstance(arr, pa.ChunkedArray):
-                arr = (arr.chunk(0) if arr.num_chunks == 1
-                       else pa.concat_arrays(arr.chunks))
-            if (isinstance(arr, pa.Array)
-                    and pa.types.is_run_end_encoded(arr.type)):
-                ends, vals = ce.ree_staged(arr)
-                if len(ends) == 0 or f.dtype is DType.STRING:
-                    # empty slice / string REE (never produced by the scan):
-                    # host-decode and take the plain path below
-                    arr = ce.ree_to_plain(arr)
-                else:
-                    rvalid = (None if vals.null_count == 0
-                              else _arrow_validity(vals))
-                    vd, _, _ = _arrow_to_staged(f.dtype, vals,
-                                                string_max_bytes)
-                    vbits = (vd.view(np.uint64)
-                             if f.dtype is DType.DOUBLE and with_bits
-                             else None)
-                    encoded[i] = "ree"
-                    staged.append((ends, rvalid, vd, vbits))
-                    enc_bytes += _nb(ends, rvalid, vd, vbits)
-                    dec_bytes += (n * vd.dtype.itemsize
-                                  + (n * 8 if vbits is not None else 0)
-                                  + _nb(rvalid))
+            for i, f in enumerate(schema):
+                arr = table.column(i).combine_chunks()
+                if isinstance(arr, pa.ChunkedArray):
+                    arr = (arr.chunk(0) if arr.num_chunks == 1
+                           else pa.concat_arrays(arr.chunks))
+                if (isinstance(arr, pa.Array)
+                        and pa.types.is_run_end_encoded(arr.type)):
+                    ends, vals = ce.ree_staged(arr)
+                    if len(ends) == 0 or f.dtype is DType.STRING:
+                        # empty slice / string REE (never produced by the scan):
+                        # host-decode and take the plain path below
+                        arr = ce.ree_to_plain(arr)
+                    else:
+                        rvalid = (None if vals.null_count == 0
+                                  else _arrow_validity(vals))
+                        vd, _, _ = _arrow_to_staged(f.dtype, vals,
+                                                    string_max_bytes)
+                        vbits = (vd.view(np.uint64)
+                                 if f.dtype is DType.DOUBLE and with_bits
+                                 else None)
+                        encoded[i] = "ree"
+                        staged.append((ends, rvalid, vd, vbits))
+                        enc_bytes += _nb(ends, rvalid, vd, vbits)
+                        dec_bytes += (n * vd.dtype.itemsize
+                                      + (n * 8 if vbits is not None else 0)
+                                      + _nb(rvalid))
+                        continue
+                if (isinstance(arr, pa.DictionaryArray)
+                        and len(arr.dictionary) > 0):
+                    # device-side decode (GpuParquetScan.scala:576 analog for
+                    # the dictionary encoding): ship the narrow index vector +
+                    # the small dictionary, gather on device — 2-8x fewer
+                    # bytes over the host link than the decoded column.
+                    # Strings gather their byte-matrix rows + lengths.
+                    idx = arr.indices
+                    validity = (None if idx.null_count == 0
+                                else _arrow_validity(idx))
+                    k = len(arr.dictionary)
+                    np_idx = np.asarray(idx.fill_null(0)).astype(
+                        np.uint8 if k <= 0xFF else
+                        np.uint16 if k <= 0xFFFF else np.int32)
+                    if f.dtype is DType.STRING:
+                        dmat, dlen = _strings_to_matrix(
+                            arr.dictionary.cast(pa.string()), string_max_bytes)
+                        encoded[i] = "string"
+                        staged.append((np_idx, validity, dmat, dlen))
+                        enc_bytes += _nb(np_idx, validity, dmat, dlen)
+                        dec_bytes += (n * dmat.shape[1] + n * 4 + _nb(validity))
+                        unique = ce.dictionary_is_unique(dmat, dlen)
+                    else:
+                        dd, _, _ = _arrow_to_staged(f.dtype, arr.dictionary,
+                                                    string_max_bytes)
+                        dbits = (dd.view(np.uint64)
+                                 if f.dtype is DType.DOUBLE and with_bits
+                                 else None)
+                        encoded[i] = "fixed"
+                        staged.append((np_idx, validity, dd, dbits))
+                        enc_bytes += _nb(np_idx, validity, dd, dbits)
+                        dec_bytes += (n * dd.dtype.itemsize
+                                      + (n * 8 if dbits is not None else 0)
+                                      + _nb(validity))
+                        unique = ce.dictionary_is_unique(dd)
+                    enc_meta[i] = (ce.field_token(table.schema, i), unique)
                     continue
-            if (isinstance(arr, pa.DictionaryArray)
-                    and len(arr.dictionary) > 0):
-                # device-side decode (GpuParquetScan.scala:576 analog for
-                # the dictionary encoding): ship the narrow index vector +
-                # the small dictionary, gather on device — 2-8x fewer
-                # bytes over the host link than the decoded column.
-                # Strings gather their byte-matrix rows + lengths.
-                idx = arr.indices
-                validity = (None if idx.null_count == 0
-                            else _arrow_validity(idx))
-                k = len(arr.dictionary)
-                np_idx = np.asarray(idx.fill_null(0)).astype(
-                    np.uint8 if k <= 0xFF else
-                    np.uint16 if k <= 0xFFFF else np.int32)
-                if f.dtype is DType.STRING:
-                    dmat, dlen = _strings_to_matrix(
-                        arr.dictionary.cast(pa.string()), string_max_bytes)
-                    encoded[i] = "string"
-                    staged.append((np_idx, validity, dmat, dlen))
-                    enc_bytes += _nb(np_idx, validity, dmat, dlen)
-                    dec_bytes += (n * dmat.shape[1] + n * 4 + _nb(validity))
-                    unique = ce.dictionary_is_unique(dmat, dlen)
-                else:
-                    dd, _, _ = _arrow_to_staged(f.dtype, arr.dictionary,
-                                                string_max_bytes)
-                    dbits = (dd.view(np.uint64)
-                             if f.dtype is DType.DOUBLE and with_bits
-                             else None)
-                    encoded[i] = "fixed"
-                    staged.append((np_idx, validity, dd, dbits))
-                    enc_bytes += _nb(np_idx, validity, dd, dbits)
-                    dec_bytes += (n * dd.dtype.itemsize
-                                  + (n * 8 if dbits is not None else 0)
-                                  + _nb(validity))
-                    unique = ce.dictionary_is_unique(dd)
-                enc_meta[i] = (ce.field_token(table.schema, i), unique)
-                continue
-            if isinstance(arr, pa.DictionaryArray):
-                arr = arr.cast(arr.type.value_type)   # empty dict
-            d, v, l = _arrow_to_staged(f.dtype, arr, string_max_bytes)
-            # DOUBLE columns also ship their IEEE bit pattern: device f64
-            # STORAGE is true 64-bit but no device op can extract its bits
-            # (f64->u64 bitcast does not lower; arithmetic is ~49-bit), so
-            # the shuffle kernel's byte packing needs the host-made sibling.
-            # with_bits=False skips it for consumers that never reach that
-            # kernel (mesh-sharded scans: exchange is an all_to_all)
-            bits = (d.view(np.uint64)
-                    if f.dtype is DType.DOUBLE and with_bits else None)
-            staged.append((d, v, l, bits))
-            plain = _nb(d, v, l, bits)
-            enc_bytes += plain
-            dec_bytes += plain
+                if isinstance(arr, pa.DictionaryArray):
+                    arr = arr.cast(arr.type.value_type)   # empty dict
+                d, v, l = _arrow_to_staged(f.dtype, arr, string_max_bytes)
+                # DOUBLE columns also ship their IEEE bit pattern: device f64
+                # STORAGE is true 64-bit but no device op can extract its bits
+                # (f64->u64 bitcast does not lower; arithmetic is ~49-bit), so
+                # the shuffle kernel's byte packing needs the host-made sibling.
+                # with_bits=False skips it for consumers that never reach that
+                # kernel (mesh-sharded scans: exchange is an all_to_all)
+                bits = (d.view(np.uint64)
+                        if f.dtype is DType.DOUBLE and with_bits else None)
+                staged.append((d, v, l, bits))
+                plain = _nb(d, v, l, bits)
+                enc_bytes += plain
+                dec_bytes += plain
+            if sp is not None:
+                sp.note(bytes=enc_bytes)
         m = um.TRANSFER_METRICS
         m[um.TRANSFER_ENCODED_BYTES].add(enc_bytes)
         m[um.TRANSFER_DECODED_EQUIV_BYTES].add(dec_bytes)
-        up = (jax.device_put(staged, device) if device is not None
-              else jax.device_put(staged))
-        # shared all-valid mask, on the same device as the data
-        alive = jnp.arange(cap, dtype=jnp.int32) < n
-        if device is not None:
-            alive = jax.device_put(alive, device)
-        pad = cap - n
-        cols = []
-        for i, (f, slot) in enumerate(zip(schema, up)):
-            enc = None
-            if encoded.get(i) == "ree":
-                # HBM expansion of the RLE runs: searchsorted over the run
-                # ends picks each row's run, one gather per buffer. The
-                # decoded column exists ONLY on device.
-                ends, rv, vd, vbits = slot
-                d, ridx = ce.expand_ree_device(jnp, ends, vd, cap)
-                bits = (jnp.take(vbits, ridx, axis=0)
-                        if vbits is not None else None)
-                l = None
-                v = (jnp.logical_and(jnp.take(rv, ridx, axis=0), alive)
-                     if rv is not None else None)
-            elif i in encoded:
-                # padded gather: index padding rows point at dict slot 0;
-                # their garbage values land beyond the live prefix
-                idx, v, dd, extra = slot
-                idx32 = idx.astype(jnp.int32)
-                if pad:
-                    idx32 = jnp.concatenate(
-                        [idx32, jnp.zeros(pad, jnp.int32)], axis=0)
-                d = jnp.take(dd, idx32, axis=0)
-                if encoded[i] == "string":
-                    l = jnp.take(extra, idx32, axis=0)
-                    bits = None
-                    enc_lengths = extra
-                else:
-                    bits = (jnp.take(extra, idx32, axis=0)
-                            if extra is not None else None)
+        with _tracing.span("stage.put", _tracing.LAYER_TRANSFER):
+            up = (jax.device_put(staged, device) if device is not None
+                  else jax.device_put(staged))
+        with _tracing.span("stage.expand", _tracing.LAYER_TRANSFER) as sp:
+            # shared all-valid mask, on the same device as the data
+            alive = jnp.arange(cap, dtype=jnp.int32) < n
+            if device is not None:
+                alive = jax.device_put(alive, device)
+            nd = 2      # eager device calls issued below, counted as issued
+            pad = cap - n
+            cols = []
+            for i, (f, slot) in enumerate(zip(schema, up)):
+                enc = None
+                if encoded.get(i) == "ree":
+                    # HBM expansion of the RLE runs: searchsorted over the run
+                    # ends picks each row's run, one gather per buffer. The
+                    # decoded column exists ONLY on device.
+                    ends, rv, vd, vbits = slot
+                    d, ridx = ce.expand_ree_device(jnp, ends, vd, cap)
+                    bits = (jnp.take(vbits, ridx, axis=0)
+                            if vbits is not None else None)
                     l = None
-                    enc_lengths = None
-                token, unique = enc_meta[i]
-                if unique:
-                    # the retained encoding pads its dictionary to a
-                    # power-of-two bucket ON DEVICE (zero link bytes): the
-                    # padded size is the jit-key shape, so per-row-group
-                    # dictionary growth doesn't recompile encoded-domain
-                    # programs
-                    k_real = int(dd.shape[0])
-                    dpad = ce.dict_bucket(k_real) - k_real
-                    dd_enc, len_enc = dd, enc_lengths
-                    if dpad:
-                        dd_enc = jnp.concatenate(
-                            [dd, jnp.zeros((dpad,) + dd.shape[1:],
-                                           dd.dtype)], axis=0)
-                        if enc_lengths is not None:
-                            len_enc = jnp.concatenate(
-                                [enc_lengths,
-                                 jnp.zeros(dpad, enc_lengths.dtype)], axis=0)
-                    enc = ce.DictEncoding(idx32, dd_enc, k_real, len_enc,
-                                          token)
-            else:
-                d, v, l, bits = slot
-                if pad:
-                    d = jnp.concatenate(
-                        [d, jnp.zeros((pad,) + d.shape[1:], d.dtype)],
-                        axis=0)
-                    if l is not None:
-                        l = jnp.concatenate([l, jnp.zeros(pad, l.dtype)],
-                                            axis=0)
-                    if bits is not None:
-                        bits = jnp.concatenate(
-                            [bits, jnp.zeros(pad, bits.dtype)], axis=0)
-            if v is not None:
-                validity = (jnp.concatenate([v, jnp.zeros(pad, jnp.bool_)])
-                            if pad and v.shape[0] != cap else v)
-            else:
-                validity = alive
-            cols.append(DeviceColumn(f.dtype, d, validity, l, bits,
-                                     encoding=enc))
+                    v = (jnp.logical_and(jnp.take(rv, ridx, axis=0), alive)
+                         if rv is not None else None)
+                    nd += (5 + (vbits is not None)
+                           + 2 * (rv is not None))
+                elif i in encoded:
+                    # padded gather: index padding rows point at dict slot 0;
+                    # their garbage values land beyond the live prefix
+                    idx, v, dd, extra = slot
+                    idx32 = idx.astype(jnp.int32)
+                    if pad:
+                        idx32 = jnp.concatenate(
+                            [idx32, jnp.zeros(pad, jnp.int32)], axis=0)
+                    d = jnp.take(dd, idx32, axis=0)
+                    nd += 2 + 2 * bool(pad) + (extra is not None)
+                    if encoded[i] == "string":
+                        l = jnp.take(extra, idx32, axis=0)
+                        bits = None
+                        enc_lengths = extra
+                    else:
+                        bits = (jnp.take(extra, idx32, axis=0)
+                                if extra is not None else None)
+                        l = None
+                        enc_lengths = None
+                    token, unique = enc_meta[i]
+                    if unique:
+                        # the retained encoding pads its dictionary to a
+                        # power-of-two bucket ON DEVICE (zero link bytes): the
+                        # padded size is the jit-key shape, so per-row-group
+                        # dictionary growth doesn't recompile encoded-domain
+                        # programs
+                        k_real = int(dd.shape[0])
+                        dpad = ce.dict_bucket(k_real) - k_real
+                        dd_enc, len_enc = dd, enc_lengths
+                        if dpad:
+                            dd_enc = jnp.concatenate(
+                                [dd, jnp.zeros((dpad,) + dd.shape[1:],
+                                               dd.dtype)], axis=0)
+                            if enc_lengths is not None:
+                                len_enc = jnp.concatenate(
+                                    [enc_lengths,
+                                     jnp.zeros(dpad, enc_lengths.dtype)],
+                                    axis=0)
+                            nd += 2 + 2 * (enc_lengths is not None)
+                        enc = ce.DictEncoding(idx32, dd_enc, k_real, len_enc,
+                                              token)
+                else:
+                    d, v, l, bits = slot
+                    if pad:
+                        d = jnp.concatenate(
+                            [d, jnp.zeros((pad,) + d.shape[1:], d.dtype)],
+                            axis=0)
+                        if l is not None:
+                            l = jnp.concatenate([l, jnp.zeros(pad, l.dtype)],
+                                                axis=0)
+                        if bits is not None:
+                            bits = jnp.concatenate(
+                                [bits, jnp.zeros(pad, bits.dtype)], axis=0)
+                        nd += 2 * (1 + (l is not None) + (bits is not None))
+                if v is not None:
+                    if pad and v.shape[0] != cap:
+                        v = jnp.concatenate([v, jnp.zeros(pad, jnp.bool_)])
+                        nd += 2
+                    validity = v
+                else:
+                    validity = alive
+                cols.append(DeviceColumn(f.dtype, d, validity, l, bits,
+                                         encoding=enc))
+            if sp is not None:
+                sp.note(columns=len(encoded), dispatches=nd)
         return DeviceBatch(schema, tuple(cols), n)
 
     def sliced_buffers(self) -> List[Tuple]:

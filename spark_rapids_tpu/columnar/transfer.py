@@ -196,9 +196,6 @@ class PendingDownload:
         self._schema = batch.schema
         self._num_rows = batch.num_rows
         self._sliced = batch.sliced_buffers()
-        #: dispatch timestamp — the span start (an existing boundary: the
-        #: copy_to_host_async enqueue; resolution stamps the end, R002)
-        self._t_dispatch_ns = time.perf_counter_ns()
         nbytes = 0
         for data, validity, lengths in self._sliced:
             for arr in (data, validity, lengths):
@@ -223,14 +220,6 @@ class PendingDownload:
         m = um.TRANSFER_METRICS
         m[um.TRANSFER_DOWNLOAD_BYTES].add(self.nbytes)
         m[um.TRANSFER_DOWNLOAD_SECONDS].add(dt)
-        # dispatch -> resolve window: the overlapped D2H the Perfetto view
-        # shows riding under the remaining compute (streaming collect).
-        # Per-batch path: the args dict builds only when tracing is live.
-        if _tracing.TRACER.on:
-            _tracing.record("transfer.download", _tracing.LAYER_TRANSFER,
-                            self._t_dispatch_ns,
-                            time.perf_counter_ns() - self._t_dispatch_ns,
-                            {"bytes": self.nbytes, "rows": self._num_rows})
         with _tracing.span("download.to_arrow", _tracing.LAYER_TRANSFER):
             return fetched_to_arrow(self._schema, fetched, self._num_rows)
 

@@ -26,7 +26,6 @@ from __future__ import annotations
 import functools
 import itertools
 import threading
-import time
 from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -895,31 +894,27 @@ class TpuShuffleExchangeExec(ShuffleExchangeExecBase):
         no data moves or re-splits)."""
         self._ensure_map(ctx)
         env = _local_shuffle_env(ctx)
-        t0 = time.perf_counter_ns() if _tracing.enabled() else 0
-        blocks = rows = 0
-        try:
-            for block in env.shuffle_catalog.blocks_for_partition(
-                    self._shuffle_id, ctx.partition_id):
-                if map_filter is not None and block.map_id not in map_filter:
-                    continue
-                blocks += 1
+        for block in env.shuffle_catalog.blocks_for_partition(
+                self._shuffle_id, ctx.partition_id):
+            if map_filter is not None and block.map_id not in map_filter:
+                continue
+            # one span per block, closed before the yield: the reduce
+            # side's own time, without the consumer's
+            with _tracing.span("exchange.fetch",
+                               _tracing.LAYER_SHUFFLE) as sp:
+                batches = []
                 for buf, _meta in env.shuffle_catalog.acquire_buffers(block):
                     try:
-                        batch = buf.get_batch()
+                        batches.append(buf.get_batch())
                     finally:
                         buf.close()
-                    self.count_output(batch.num_rows)
-                    rows += batch.num_rows
-                    yield batch
-        finally:
-            if t0:
-                # the reduce side of one partition: first catalog lookup to
-                # the consumer's last pull (its work between pulls included)
-                _tracing.record(
-                    "exchange.read", _tracing.LAYER_SHUFFLE, t0,
-                    time.perf_counter_ns() - t0,
-                    {"partition": ctx.partition_id, "blocks": blocks,
-                     "rows": rows})
+                if sp is not None:
+                    sp.note(partition=ctx.partition_id, map_id=block.map_id,
+                            rows=sum(b.num_rows for b in batches),
+                            bytes=sum(b.device_size_bytes for b in batches))
+            for batch in batches:
+                self.count_output(batch.num_rows)
+                yield batch
 
     # ---- map side ------------------------------------------------------------
     def iter_map_pieces(self, ctx: ExecContext, partition_ids=None,

@@ -102,71 +102,87 @@ def concat_device_batches(batches: List[DeviceBatch], schema: Schema,
     assert_unsharded(batches, "concat_device_batches")
     if len(batches) == 1:
         return batches[0]
-    total = sum(b.num_rows for b in batches)
-    cap = bucket_capacity(total)
-    cols = []
-    for ci, f in enumerate(schema):
-        datas, valids, lens, bit_parts = [], [], [], []
-        # the f64 bit sibling survives only when EVERY contributor carries
-        # one (upload-time doubles); device-computed doubles have none and
-        # a partial sibling would desynchronize from the data
-        carry_bits = (f.dtype is DType.DOUBLE
-                      and all(b.columns[ci].bits is not None for b in batches))
-        # the dictionary encoding survives when every contributor carries
-        # one from the SAME dictionary stream (DictionaryUnifier token):
-        # dictionaries are then prefix-compatible, so the concatenated
-        # index vector stays valid against the largest contributor's
-        # dictionary — encoded-domain operators keep working after coalesce
-        encs = [b.columns[ci].encoding for b in batches]
-        carry_enc = (all(e is not None and e.token is not None
-                         for e in encs)
-                     and len({e.token for e in encs}) == 1)
-        idx_parts = []
-        for b in batches:
-            c = b.columns[ci]
-            datas.append(c.data[:b.num_rows])
-            valids.append(c.validity[:b.num_rows])
-            if c.lengths is not None:
-                lens.append(c.lengths[:b.num_rows])
-            if carry_bits:
-                bit_parts.append(c.bits[:b.num_rows])
+    # the dispatch of eager slices and concatenates, through no program
+    # cache; not awaited
+    with _tracing.span("batch.concat", _tracing.LAYER_EXEC) as sp:
+        total = sum(b.num_rows for b in batches)
+        cap = bucket_capacity(total)
+        cols = []
+        nd = 0      # eager device calls issued, counted as issued
+        for ci, f in enumerate(schema):
+            datas, valids, lens, bit_parts = [], [], [], []
+            # the f64 bit sibling survives only when EVERY contributor carries
+            # one (upload-time doubles); device-computed doubles have none and
+            # a partial sibling would desynchronize from the data
+            carry_bits = (f.dtype is DType.DOUBLE
+                          and all(b.columns[ci].bits is not None
+                                  for b in batches))
+            # the dictionary encoding survives when every contributor carries
+            # one from the SAME dictionary stream (DictionaryUnifier token):
+            # dictionaries are then prefix-compatible, so the concatenated
+            # index vector stays valid against the largest contributor's
+            # dictionary — encoded-domain operators keep working after coalesce
+            encs = [b.columns[ci].encoding for b in batches]
+            carry_enc = (all(e is not None and e.token is not None
+                             for e in encs)
+                         and len({e.token for e in encs}) == 1)
+            idx_parts = []
+            for b in batches:
+                c = b.columns[ci]
+                datas.append(c.data[:b.num_rows])
+                valids.append(c.validity[:b.num_rows])
+                if c.lengths is not None:
+                    lens.append(c.lengths[:b.num_rows])
+                if carry_bits:
+                    bit_parts.append(c.bits[:b.num_rows])
+                if carry_enc:
+                    idx_parts.append(c.encoding.indices[:b.num_rows])
+            if f.dtype is DType.STRING:
+                from spark_rapids_tpu.ops.strings import pad_width
+                W = max(d.shape[-1] for d in datas)
+                datas = [pad_width(jnp, d, W) for d in datas]
+            data = jnp.concatenate(datas, axis=0)
+            validity = jnp.concatenate(valids, axis=0)
+            bits = jnp.concatenate(bit_parts, axis=0) if carry_bits else None
+            pad = cap - total
+            if pad:
+                pad_shape = (pad,) + data.shape[1:]
+                data = jnp.concatenate(
+                    [data, jnp.zeros(pad_shape, data.dtype)], axis=0)
+                validity = jnp.concatenate(
+                    [validity, jnp.zeros(pad, bool)], axis=0)
+                if bits is not None:
+                    bits = jnp.concatenate(
+                        [bits, jnp.zeros(pad, bits.dtype)], axis=0)
+            # a slice a part, a concatenate a joined vector, and with a pad
+            # its zeros and one more concatenate
+            nd += (len(datas) + len(valids) + len(lens) + len(bit_parts)
+                   + len(idx_parts)
+                   + (3 if pad else 1) * (2 + carry_bits + carry_enc
+                                          + (f.dtype is DType.STRING)))
+            enc = None
             if carry_enc:
-                idx_parts.append(c.encoding.indices[:b.num_rows])
-        if f.dtype is DType.STRING:
-            from spark_rapids_tpu.ops.strings import pad_width
-            W = max(d.shape[-1] for d in datas)
-            datas = [pad_width(jnp, d, W) for d in datas]
-        data = jnp.concatenate(datas, axis=0)
-        validity = jnp.concatenate(valids, axis=0)
-        bits = jnp.concatenate(bit_parts, axis=0) if carry_bits else None
-        pad = cap - total
-        if pad:
-            pad_shape = (pad,) + data.shape[1:]
-            data = jnp.concatenate([data, jnp.zeros(pad_shape, data.dtype)], axis=0)
-            validity = jnp.concatenate([validity, jnp.zeros(pad, bool)], axis=0)
-            if bits is not None:
-                bits = jnp.concatenate(
-                    [bits, jnp.zeros(pad, bits.dtype)], axis=0)
-        enc = None
-        if carry_enc:
-            from spark_rapids_tpu.columnar.encoding import DictEncoding
-            indices = jnp.concatenate(idx_parts, axis=0)
-            if pad:
-                indices = jnp.concatenate(
-                    [indices, jnp.zeros(pad, indices.dtype)], axis=0)
-            big = max(encs, key=lambda e: (e.k, e.k_real))
-            enc = DictEncoding(indices, big.values, big.k_real, big.lengths,
-                               big.token)
-        if f.dtype is DType.STRING:
-            lengths = jnp.concatenate(lens, axis=0)
-            if pad:
-                lengths = jnp.concatenate(
-                    [lengths, jnp.zeros(pad, lengths.dtype)], axis=0)
-            cols.append(DeviceColumn(f.dtype, data, validity, lengths,
-                                     encoding=enc))
-        else:
-            cols.append(DeviceColumn(f.dtype, data, validity, bits=bits,
-                                     encoding=enc))
+                from spark_rapids_tpu.columnar.encoding import DictEncoding
+                indices = jnp.concatenate(idx_parts, axis=0)
+                if pad:
+                    indices = jnp.concatenate(
+                        [indices, jnp.zeros(pad, indices.dtype)], axis=0)
+                big = max(encs, key=lambda e: (e.k, e.k_real))
+                enc = DictEncoding(indices, big.values, big.k_real, big.lengths,
+                                   big.token)
+            if f.dtype is DType.STRING:
+                lengths = jnp.concatenate(lens, axis=0)
+                if pad:
+                    lengths = jnp.concatenate(
+                        [lengths, jnp.zeros(pad, lengths.dtype)], axis=0)
+                cols.append(DeviceColumn(f.dtype, data, validity, lengths,
+                                         encoding=enc))
+            else:
+                cols.append(DeviceColumn(f.dtype, data, validity, bits=bits,
+                                         encoding=enc))
+        if sp is not None:
+            sp.note(batches=len(batches), rows=total, columns=len(cols),
+                    dispatches=nd)
     return DeviceBatch(schema, tuple(cols), total)
 
 

@@ -130,13 +130,29 @@ def _iter_file_tables(f: PartitionedFile, data_schema: Schema,
                                      partition_schema, batch_rows,
                                      device_rle, unifier)
         return
-    for rb in pf.iter_batches(batch_size=batch_rows, row_groups=groups,
-                              columns=want):
-        t = evolve_schema(pa.Table.from_batches([rb]), data_schema)
-        if needs_rebase:
-            t = _rebase_legacy_datetimes(t)
-        yield append_partition_columns(t, partition_schema,
-                                       f.partition_values)
+    batches = pf.iter_batches(batch_size=batch_rows, row_groups=groups,
+                              columns=want)
+    while True:
+        # one span per batch, closed before the yield: the consumer's time
+        # is not the scan's (the last one finds the end: no rows)
+        with _tracing.span("scan.read_group", _tracing.LAYER_TRANSFER) as grp:
+            with _tracing.span("scan.arrow_read",
+                               _tracing.LAYER_TRANSFER) as sp:
+                rb = next(batches, None)
+                if sp is not None and rb is not None:
+                    sp.note(columns=want, rows=rb.num_rows, bytes=rb.nbytes)
+            if rb is not None:
+                t = evolve_schema(pa.Table.from_batches([rb]), data_schema)
+                if needs_rebase:
+                    t = _rebase_legacy_datetimes(t)
+                t = append_partition_columns(t, partition_schema,
+                                             f.partition_values)
+            if grp is not None:
+                grp.note(rows=rb.num_rows if rb is not None else 0,
+                         columns=len(want))
+        if rb is None:
+            return
+        yield t
 
 
 def _iter_dict_tables(pf: pq.ParquetFile, f: PartitionedFile,
@@ -157,8 +173,7 @@ def _iter_dict_tables(pf: pq.ParquetFile, f: PartitionedFile,
     split the row group at the dictionary-prefix/PLAIN-tail boundary:
     prefix segments stay encoded, tail segments carry the host-decoded
     values."""
-    from spark_rapids_tpu.columnar.encoding import (DictionaryUnifier,
-                                                    with_dict_tokens)
+    from spark_rapids_tpu.columnar.encoding import DictionaryUnifier
     from spark_rapids_tpu.io.parquet_pages import read_dict_column
     if unifier is None:
         unifier = DictionaryUnifier()
@@ -172,57 +187,89 @@ def _iter_dict_tables(pf: pq.ParquetFile, f: PartitionedFile,
     pf_str = (pq.ParquetFile(f.path, read_dictionary=str_cols)
               if str_cols else pf)
     for rg in groups:
-        encoded = {}
-        for f2 in data_schema:
-            if f2.dtype is DType.STRING or f2.name not in names:
-                continue
-            ci = names.index(f2.name)
-            at = arrow_schema.field(f2.name).type
-            r = read_dict_column(f.path, md, rg, ci, at,
-                                 want_runs=device_rle)
-            if r is not None:
-                encoded[f2.name] = r
-        rest = [n for n in want if n not in encoded]
-        plain = (pf_str.read_row_group(rg, columns=rest) if rest else None)
-        nrows = md.row_group(rg).num_rows
-        cols = {}       # name -> (prefix_or_whole, tail_or_None, split_row)
-        tokens = {}
+        # the group's span closes before its first yield: the consumer's
+        # time between batches is not the scan's
+        with _tracing.span("scan.read_group", _tracing.LAYER_TRANSFER) as grp:
+            nrows = md.row_group(rg).num_rows
+            encoded = {}
+            for f2 in data_schema:
+                if f2.dtype is DType.STRING or f2.name not in names:
+                    continue
+                ci = names.index(f2.name)
+                at = arrow_schema.field(f2.name).type
+                r = read_dict_column(f.path, md, rg, ci, at,
+                                     want_runs=device_rle)
+                if r is not None:
+                    encoded[f2.name] = r
+            rest = [n for n in want if n not in encoded]
+            plain = None
+            if rest:
+                with _tracing.span("scan.arrow_read",
+                                   _tracing.LAYER_TRANSFER) as sp:
+                    plain = pf_str.read_row_group(rg, columns=rest)
+                    if sp is not None:
+                        sp.note(columns=rest, rows=plain.num_rows,
+                                bytes=plain.nbytes)
+            with _tracing.span("scan.unify", _tracing.LAYER_TRANSFER) as sp:
+                out = _group_tables(encoded, plain, want, nrows, unifier,
+                                    batch_rows, data_schema,
+                                    partition_schema, f.partition_values)
+                if sp is not None:
+                    sp.note(columns=len(want), batches=len(out))
+            if grp is not None:
+                grp.note(row_group=rg, rows=nrows, columns=len(want))
+        yield from out
+
+
+def _group_tables(encoded, plain, want, nrows: int, unifier,
+                  batch_rows: int, data_schema: Schema,
+                  partition_schema: Schema,
+                  partition_values) -> List[pa.Table]:
+    """One row group's columns -> its batch_rows-bounded tables: every
+    dictionary remapped through the scan's unifier, the group split where a
+    mixed-encoding column's dictionary prefix ends, each segment sliced
+    (zero-copy)."""
+    from spark_rapids_tpu.columnar.encoding import with_dict_tokens
+    cols = {}       # name -> (prefix_or_whole, tail_or_None, split_row)
+    tokens = {}
+    for n in want:
+        if n in encoded:
+            r = encoded[n]
+            prefix = r.prefix
+            if isinstance(prefix, pa.DictionaryArray):
+                prefix, tokens[n] = unifier.unify(n, prefix)
+            cols[n] = (prefix, r.tail, len(prefix))
+        else:
+            c = plain.column(n)
+            if isinstance(c, pa.ChunkedArray):
+                # combine_chunks on a ChunkedArray yields an Array
+                # (also for the 0-chunk empty-file case)
+                c = (c.chunk(0) if c.num_chunks == 1
+                     else c.combine_chunks())
+            if isinstance(c, pa.DictionaryArray) and len(c.dictionary):
+                c, tokens[n] = unifier.unify(n, c)
+            cols[n] = (c, None, nrows)
+    # segment boundaries: a mixed-encoding column splits the row group
+    # where its dictionary prefix ends (only the tail is decoded)
+    bounds = sorted({0, nrows} | {sr for _, tail, sr in cols.values()
+                                  if tail is not None})
+    out = []
+    for s, e in zip(bounds, bounds[1:]):
+        seg_cols, fields = [], []
         for n in want:
-            if n in encoded:
-                r = encoded[n]
-                prefix = r.prefix
-                if isinstance(prefix, pa.DictionaryArray):
-                    prefix, tokens[n] = unifier.unify(n, prefix)
-                cols[n] = (prefix, r.tail, len(prefix))
-            else:
-                c = plain.column(n)
-                if isinstance(c, pa.ChunkedArray):
-                    # combine_chunks on a ChunkedArray yields an Array
-                    # (also for the 0-chunk empty-file case)
-                    c = (c.chunk(0) if c.num_chunks == 1
-                         else c.combine_chunks())
-                if isinstance(c, pa.DictionaryArray) and len(c.dictionary):
-                    c, tokens[n] = unifier.unify(n, c)
-                cols[n] = (c, None, nrows)
-        # segment boundaries: a mixed-encoding column splits the row group
-        # where its dictionary prefix ends (only the tail is decoded)
-        bounds = sorted({0, nrows} | {sr for _, tail, sr in cols.values()
-                                      if tail is not None})
-        for s, e in zip(bounds, bounds[1:]):
-            seg_cols, fields = [], []
-            for n in want:
-                prefix, tail, split = cols[n]
-                a = (prefix.slice(s, e - s) if e <= split
-                     else tail.slice(s - split, e - s))
-                seg_cols.append(a)
-                fields.append(pa.field(n, a.type))
-            table = pa.table(seg_cols, schema=pa.schema(fields))
-            table = with_dict_tokens(table, tokens)
-            for start in range(0, e - s, batch_rows):
-                t = table.slice(start, min(batch_rows, e - s - start))
-                t = evolve_schema(t, data_schema)
-                yield append_partition_columns(t, partition_schema,
-                                               f.partition_values)
+            prefix, tail, split = cols[n]
+            a = (prefix.slice(s, e - s) if e <= split
+                 else tail.slice(s - split, e - s))
+            seg_cols.append(a)
+            fields.append(pa.field(n, a.type))
+        table = pa.table(seg_cols, schema=pa.schema(fields))
+        table = with_dict_tokens(table, tokens)
+        for start in range(0, e - s, batch_rows):
+            t = table.slice(start, min(batch_rows, e - s - start))
+            t = evolve_schema(t, data_schema)
+            out.append(append_partition_columns(t, partition_schema,
+                                                partition_values))
+    return out
 
 
 def _rebase_legacy_datetimes(t: pa.Table) -> pa.Table:
@@ -417,6 +464,15 @@ class TpuParquetScanExec(_ParquetScanBase):
         stop = threading.Event()
         smax = ctx.string_max_bytes
 
+        def put(item) -> bool:
+            # a full queue is the consumer holding the scan back: a span
+            # only where the put blocks
+            if _tracing.TRACER.on and q.full():
+                with _tracing.span("scan.backpressure",
+                                   _tracing.LAYER_TRANSFER):
+                    return _put_abortable(q, item, stop)
+            return _put_abortable(q, item, stop)
+
         def produce() -> None:
             # rebind the owning query thread-locally (the PipelinedExec
             # producer discipline): program-cache attribution AND the
@@ -434,12 +490,12 @@ class TpuParquetScanExec(_ParquetScanBase):
                         # silently default.
                         b = upload_table_conf(t, smax, ctx.conf,
                                               device=ctx.device)
-                        if not _put_abortable(q, ("b", b), stop):
+                        if not put(("b", b)):
                             return  # consumer abandoned the scan early
                 except BaseException as e:  # noqa: BLE001 - reraised below
-                    _put_abortable(q, ("e", e), stop)
+                    put(("e", e))
                     return
-                _put_abortable(q, ("end", None), stop)
+                put(("end", None))
 
         spawning_span = _tracing.current() if _tracing.TRACER.on else None
         worker = threading.Thread(target=produce, daemon=True,
@@ -447,7 +503,9 @@ class TpuParquetScanExec(_ParquetScanBase):
         worker.start()
         try:
             while True:
-                kind, val = q.get()
+                # what the query's own thread stands still for
+                with _tracing.span("scan.wait", _tracing.LAYER_TRANSFER):
+                    kind, val = q.get()
                 if kind == "end":
                     break
                 if kind == "e":

@@ -28,6 +28,8 @@ from typing import List, Optional, Tuple
 import numpy as np
 import pyarrow as pa
 
+from spark_rapids_tpu.utils import tracing as _tracing
+
 # parquet enums (format/PageType, format/Encoding)
 _DATA_PAGE, _DICT_PAGE, _DATA_PAGE_V2 = 0, 2, 3
 _ENC_PLAIN, _ENC_PLAIN_DICT, _ENC_RLE, _ENC_RLE_DICT = 0, 2, 3, 8
@@ -201,13 +203,14 @@ class _ChunkPages:
                  runs: Tuple[np.ndarray, np.ndarray],
                  prefix_defs: Optional[np.ndarray], prefix_rows: int,
                  tail_values: Optional[np.ndarray],
-                 tail_defs: Optional[np.ndarray]):
+                 tail_defs: Optional[np.ndarray], pages: int = 0):
         self.dictionary = dictionary
         self.runs = runs                  # (values, lengths) over DEFINED rows
         self.prefix_defs = prefix_defs    # bool[prefix_rows] or None (no nulls)
         self.prefix_rows = prefix_rows
         self.tail_values = tail_values    # defined PLAIN values or None
         self.tail_defs = tail_defs        # bool[tail_rows] or None
+        self.pages = pages                # dictionary + data pages parsed
 
     def prefix_indices(self) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """Expand the run form to per-row indices (+validity) — the
@@ -224,8 +227,11 @@ class _ChunkPages:
 def _decompress(codec: str, raw: memoryview, usize: int) -> memoryview:
     if codec == "UNCOMPRESSED":
         return raw
-    out = pa.Codec(codec.lower()).decompress(bytes(raw),
-                                             decompressed_size=usize)
+    with _tracing.span("scan.decompress", _tracing.LAYER_TRANSFER) as sp:
+        out = pa.Codec(codec.lower()).decompress(bytes(raw),
+                                                 decompressed_size=usize)
+        if sp is not None:
+            sp.note(compressed_bytes=len(raw), bytes=usize)
     return memoryview(out)
 
 
@@ -248,12 +254,13 @@ def decode_dict_chunk(data: memoryview, codec: str, phys: str,
     tail_val_parts: List[np.ndarray] = []
     tail_def_parts: List[np.ndarray] = []
     prefix_rows = 0
-    seen = 0
+    seen = pages = 0
     in_tail = False
     while seen < num_values and pos < len(data):
         th = _Thrift(data, pos)
         hdr = th.read_struct()
         body = th.pos
+        pages += 1
         ptype = hdr.get(1)
         usize, csize = hdr.get(2, 0), hdr.get(3, 0)
         pos = body + csize
@@ -351,7 +358,7 @@ def decode_dict_chunk(data: memoryview, codec: str, phys: str,
         if tail_defs is None and len(tail_values) != num_values - prefix_rows:
             return None                   # inconsistent counts: bail
     return _ChunkPages(dictionary, (rvals, rlens), prefix_defs, prefix_rows,
-                       tail_values, tail_defs)
+                       tail_values, tail_defs, pages)
 
 
 # ------------------------------------------------------------- file surface
@@ -377,29 +384,53 @@ def read_dict_column(path: str, pf_metadata, rg: int, col_idx: int,
     """Read one row group's column from the raw page bytes, keeping the
     file's own encoding; None when ineligible OR when no encoded form is
     smaller than the decoded column (per-column fallback — shipping an
-    encoding that does not shrink the link is pure overhead)."""
+    encoding that does not shrink the link is pure overhead). One
+    ``scan.chunk_decode`` span: file read, page headers, decompression,
+    run parse and Arrow assembly; ``form`` says what came of it."""
     col = pf_metadata.row_group(rg).column(col_idx)
-    sc = pf_metadata.schema.column(col_idx)
+    with _tracing.span("scan.chunk_decode", _tracing.LAYER_TRANSFER) as sp:
+        read, pages = _read_chunk(path, col,
+                                  pf_metadata.schema.column(col_idx),
+                                  arrow_type, want_runs)
+        if sp is not None:
+            width = _PHYS_NP.get(col.physical_type)
+            sp.note(column=col.path_in_schema, codec=col.compression,
+                    compressed_bytes=col.total_compressed_size,
+                    decoded_bytes=(col.num_values * np.dtype(width).itemsize
+                                   if width else 0),
+                    pages=pages,
+                    form=("declined" if read is None
+                          else "mixed" if read.tail is not None
+                          else "ree" if pa.types.is_run_end_encoded(
+                              read.prefix.type) else "dict"))
+    return read
+
+
+def _read_chunk(path: str, col, sc, arrow_type: pa.DataType,
+                want_runs: bool) -> Tuple[Optional[ColumnRead], int]:
+    """(the column read or None, pages parsed) of one column chunk."""
     if sc.max_repetition_level != 0 or sc.max_definition_level > 1:
-        return None
+        return None, 0
     if col.dictionary_page_offset is None:
-        return None
+        return None, 0
     try:
         pa.Codec(col.compression.lower())
     except (ValueError, NotImplementedError):
         if col.compression != "UNCOMPRESSED":
-            return None
-    start = col.dictionary_page_offset
-    with open(path, "rb") as f:
-        f.seek(start)
-        data = memoryview(f.read(col.total_compressed_size))
+            return None, 0
+    with _tracing.span("scan.chunk_io", _tracing.LAYER_TRANSFER) as sp:
+        with open(path, "rb") as f:
+            f.seek(col.dictionary_page_offset)
+            data = memoryview(f.read(col.total_compressed_size))
+        if sp is not None:
+            sp.note(bytes=len(data))
     try:
         chunk = decode_dict_chunk(data, col.compression, col.physical_type,
                                   col.num_values, sc.max_definition_level)
     except Exception:       # malformed/unexpected layout: decoded fallback
-        return None
+        return None, 0
     if chunk is None:
-        return None
+        return None, 0
     k = len(chunk.dictionary)
     elem = chunk.dictionary.dtype.itemsize
     n_prefix = chunk.prefix_rows
@@ -409,7 +440,8 @@ def read_dict_column(path: str, pf_metadata, rg: int, col_idx: int,
     ree_bytes = len(rvals) * (4 + elem)
     decoded_bytes = n_prefix * elem
     if min(dict_bytes, ree_bytes) >= decoded_bytes:
-        return None         # no encoding survives: decoded upload is smaller
+        # no encoding survives: decoded upload is smaller
+        return None, chunk.pages
     dict_vals = pa.array(chunk.dictionary)
     if not dict_vals.type.equals(arrow_type):
         dict_vals = dict_vals.cast(arrow_type)
@@ -440,4 +472,4 @@ def read_dict_column(path: str, pf_metadata, rg: int, col_idx: int,
             tail = pa.array(full, mask=~chunk.tail_defs)
         if not tail.type.equals(arrow_type):
             tail = tail.cast(arrow_type)
-    return ColumnRead(prefix, tail)
+    return ColumnRead(prefix, tail), chunk.pages

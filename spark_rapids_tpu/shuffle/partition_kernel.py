@@ -50,7 +50,9 @@ from jax.experimental.pallas import tpu as pltpu
 from spark_rapids_tpu.columnar.batch import DeviceBatch
 from spark_rapids_tpu.columnar.column import DeviceColumn
 from spark_rapids_tpu.columnar.dtypes import DType, Schema, bucket_capacity
-from spark_rapids_tpu.serving.program_cache import named_jit
+from spark_rapids_tpu.serving.program_cache import (_Program,
+                                                    global_program_cache,
+                                                    named_jit)
 
 W = 512                    #: window rows (one spread dot per window)
 GROUP_WINDOWS = 64         #: windows per group (one output piece set each)
@@ -798,7 +800,10 @@ def consolidate(out, stats_host: np.ndarray, j: int, spec: PackSpec,
                 mat = jax.lax.optimization_barrier(mat)
                 return _flatten_unpacked(unpack_columns(spec, schema, mat))
             return named_jit(key[0], f)
-        fn = build()
+        # this module's own dict keeps it (no hit or miss of the program
+        # cache), the cache's wrapper gives its calls their program.pconsol
+        # span and its first call to compile_s, as every cached program's
+        fn = _Program(build(), global_program_cache())
         _PROGRAMS[key] = fn
 
     res = fn(out, np.int32(j), np.int32(nb_tot * BLOCK),

@@ -34,9 +34,8 @@ Design constraints (the R002 contract):
   profile hold the same spans and the profile's are on the device
   trace's clock. Windows between asynchronous boundaries that are only
   known afterwards (``record()`` with explicit timestamps:
-  ``transfer.download``, ``shuffle.fetch``, ``serving.queue_wait``,
-  ``serving.preempt_yield``), instants and the ``query`` root are in
-  the ring alone.
+  ``shuffle.fetch``, ``serving.queue_wait``, ``serving.preempt_yield``),
+  instants and the ``query`` root are in the ring alone.
 - disabled mode is near-zero-cost: every hook is gated on one module-bool
   read (``enabled()``); ``span()`` returns a shared no-op context manager
   without allocating.

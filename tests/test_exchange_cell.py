@@ -1,7 +1,7 @@
 """TPC-H Q1 over ``lineitem.repartition(8, "l_orderkey")``, the deployment of
 ``tpch_sf1_exchange``: the answer against a plain numpy Q1 written here, the
 partitioning against a plain reference of what a hash partitioning promises,
-what the ``exchange.map`` / ``exchange.split`` / ``exchange.read`` spans say
+what the ``exchange.map`` / ``exchange.split`` / ``exchange.fetch`` spans say
 of one query, and that a session which runs the query again and again builds
 nothing new and leaves nothing in the shuffle catalog. CPU, SF0.01; the
 chip's cell is ``tpch_sf1_exchange.repartition`` (benchmark/), and the
@@ -198,14 +198,53 @@ def test_a_query_leaves_the_exchanges_spans(lineitem, engine):
     moved = [s for s in splits if s.args["path"] != "single"]
     assert len(moved) == sum(m.args["kernel_batches"] + m.args["sort_batches"]
                              for m in spans["exchange.map"])
-    # the reduce side: each partition of the hash exchange read once, then
-    # the single partition above
-    reads = spans["exchange.read"]
-    assert sorted(r.args["partition"] for r in reads) \
-        == sorted(list(range(PARTITIONS)) + [0])
-    assert sum(r.args["rows"] for r in reads) \
+    # the reduce side: one fetch a block, the eight partitions of the hash
+    # exchange (one block each) and then the eight blocks of the single
+    # partition above, each closed before its consumer ran
+    fetches = spans["exchange.fetch"]
+    by_exec = collections.defaultdict(list)
+    for r in fetches:
+        by_exec[r.plan_id].append(r)
+    assert sorted(r.args["partition"] for r in by_exec[hashed.plan_id]) \
+        == list(range(PARTITIONS))
+    assert [r.args["partition"] for r in by_exec[single.plan_id]] \
+        == [0] * PARTITIONS
+    assert sorted(r.args["map_id"] for r in by_exec[single.plan_id]) \
+        == list(range(PARTITIONS))
+    assert sum(r.args["rows"] for r in fetches) \
         == hashed.args["rows"] + single.args["rows"]
-    assert len(reads) == len(execs)
+    assert all(r.args["bytes"] > 0 for r in fetches)
+    assert len(fetches) == 2 * PARTITIONS
+    assert set(by_exec) == {r.plan_id for r in execs}
+    # closed before the consumer ran: the stage's program over a fetched
+    # batch starts after that batch's fetch has ended, and no span lies
+    # under a fetch
+    assert not [r for r in records
+                if r.parent_id in {f.span_id for f in fetches}]
+    stages = sorted((r for r in records if r.name == "program.stage"),
+                    key=lambda r: r.ts_ns)
+    hash_fetches = sorted(by_exec[hashed.plan_id], key=lambda r: r.ts_ns)
+    assert len(stages) == len(hash_fetches)
+    for fetch, stage in zip(hash_fetches, stages):
+        assert fetch.ts_ns + fetch.dur_ns <= stage.ts_ns
+    # the aggregate over the eight batches concatenates them once, under
+    # its own exec span
+    (concat,) = [r for r in records if r.name == "batch.concat"]
+    assert by_id_name(records, concat.parent_id) == "TpuHashAggregateExec"
+    assert concat.args["batches"] == PARTITIONS
+    assert concat.args["rows"] == single.args["rows"]
+    assert concat.args["dispatches"] > concat.args["columns"] * PARTITIONS
+    if engine == "kernel":
+        # the consolidation program, one call a piece, through the wrapper
+        # that gives every cached program its span
+        consols = [r for r in records if r.name == "program.pconsol"]
+        assert len(consols) == PARTITIONS
+        assert {by_id_name(records, r.parent_id) for r in consols} \
+            == {"exchange.split"}
+
+
+def by_id_name(records, span_id):
+    return next(r.name for r in records if r.span_id == span_id)
 
 
 def test_without_tracing_the_exchange_leaves_no_span_and_no_program(lineitem):

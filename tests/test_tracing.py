@@ -60,6 +60,20 @@ def test_disabled_mode_records_nothing():
     assert not t.on
 
 
+@pytest.mark.parametrize("site", ["parquet_prefetch", "parquet_serial",
+                                  "exchange"])
+def test_disabled_mode_records_nothing_at_the_leaf_sites(site, tpch,
+                                                         lineitem_dir):
+    """Untraced, the scan's host pipeline, the action's blocks, the
+    concat, the fetches and the consolidation calls reach the ring with
+    nothing: the same queries that leave those spans when traced."""
+    assert not tracing.TRACER.on
+    mark = tracing.TRACER.mark()
+    out = _leaf_site_query(site, tpch, lineitem_dir, trace=False).collect()
+    assert out.num_rows >= 1
+    assert tracing.TRACER.mark() == mark
+
+
 def test_span_records_on_exit():
     t = tracing.Tracer(capacity=32)
     with t.activate():
@@ -130,7 +144,10 @@ def test_chrome_export_valid_with_layers(tmp_path):
     assert all(counts[c] >= 1 for c in
                ("query", "plan", "action", "exec", "program", "transfer",
                 "memory", "serving")), counts
-    assert counts["query"] == counts["action"] == 1, counts
+    # one root and one action; the action's own blocks share its layer
+    assert counts["query"] == 1 and counts["action"] > 1, counts
+    assert [r.name for r in sess.last_trace
+            if r.name in ("query", "action")] == ["action", "query"]
 
 
 def _fake_annotations(monkeypatch):
@@ -208,11 +225,11 @@ KNOWN_KINDS = {
     "mshrink", "mexpand", "mwindow", "mwindow_part", "magg",
     "magg_merge_ag", "magg_merge_part", "magg_part", "mjoin_size",
     "mjoin_gather", "mjoin_lpart", "mjoin_rpart", "msort", "msort_sample",
-    "msort_part", "munion", "mexchange"}
+    "msort_part", "munion", "mexchange", "pconsol"}
 #: ring-only by their nature: the root, and windows whose two ends are only
 #: known afterwards (tracing.record with explicit timestamps)
-RING_ONLY = {"query", "transfer.download", "serving.queue_wait",
-             "serving.preempt_yield", "shuffle.fetch"}
+RING_ONLY = {"query", "serving.queue_wait", "serving.preempt_yield",
+             "shuffle.fetch"}
 
 
 @pytest.fixture(scope="module")
@@ -225,6 +242,39 @@ def _tpch_query(qnum, sess, tables):
     from spark_rapids_tpu.benchmarks.tpch_queries import QUERIES
     return QUERIES[qnum]({k: sess.create_dataframe(tables[k])
                           for k in ("lineitem", "orders", "customer")})
+
+
+@pytest.fixture(scope="module")
+def lineitem_dir(tpch, tmp_path_factory):
+    """SF0.002 lineitem as one parquet file of three row groups."""
+    import pyarrow.parquet as pq
+    d = tmp_path_factory.mktemp("lineitem")
+    pq.write_table(tpch["lineitem"], str(d / "part-0.parquet"),
+                   row_group_size=4096)
+    return str(d)
+
+
+PREFETCH = "spark.rapids.tpu.io.scan.prefetchBatches"
+
+
+def _leaf_site_query(site, tables, lineitem_dir, trace=True):
+    """Q1 over the parquet file with the scan's prefetch thread or
+    without, or over ``lineitem.repartition(8, "l_orderkey")`` through the
+    interpreted reorder kernel."""
+    from spark_rapids_tpu.benchmarks.tpch_queries import QUERIES
+    conf = {**BASE_CONF, "spark.rapids.tpu.sql.hasNans": "false",
+            "spark.rapids.tpu.trace.enabled": "true" if trace else "false"}
+    if site == "exchange":
+        sess = TpuSession({**conf,
+                           "spark.rapids.tpu.shuffle.kernel.mode":
+                           "interpret"})
+        lineitem = sess.create_dataframe(tables["lineitem"]).repartition(
+            8, "l_orderkey")
+    else:
+        sess = TpuSession({**conf, PREFETCH:
+                           "2" if site == "parquet_prefetch" else "0"})
+        lineitem = sess.read.parquet(lineitem_dir)
+    return QUERIES[1]({"lineitem": lineitem})
 
 
 def _assert_one_tree(records, query_id=None):
@@ -333,6 +383,153 @@ def test_span_tree_of_a_served_query(tpch):
                                             rel=0.05, abs=5e6)
 
 
+# --------------------------------------------- the leaf spans (PR 36)
+SCAN_SPANS = {"scan.read_group", "scan.chunk_decode", "scan.chunk_io",
+              "scan.decompress", "scan.arrow_read", "scan.unify",
+              "stage.host", "stage.put", "stage.expand"}
+#: what each leaf span of the scan hangs under
+SCAN_PARENTS = {"scan.read_group": "TpuParquetScanExec",
+                "scan.wait": "TpuParquetScanExec",
+                "scan.backpressure": "TpuParquetScanExec",
+                "scan.chunk_decode": "scan.read_group",
+                "scan.arrow_read": "scan.read_group",
+                "scan.unify": "scan.read_group",
+                "scan.chunk_io": "scan.chunk_decode",
+                "scan.decompress": "scan.chunk_decode",
+                "stage.host": "upload.stage", "stage.put": "upload.stage",
+                "stage.expand": "upload.stage"}
+
+
+@pytest.mark.parametrize("site", ["parquet_prefetch", "parquet_serial"])
+def test_parquet_scan_leaves_its_host_pipeline(site, tpch, lineitem_dir,
+                                               monkeypatch):
+    """A traced parquet scan: every leaf span of the host pipeline in the
+    query's one tree, under its parent and inside its interval, with the
+    scan exec's plan id, on the prefetch thread (or, with prefetch off, on
+    the query's, and then no ``scan.wait``)."""
+    import os
+    monkeypatch.setattr(os, "cpu_count", lambda: 4)     # prefetch needs 2
+    df = _leaf_site_query(site, tpch, lineitem_dir)
+    df.collect()
+    records = df.session.last_trace
+    root = _assert_one_tree(records)
+    by_id = {r.span_id: r for r in records}
+    (scan,) = [r for r in records if r.name == "TpuParquetScanExec"]
+    leaves = [r for r in records if r.name in SCAN_PARENTS]
+    names = {r.name for r in leaves}
+    assert SCAN_SPANS <= names, SCAN_SPANS - names
+    for r in leaves:
+        assert by_id[r.parent_id].name == SCAN_PARENTS[r.name], r.name
+        assert r.plan_id == scan.plan_id and r.cat == "transfer"
+    work = [r for r in leaves
+            if r.name not in ("scan.wait", "scan.backpressure")]
+    if site == "parquet_prefetch":
+        # the work on the one prefetch thread, the wait on the query's
+        assert len({r.tid for r in work}) == 1
+        assert work[0].tid != root.tid
+        waits = [r for r in leaves if r.name == "scan.wait"]
+        assert waits and {r.tid for r in waits} == {root.tid}
+    else:
+        assert {r.tid for r in work} == {root.tid}
+        assert not names & {"scan.wait", "scan.backpressure"}
+    groups = [r for r in leaves if r.name == "scan.read_group"]
+    assert [g.args["row_group"] for g in groups] == [0, 1, 2]
+    assert sum(g.args["rows"] for g in groups) == tpch["lineitem"].num_rows
+    decodes = [r for r in leaves if r.name == "scan.chunk_decode"]
+    reads = [r for r in leaves if r.name == "scan.arrow_read"]
+    encoded = []        # columns a group's batch carries still encoded
+    for g in groups:
+        assert g.args["columns"] == 7                   # Q1's, pruned
+        mine = [d for d in decodes if d.parent_id == g.span_id]
+        assert len(mine) == 5                           # the fixed-width
+        assert sum(d.dur_ns for d in mine) <= g.dur_ns
+        (read,) = [r for r in reads if r.parent_id == g.span_id]
+        # a declined chunk goes to pyarrow's read, which names it, beside
+        # the strings the page reader never takes
+        declined = {d.args["column"] for d in mine
+                    if d.args["form"] == "declined"}
+        assert "l_extendedprice" in declined            # all but distinct
+        assert set(read.args["columns"]) == declined | {"l_returnflag",
+                                                        "l_linestatus"}
+        assert read.args["rows"] == g.args["rows"] and read.args["bytes"] > 0
+        encoded.append(7 - len(declined))
+    for d in decodes:
+        assert d.args["form"] in ("dict", "ree", "mixed", "declined")
+        assert d.args["codec"] == "SNAPPY" and d.args["pages"] >= 2
+        assert 0 < d.args["compressed_bytes"]
+        assert d.args["decoded_bytes"] in (
+            by_id[d.parent_id].args["rows"] * 8,
+            by_id[d.parent_id].args["rows"] * 4)
+        kids = [r for r in leaves if r.parent_id == d.span_id]
+        (io,) = [k for k in kids if k.name == "scan.chunk_io"]
+        assert io.args["bytes"] == d.args["compressed_bytes"]
+        pages = [k for k in kids if k.name == "scan.decompress"]
+        assert len(pages) == d.args["pages"]
+        assert all(k.args["compressed_bytes"] > 0 and k.args["bytes"] > 0
+                   for k in pages)
+    stages = [r for r in records if r.name == "upload.stage"]
+    assert len(stages) == len(groups)
+    for st, n_encoded in zip(stages, encoded):
+        host, put, expand = sorted(
+            (r for r in leaves if r.parent_id == st.span_id),
+            key=lambda r: r.ts_ns)
+        assert (host.name, put.name, expand.name) == (
+            "stage.host", "stage.put", "stage.expand")
+        assert host.args["bytes"] > 0
+        # the encoded columns decode on the device by eager calls
+        assert expand.args["columns"] == n_encoded
+        assert expand.args["dispatches"] > expand.args["columns"]
+    # three batches reach the aggregate: one concat, under its exec
+    (concat,) = [r for r in records if r.name == "batch.concat"]
+    assert by_id[concat.parent_id].cat == "exec"
+    assert concat.args["batches"] == 3 and concat.args["columns"] == 7
+    assert concat.args["rows"] <= tpch["lineitem"].num_rows
+
+
+def test_one_batch_is_not_concatenated():
+    """No ``batch.concat`` span where an aggregate gets a single batch."""
+    sess = TpuSession({**BASE_CONF,
+                       "spark.rapids.tpu.trace.enabled": "true"})
+    _q(sess).collect()
+    assert "batch.concat" not in {r.name for r in sess.last_trace}
+
+
+#: the action's own blocks and the span each hangs under
+ACTION_BLOCKS = {"query.prepare": "query", "query.cleanup": "query",
+                 "query.metrics": "query", "query.schema": "query",
+                 "action.download_dispatch": "action"}
+
+
+@pytest.mark.parametrize("entry", ["collect", "served"])
+def test_the_actions_own_work_has_names(entry, tpch):
+    """Every block of ``_run_partitions`` and ``_collect`` outside the
+    execs runs under a child of ``query`` or ``action``, by name; what is
+    left to the two as self time is small."""
+    sess = TpuSession(TPCH_CONF)
+    df = _tpch_query(1, sess, tpch)
+    df.collect()                                        # warm
+    if entry == "collect":
+        df.collect()
+        records = sess.last_trace
+    else:
+        handle = sess.submit(df)
+        handle.result(timeout=300)
+        records = tracing.TRACER.since(0, query_id=handle.query_id)
+    root = _assert_one_tree(records)
+    by_id = {r.span_id: r for r in records}
+    blocks = [r for r in records if r.name in ACTION_BLOCKS]
+    assert {r.name for r in blocks} == set(ACTION_BLOCKS)
+    for r in blocks:
+        assert by_id[r.parent_id].name == ACTION_BLOCKS[r.name]
+        assert r.cat == "action"
+    (metrics,) = [r for r in blocks if r.name == "query.metrics"]
+    assert metrics.args["execs"] == len(list(_iter_execs(sess.last_plan)))
+    action = next(r for r in records if r.name == "action")
+    if entry == "collect":
+        # the two containers keep what a span's own bookkeeping costs
+        assert root.self_ns + action.self_ns < 0.2 * root.dur_ns
+
+
 def test_scan_cache_latch_is_a_span(monkeypatch):
     """A query latched behind another's upload of the same table records
     the wait; the builder records whether its batch was kept."""
@@ -422,6 +619,69 @@ def test_tracing_off_costs_one_bool_read(monkeypatch):
     monkeypatch.setattr(pc._tracing, "TRACER", counting)
     assert prog(2) == 3
     assert CountingTracer.reads == 1
+
+
+def _leaf_site(site, lineitem_dir):
+    """One new span site, driven alone: a callable that passes it once."""
+    import pyarrow.parquet as pq
+    from spark_rapids_tpu.columnar.batch import DeviceBatch
+    from spark_rapids_tpu.columnar.dtypes import Schema
+    from spark_rapids_tpu.execs.tpu_execs import concat_device_batches
+    from spark_rapids_tpu.io import parquet as io_parquet
+    from spark_rapids_tpu.io.datasource import PartitionedFile
+    from spark_rapids_tpu.io.parquet_pages import read_dict_column
+    path = lineitem_dir + "/part-0.parquet"
+    if site == "chunk_decode":
+        pf = pq.ParquetFile(path)
+        ci = pf.schema_arrow.names.index("l_quantity")
+        return lambda: read_dict_column(path, pf.metadata, 0, ci,
+                                        pa.float64(), want_runs=True)
+    if site in ("file_tables_dict", "file_tables_plain"):
+        schema = Schema.from_pa(pq.read_schema(path))
+        return lambda: list(io_parquet._iter_file_tables(
+            PartitionedFile(path), schema, Schema([]), (), 1 << 20, 1 << 31,
+            device_dict=site == "file_tables_dict", device_rle=True))
+    table = _table(64)
+    if site == "from_arrow":
+        return lambda: DeviceBatch.from_arrow(table)
+    halves = [DeviceBatch.from_arrow(table.slice(0, 32)),
+              DeviceBatch.from_arrow(table.slice(32))]
+    return lambda: concat_device_batches(halves, halves[0].schema)
+
+
+@pytest.mark.parametrize("site", ["chunk_decode", "file_tables_dict",
+                                  "file_tables_plain", "from_arrow",
+                                  "concat"])
+def test_tracing_off_costs_one_bool_read_at_a_leaf_site(site, lineitem_dir,
+                                                        monkeypatch):
+    """Each new site, off: one read of the tracer's flag for each span it
+    would have opened, no args dict, nothing in the ring."""
+
+    class CountingTracer(tracing.Tracer):
+        reads = 0
+
+        @property
+        def on(self):
+            CountingTracer.reads += 1
+            return self._on
+
+        @on.setter
+        def on(self, value):
+            self._on = value
+
+    call = _leaf_site(site, lineitem_dir)
+    traced = tracing.Tracer(capacity=4096)
+    monkeypatch.setattr(tracing, "TRACER", traced)
+    with traced.activate():
+        call()
+    spans = traced.since(0)
+    assert spans and all(r.args is None or r.args for r in spans)
+    counting = CountingTracer(capacity=16)
+    monkeypatch.setattr(tracing, "TRACER", counting)
+    CountingTracer.reads = 0
+    call()
+    assert CountingTracer.reads == len(spans)
+    assert counting.since(0) == []
 
 
 def test_ring_counts_what_it_dropped():
