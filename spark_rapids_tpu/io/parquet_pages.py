@@ -99,131 +99,91 @@ class _Thrift:
 
 
 # ------------------------------------------------------------- RLE/bit-packed
-def _unpack_bits(buf: np.ndarray, bit_width: int, n: int) -> np.ndarray:
-    """LSB-first bit-packed values -> int32 (vectorized)."""
-    bits = np.unpackbits(buf, bitorder="little")[: n * bit_width]
-    weights = (1 << np.arange(bit_width, dtype=np.int64))
-    return (bits.reshape(n, bit_width) @ weights).astype(np.int32)
-
-
-def rle_bp_decode(buf: memoryview, bit_width: int, count: int) -> np.ndarray:
-    """Parquet RLE/bit-packed hybrid -> int32[count]."""
-    out = np.empty(count, np.int32)
-    if bit_width == 0:
-        out[:] = 0
-        return out
-    th = _Thrift(buf)
-    got = 0
-    byte_w = (bit_width + 7) // 8
-    while got < count:
-        header = th.varint()
-        if header & 1:                      # bit-packed groups of 8
-            n = (header >> 1) * 8
-            nbytes = n * bit_width // 8
-            raw = np.frombuffer(th.buf[th.pos:th.pos + nbytes], np.uint8)
-            th.pos += nbytes
-            vals = _unpack_bits(raw, bit_width, n)
-            take = min(n, count - got)
-            out[got:got + take] = vals[:take]
-            got += take
-        else:                               # RLE run
-            run = header >> 1
-            raw = bytes(th.buf[th.pos:th.pos + byte_w]) + b"\0" * (4 - byte_w)
-            th.pos += byte_w
-            value = int(np.frombuffer(raw, "<u4")[0])
-            take = min(run, count - got)
-            out[got:got + take] = value
-            got += take
+def _unpack(stream: bytes, bit_width: int, n: int) -> np.ndarray:
+    """``n`` (a multiple of 8) LSB-first bit-packed values -> uint32[n].
+    ``stream`` holds the n // 8 groups of ``bit_width`` bytes each, then 8
+    bytes of padding. Value j of every group starts at the same bit of its
+    group, so each of the eight is one strided read of an unaligned word, a
+    shift and a mask over all groups: a 4-byte window holds shift (<= 7) +
+    width up to width 25, wider values take an 8-byte one."""
+    out = np.empty(n, np.uint32)
+    word = np.dtype("<u4") if bit_width <= 25 else np.dtype("<u8")
+    mask = (1 << bit_width) - 1
+    for j in range(8):
+        bit = j * bit_width
+        w = np.ndarray((n // 8,), word, buffer=stream, offset=bit >> 3,
+                       strides=(bit_width,))
+        out[j::8] = (w >> (bit & 7)) & mask
     return out
 
 
-def rle_bp_runs(buf: memoryview, bit_width: int,
-                count: int) -> Tuple[np.ndarray, np.ndarray]:
-    """Parquet RLE/bit-packed hybrid -> (run values int32, run lengths
-    int64) WITHOUT host expansion: an RLE run contributes one (value,
-    length) pair whatever its length, bit-packed groups contribute their
-    literal values with length 1. RLE-dominant streams stay tiny; callers
-    compare the run count against the row count to decide whether the runs
-    (not the expanded indices) should cross the host link
-    (columnar/encoding.expand_ree_device does the expansion in HBM)."""
-    if count == 0:
-        return np.zeros(0, np.int32), np.zeros(0, np.int64)
+def rle_bp_decode(buf: memoryview, bit_width: int,
+                  out: np.ndarray) -> Tuple[int, int]:
+    """Parquet RLE/bit-packed hybrid -> ``out`` (int32; its length is the
+    value count), in place. One walk of the headers: an RLE run is a slice
+    assignment; bit-packed groups are only located, since their bytes end to
+    end are one bitstream (a group of 8 values takes exactly ``bit_width``
+    bytes), then unpacked in one call and written in order to the rows the
+    runs left (only the last group may be cut by the count). Returns (values
+    unpacked from bit-packed groups, values filled from RLE runs)."""
+    count = len(out)
     if bit_width == 0:
-        return np.zeros(1, np.int32), np.array([count], np.int64)
-    vals_parts: List[np.ndarray] = []
-    len_parts: List[np.ndarray] = []
-    th = _Thrift(buf)
-    got = 0
+        out[:] = 0
+        return 0, count
+    rows = out.view(np.uint32)
     byte_w = (bit_width + 7) // 8
+    pos = got = rle = 0
+    packed: List[memoryview] = []
+    runs: List[Tuple[int, int]] = []        # (first row, rows) of RLE runs
     while got < count:
-        header = th.varint()
+        header = buf[pos]
+        if header & 0x80:                   # a varint of more than a byte
+            th = _Thrift(buf, pos)
+            header = th.varint()
+            pos = th.pos
+        else:
+            pos += 1
         if header & 1:                      # bit-packed groups of 8
-            n = (header >> 1) * 8
-            nbytes = n * bit_width // 8
-            raw = np.frombuffer(th.buf[th.pos:th.pos + nbytes], np.uint8)
-            th.pos += nbytes
-            vals = _unpack_bits(raw, bit_width, n)
-            take = min(n, count - got)
-            vals_parts.append(vals[:take])
-            len_parts.append(np.ones(take, np.int64))
-            got += take
+            nbytes = (header >> 1) * bit_width
+            packed.append(buf[pos:pos + nbytes])
+            pos += nbytes
+            got += (header >> 1) * 8
         else:                               # RLE run
-            run = header >> 1
-            raw = bytes(th.buf[th.pos:th.pos + byte_w]) + b"\0" * (4 - byte_w)
-            th.pos += byte_w
-            value = int(np.frombuffer(raw, "<u4")[0])
-            take = min(run, count - got)
-            vals_parts.append(np.array([value], np.int32))
-            len_parts.append(np.array([take], np.int64))
+            take = min(header >> 1, count - got)
+            rows[got:got + take] = int.from_bytes(buf[pos:pos + byte_w],
+                                                  "little")
+            pos += byte_w
+            runs.append((got, take))
+            rle += take
             got += take
-    return (np.concatenate(vals_parts).astype(np.int32),
-            np.concatenate(len_parts))
+    if packed:
+        stream = b"".join(packed)
+        values = _unpack(stream + bytes(8), bit_width,
+                         len(stream) * 8 // bit_width)
+        row = first = 0
+        for start, n in runs + [(count, 0)]:
+            if start > row:
+                rows[row:start] = values[first:first + start - row]
+                first += start - row
+            row = start + n
+    return count - rle, rle
 
 
-def merge_runs(values: np.ndarray,
-               lengths: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    """Coalesce adjacent equal-valued runs (page boundaries split runs;
-    bit-packed groups emit length-1 runs that often repeat). Vectorized."""
-    if len(values) < 2:
-        return values, lengths
-    starts = np.flatnonzero(
-        np.concatenate([[True], values[1:] != values[:-1]]))
-    csum = np.concatenate([[0], np.cumsum(lengths)])
-    ends = np.concatenate([starts[1:], [len(values)]])
-    return values[starts], csum[ends] - csum[starts]
+def run_count(idx: np.ndarray) -> int:
+    """Runs of equal adjacent indices: what the stream would be as
+    run-length pairs, page boundaries merged."""
+    if len(idx) == 0:
+        return 0
+    return 1 + int(np.count_nonzero(idx[1:] != idx[:-1]))
+
+
+def index_runs(idx: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """(int32 run ends, run values) of the runs ``run_count`` counts."""
+    starts = np.flatnonzero(np.concatenate([[True], idx[1:] != idx[:-1]]))
+    return np.append(starts[1:], len(idx)).astype(np.int32), idx[starts]
 
 
 # ------------------------------------------------------------- chunk decode
-class _ChunkPages:
-    """One column chunk parsed into a dictionary-encoded prefix (kept as
-    RUNS — no host expansion) plus an optional PLAIN tail (the writer's
-    mid-chunk dictionary fallback; only the tail decodes on host)."""
-
-    def __init__(self, dictionary: np.ndarray,
-                 runs: Tuple[np.ndarray, np.ndarray],
-                 prefix_defs: Optional[np.ndarray], prefix_rows: int,
-                 tail_values: Optional[np.ndarray],
-                 tail_defs: Optional[np.ndarray], pages: int = 0):
-        self.dictionary = dictionary
-        self.runs = runs                  # (values, lengths) over DEFINED rows
-        self.prefix_defs = prefix_defs    # bool[prefix_rows] or None (no nulls)
-        self.prefix_rows = prefix_rows
-        self.tail_values = tail_values    # defined PLAIN values or None
-        self.tail_defs = tail_defs        # bool[tail_rows] or None
-        self.pages = pages                # dictionary + data pages parsed
-
-    def prefix_indices(self) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-        """Expand the run form to per-row indices (+validity) — the
-        dictionary-index representation when runs are not worth keeping."""
-        vals, lens = self.runs
-        idx = np.repeat(vals, lens).astype(np.int32)
-        if self.prefix_defs is None:
-            return idx, None
-        full = np.zeros(self.prefix_rows, np.int32)
-        full[self.prefix_defs] = idx
-        return full, self.prefix_defs
-
-
 def _decompress(codec: str, raw: memoryview, usize: int) -> memoryview:
     if codec == "UNCOMPRESSED":
         return raw
@@ -235,27 +195,124 @@ def _decompress(codec: str, raw: memoryview, usize: int) -> memoryview:
     return memoryview(out)
 
 
+class _DataPage:
+    """A data page's header fields; its body stays unread until ``open``."""
+
+    def __init__(self, body: int, csize: int, usize: int, nv: int,
+                 n_nulls: Optional[int] = None, dlen: int = 0,
+                 compressed: bool = True):
+        self.body, self.csize, self.usize, self.nv = body, csize, usize, nv
+        self.n_nulls = n_nulls            # None: a V1 page
+        self.dlen, self.compressed = dlen, compressed
+
+    def open(self, data: memoryview, codec: str,
+             defs: Optional[np.ndarray]) -> Tuple[memoryview, int]:
+        """Decode the definition levels into ``defs`` (int32[nv]; None: the
+        column has none); (the values' bytes, the count of defined values)."""
+        raw = data[self.body:self.body + self.csize]
+        if self.n_nulls is None:                    # V1: levels inside
+            page = _decompress(codec, raw, self.usize)
+            if defs is None:
+                return page, self.nv
+            dlen = int.from_bytes(page[:4], "little")
+            rle_bp_decode(page[4:4 + dlen], 1, defs)
+            return page[4 + dlen:], int(np.count_nonzero(defs))
+        vals = raw[self.dlen:]                      # V2: levels outside
+        if self.compressed:
+            vals = _decompress(codec, vals, self.usize - self.dlen)
+        if defs is not None:
+            if self.dlen:
+                rle_bp_decode(raw[:self.dlen], 1, defs)
+            else:
+                defs[:] = 1
+        return vals, self.nv - self.n_nulls
+
+
+class _ChunkPages:
+    """One column chunk: the dictionary and the dictionary-encoded prefix's
+    indices, decoded into one array; the PLAIN tail (the writer's mid-chunk
+    dictionary fallback) only located, and read by ``read_tail`` for a chunk
+    that is kept."""
+
+    def __init__(self, data: memoryview, codec: str, np_t, max_def: int,
+                 dict_page: Tuple[int, int, int, int],
+                 prefix: List[_DataPage], tail: List[_DataPage], pages: int):
+        self.data, self.codec, self.np_t = data, codec, np_t
+        self.max_def = max_def
+        self.tail = tail                  # PLAIN pages, unread
+        self.pages = pages                # headers parsed
+        self.pages_decompressed = 1       # bodies opened: the dictionary's
+        self.literal_values = self.rle_values = 0
+        body, csize, usize, k = dict_page
+        self.dictionary = np.frombuffer(
+            _decompress(codec, data[body:body + csize], usize), np_t, count=k)
+        self.prefix_rows = sum(p.nv for p in prefix)
+        idx = np.empty(self.prefix_rows, np.int32)
+        defs = np.empty(self.prefix_rows, np.int32) if max_def > 0 else None
+        row = n_def = 0
+        for p in prefix:
+            vals, n = self._open(p, None if defs is None
+                                 else defs[row:row + p.nv])
+            lit, rle = rle_bp_decode(vals[1:], vals[0], idx[n_def:n_def + n])
+            self.literal_values += lit
+            self.rle_values += rle
+            row += p.nv
+            n_def += n
+        self.indices = idx[:n_def]        # over the DEFINED rows
+        self.prefix_defs = (defs.astype(bool)   # None: no nulls
+                            if defs is not None and not bool(defs.all())
+                            else None)
+
+    def _open(self, page: _DataPage,
+              defs: Optional[np.ndarray]) -> Tuple[memoryview, int]:
+        self.pages_decompressed += 1
+        return page.open(self.data, self.codec, defs)
+
+    def prefix_indices(self) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+        """Per-row indices (+validity; a null row points at slot 0)."""
+        if self.prefix_defs is None:
+            return self.indices, None
+        full = np.zeros(self.prefix_rows, np.int32)
+        full[self.prefix_defs] = self.indices
+        return full, self.prefix_defs
+
+    def read_tail(self, num_values: int) -> Tuple[
+            Optional[np.ndarray], Optional[np.ndarray]]:
+        """(defined PLAIN values, bool validity or None) of the tail; None
+        values for inconsistent counts."""
+        val_parts: List[np.ndarray] = []
+        def_parts: List[np.ndarray] = []
+        for p in self.tail:
+            defs = np.empty(p.nv, np.int32) if self.max_def > 0 else None
+            vals, n = self._open(p, defs)
+            val_parts.append(np.frombuffer(vals, self.np_t, count=n))
+            def_parts.append(np.ones(p.nv, np.int32) if defs is None
+                             else defs)
+        values = np.concatenate(val_parts)
+        tdefs = np.concatenate(def_parts)
+        if bool(tdefs.all()):
+            if len(values) != num_values - self.prefix_rows:
+                return None, None         # inconsistent counts: bail
+            return values, None
+        return values, tdefs.astype(bool)
+
+
 def decode_dict_chunk(data: memoryview, codec: str, phys: str,
                       num_values: int, max_def: int) -> Optional[_ChunkPages]:
-    """Parse one column chunk's pages. Handles the mixed-encoding chunk
-    (dictionary-encoded prefix, PLAIN fallback tail once the dictionary
-    overflowed): the prefix stays encoded as runs, only the PLAIN tail is
-    decoded. Returns None for layouts out of scope (no dictionary page at
-    all, dictionary pages after the PLAIN fallback, nested columns) —
-    caller reads the column through pyarrow instead."""
+    """Parse one column chunk's page headers, then decode the dictionary
+    and the dictionary-encoded prefix's indices; a PLAIN tail (the writer's
+    fallback once the dictionary overflowed) is located, not read. Returns
+    None for layouts out of scope (no dictionary page at all, dictionary
+    pages after the PLAIN fallback, nested columns) — caller reads the
+    column through pyarrow instead."""
     np_t = _PHYS_NP.get(phys)
     if np_t is None:
         return None
     pos = 0
-    dictionary: Optional[np.ndarray] = None
-    run_val_parts: List[np.ndarray] = []
-    run_len_parts: List[np.ndarray] = []
-    def_parts: List[np.ndarray] = []
-    tail_val_parts: List[np.ndarray] = []
-    tail_def_parts: List[np.ndarray] = []
-    prefix_rows = 0
+    dict_page: Optional[Tuple[int, int, int, int]] = None
+    prefix: List[_DataPage] = []
+    tail: List[_DataPage] = []
     seen = pages = 0
-    in_tail = False
     while seen < num_values and pos < len(data):
         th = _Thrift(data, pos)
         hdr = th.read_struct()
@@ -268,97 +325,36 @@ def decode_dict_chunk(data: memoryview, codec: str, phys: str,
             dh = hdr.get(7, {})
             if dh.get(2, _ENC_PLAIN) not in (_ENC_PLAIN, _ENC_PLAIN_DICT):
                 return None
-            page = _decompress(codec, data[body:body + csize], usize)
-            dictionary = np.frombuffer(page, np_t, count=dh.get(1, -1))
+            dict_page = (body, csize, usize, dh.get(1, -1))
             continue
         if ptype == _DATA_PAGE:
             dh = hdr.get(5, {})
-            nv = dh.get(1, 0)
             enc = dh.get(2)
-            if enc not in (_ENC_PLAIN_DICT, _ENC_RLE_DICT, _ENC_PLAIN):
-                return None
-            page = _decompress(codec, data[body:body + csize], usize)
-            p = 0
-            if max_def > 0:
-                dlen = int(np.frombuffer(page[p:p + 4], "<u4")[0])
-                p += 4
-                defs = rle_bp_decode(page[p:p + dlen], 1, nv)
-                p += dlen
-            else:
-                defs = np.ones(nv, np.int32)
-            n_def = int(defs.sum())
-            if enc == _ENC_PLAIN:
-                in_tail = True
-                tail_val_parts.append(
-                    np.frombuffer(page, np_t, count=n_def, offset=p))
-                tail_def_parts.append(defs)
-            else:
-                if in_tail:           # dict page after the PLAIN fallback:
-                    return None       # not the writer layout we model
-                bw = page[p]
-                p += 1
-                rv, rl = rle_bp_runs(page[p:], int(bw), n_def)
-                run_val_parts.append(rv)
-                run_len_parts.append(rl)
-                def_parts.append(defs)
-                prefix_rows += nv
-            seen += nv
-            continue
-        if ptype == _DATA_PAGE_V2:
+            page = _DataPage(body, csize, usize, dh.get(1, 0))
+        elif ptype == _DATA_PAGE_V2:
             dh = hdr.get(8, {})
-            nv, n_nulls = dh.get(1, 0), dh.get(2, 0)
             enc = dh.get(4)
-            if enc not in (_ENC_PLAIN_DICT, _ENC_RLE_DICT, _ENC_PLAIN):
-                return None
-            dlen, rlen = dh.get(5, 0), dh.get(6, 0)
-            if rlen:
-                return None               # nested: out of scope
-            levels = data[body:body + dlen]
-            vals_raw = data[body + dlen:body + csize]
-            compressed = dh.get(7, True)
-            vals = (_decompress(codec, vals_raw, usize - dlen)
-                    if compressed else vals_raw)
-            if max_def > 0 and dlen:
-                defs = rle_bp_decode(levels, 1, nv)
-            else:
-                defs = np.ones(nv, np.int32)
-            if enc == _ENC_PLAIN:
-                in_tail = True
-                tail_val_parts.append(
-                    np.frombuffer(vals, np_t, count=nv - n_nulls))
-                tail_def_parts.append(defs)
-            else:
-                if in_tail:
-                    return None
-                bw = vals[0]
-                rv, rl = rle_bp_runs(vals[1:], int(bw), nv - n_nulls)
-                run_val_parts.append(rv)
-                run_len_parts.append(rl)
-                def_parts.append(defs)
-                prefix_rows += nv
-            seen += nv
-            continue
-        # index pages etc.: skip
-    if dictionary is None or seen < num_values or prefix_rows == 0:
+            if dh.get(6, 0):
+                return None               # repetition levels: nested
+            page = _DataPage(body, csize, usize, dh.get(1, 0),
+                             n_nulls=dh.get(2, 0), dlen=dh.get(5, 0),
+                             compressed=dh.get(7, True))
+        else:
+            continue                      # index pages etc.: skip
+        if enc not in (_ENC_PLAIN_DICT, _ENC_RLE_DICT, _ENC_PLAIN):
+            return None
+        if enc == _ENC_PLAIN:
+            tail.append(page)
+        elif tail:                        # dict page after the PLAIN
+            return None                   # fallback: not the writer layout
+        else:
+            prefix.append(page)
+        seen += page.nv
+    if (dict_page is None or seen < num_values
+            or not any(p.nv for p in prefix)):
         return None
-    rvals, rlens = merge_runs(
-        np.concatenate(run_val_parts) if run_val_parts
-        else np.zeros(0, np.int32),
-        np.concatenate(run_len_parts) if run_len_parts
-        else np.zeros(0, np.int64))
-    defs = np.concatenate(def_parts) if def_parts else np.ones(0, np.int32)
-    prefix_defs = None
-    if max_def > 0 and not bool(defs.all()):
-        prefix_defs = defs.astype(bool)
-    tail_values = tail_defs = None
-    if tail_val_parts:
-        tail_values = np.concatenate(tail_val_parts)
-        tdefs = np.concatenate(tail_def_parts)
-        tail_defs = tdefs.astype(bool) if not bool(tdefs.all()) else None
-        if tail_defs is None and len(tail_values) != num_values - prefix_rows:
-            return None                   # inconsistent counts: bail
-    return _ChunkPages(dictionary, (rvals, rlens), prefix_defs, prefix_rows,
-                       tail_values, tail_defs, pages)
+    return _ChunkPages(data, codec, np_t, max_def, dict_page, prefix, tail,
+                       pages)
 
 
 # ------------------------------------------------------------- file surface
@@ -386,10 +382,10 @@ def read_dict_column(path: str, pf_metadata, rg: int, col_idx: int,
     smaller than the decoded column (per-column fallback — shipping an
     encoding that does not shrink the link is pure overhead). One
     ``scan.chunk_decode`` span: file read, page headers, decompression,
-    run parse and Arrow assembly; ``form`` says what came of it."""
+    index decode and Arrow assembly; ``form`` says what came of it."""
     col = pf_metadata.row_group(rg).column(col_idx)
     with _tracing.span("scan.chunk_decode", _tracing.LAYER_TRANSFER) as sp:
-        read, pages = _read_chunk(path, col,
+        read, chunk = _read_chunk(path, col,
                                   pf_metadata.schema.column(col_idx),
                                   arrow_type, want_runs)
         if sp is not None:
@@ -398,7 +394,11 @@ def read_dict_column(path: str, pf_metadata, rg: int, col_idx: int,
                     compressed_bytes=col.total_compressed_size,
                     decoded_bytes=(col.num_values * np.dtype(width).itemsize
                                    if width else 0),
-                    pages=pages,
+                    pages=chunk.pages if chunk else 0,
+                    pages_decompressed=(chunk.pages_decompressed
+                                        if chunk else 0),
+                    literal_values=chunk.literal_values if chunk else 0,
+                    rle_values=chunk.rle_values if chunk else 0,
                     form=("declined" if read is None
                           else "mixed" if read.tail is not None
                           else "ree" if pa.types.is_run_end_encoded(
@@ -407,17 +407,21 @@ def read_dict_column(path: str, pf_metadata, rg: int, col_idx: int,
 
 
 def _read_chunk(path: str, col, sc, arrow_type: pa.DataType,
-                want_runs: bool) -> Tuple[Optional[ColumnRead], int]:
-    """(the column read or None, pages parsed) of one column chunk."""
+                want_runs: bool) -> Tuple[Optional[ColumnRead],
+                                          Optional[_ChunkPages]]:
+    """(the column read or None, the chunk as far as it was decoded, or
+    None when it was not) of one column chunk. The form is chosen from the
+    dictionary and the prefix's indices alone: a declined chunk's PLAIN
+    tail is never opened."""
     if sc.max_repetition_level != 0 or sc.max_definition_level > 1:
-        return None, 0
+        return None, None
     if col.dictionary_page_offset is None:
-        return None, 0
+        return None, None
     try:
         pa.Codec(col.compression.lower())
     except (ValueError, NotImplementedError):
         if col.compression != "UNCOMPRESSED":
-            return None, 0
+            return None, None
     with _tracing.span("scan.chunk_io", _tracing.LAYER_TRANSFER) as sp:
         with open(path, "rb") as f:
             f.seek(col.dictionary_page_offset)
@@ -428,20 +432,35 @@ def _read_chunk(path: str, col, sc, arrow_type: pa.DataType,
         chunk = decode_dict_chunk(data, col.compression, col.physical_type,
                                   col.num_values, sc.max_definition_level)
     except Exception:       # malformed/unexpected layout: decoded fallback
-        return None, 0
+        return None, None
     if chunk is None:
-        return None, 0
+        return None, None
     k = len(chunk.dictionary)
     elem = chunk.dictionary.dtype.itemsize
     n_prefix = chunk.prefix_rows
     idx_w = 1 if k <= 127 else 2 if k <= 0x7FFF else 4
     dict_bytes = n_prefix * idx_w + k * elem
-    rvals, rlens = chunk.runs
-    ree_bytes = len(rvals) * (4 + elem)
+    ree_bytes = run_count(chunk.indices) * (4 + elem)
     decoded_bytes = n_prefix * elem
     if min(dict_bytes, ree_bytes) >= decoded_bytes:
         # no encoding survives: decoded upload is smaller
-        return None, chunk.pages
+        return None, chunk
+    tail = None
+    if chunk.tail:
+        try:
+            tail_values, tail_defs = chunk.read_tail(col.num_values)
+        except Exception:   # malformed tail: decoded fallback
+            return None, None
+        if tail_values is None:
+            return None, None
+        if tail_defs is None:
+            tail = pa.array(tail_values)
+        else:
+            full = np.zeros(len(tail_defs), tail_values.dtype)
+            full[tail_defs] = tail_values
+            tail = pa.array(full, mask=~tail_defs)
+        if not tail.type.equals(arrow_type):
+            tail = tail.cast(arrow_type)
     dict_vals = pa.array(chunk.dictionary)
     if not dict_vals.type.equals(arrow_type):
         dict_vals = dict_vals.cast(arrow_type)
@@ -449,9 +468,10 @@ def _read_chunk(path: str, col, sc, arrow_type: pa.DataType,
         # RLE-dominant, null-free: ship the runs themselves. Values are the
         # per-run DECODED value (one dictionary lookup per run — k-sized
         # host work); run ends are the int32 cumulative lengths.
-        ends = pa.array(np.cumsum(rlens).astype(np.int32), type=pa.int32())
-        run_values = dict_vals.take(pa.array(rvals.astype(np.int64)))
-        prefix: pa.Array = pa.RunEndEncodedArray.from_arrays(ends, run_values)
+        ends, run_idx = index_runs(chunk.indices)
+        run_values = dict_vals.take(pa.array(run_idx.astype(np.int64)))
+        prefix: pa.Array = pa.RunEndEncodedArray.from_arrays(
+            pa.array(ends, type=pa.int32()), run_values)
     else:
         indices, validity = chunk.prefix_indices()
         idx_t = (pa.int8() if k <= 127 else
@@ -462,14 +482,4 @@ def _read_chunk(path: str, col, sc, arrow_type: pa.DataType,
         else:
             idx = pa.array(indices, type=idx_t, safe=False)
         prefix = pa.DictionaryArray.from_arrays(idx, dict_vals)
-    tail = None
-    if chunk.tail_values is not None:
-        if chunk.tail_defs is None:
-            tail = pa.array(chunk.tail_values)
-        else:
-            full = np.zeros(len(chunk.tail_defs), chunk.tail_values.dtype)
-            full[chunk.tail_defs] = chunk.tail_values
-            tail = pa.array(full, mask=~chunk.tail_defs)
-        if not tail.type.equals(arrow_type):
-            tail = tail.cast(arrow_type)
-    return ColumnRead(prefix, tail), chunk.pages
+    return ColumnRead(prefix, tail), chunk

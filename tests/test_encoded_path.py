@@ -21,10 +21,11 @@ from spark_rapids_tpu.execs.base import ExecContext
 from spark_rapids_tpu.execs.tpu_execs import concat_device_batches
 from spark_rapids_tpu.io.datasource import PartitionedFile
 from spark_rapids_tpu.io.parquet import TpuParquetScanExec
-from spark_rapids_tpu.io.parquet_pages import (merge_runs, read_dict_column,
-                                               rle_bp_runs)
+from spark_rapids_tpu.io.parquet_pages import (index_runs, read_dict_column,
+                                               rle_bp_decode, run_count)
 from spark_rapids_tpu.testing import assert_tables_equal
 from spark_rapids_tpu.utils import metrics as um
+from spark_rapids_tpu.utils import tracing
 
 
 def _write(table: pa.Table, tmp_path, name="t.parquet", **kw) -> str:
@@ -124,36 +125,187 @@ def test_upload_metrics_count_encoded_vs_decoded_bytes():
     assert 0 < enc < dec          # the encoding shrank the link
 
 
-# ------------------------------------------------------------- runs parsing
-def test_rle_bp_runs_matches_decode_and_merges():
-    from spark_rapids_tpu.io.parquet_pages import rle_bp_decode
+# ------------------------------------------------------- the hybrid decoder
+def _varint(v):
+    out = b""
+    while True:
+        b7 = v & 0x7F
+        v >>= 7
+        out += bytes([b7 | (0x80 if v else 0)])
+        if not v:
+            return out
+
+
+def _pack(values, bw):
+    """LSB-first bit packing, one bit at a time."""
+    bits = [(v >> i) & 1 for v in values for i in range(bw)]
+    return bytes(sum(bits[j + i] << i for i in range(8) if j + i < len(bits))
+                 for j in range(0, len(bits), 8))
+
+
+def _hybrid(segments, bw):
+    """Encode ("rle", value, n) / ("packed", values) segments; a packed
+    segment's values are padded with zeros to whole groups of 8."""
+    out = b""
+    for seg in segments:
+        if seg[0] == "rle":
+            out += _varint(seg[2] << 1) + seg[1].to_bytes((bw + 7) // 8,
+                                                          "little")
+        else:
+            vals = list(seg[1]) + [0] * (-len(seg[1]) % 8)
+            out += _varint((len(vals) // 8) << 1 | 1) + _pack(vals, bw)
+    return out
+
+
+def _reference_decode(buf, bw, count):
+    """The RLE/bit-packed hybrid read one bit at a time (the spec's loop)."""
+    out, pos = [], 0
+    while len(out) < count:
+        header = shift = 0
+        while True:
+            b = buf[pos]
+            pos += 1
+            header |= (b & 0x7F) << shift
+            shift += 7
+            if not b & 0x80:
+                break
+        if header & 1:
+            for i in range((header >> 1) * 8):
+                out.append(sum(((buf[pos + (i * bw + b) // 8]
+                                 >> ((i * bw + b) % 8)) & 1) << b
+                               for b in range(bw)))
+            pos += (header >> 1) * bw
+        else:
+            w = (bw + 7) // 8
+            out += [int.from_bytes(buf[pos:pos + w], "little")] * (header >> 1)
+            pos += w
+    return np.array(out[:count], np.uint32).view(np.int32)
+
+
+def _segments(shape, bw, rng):
+    top = 1 << bw
+    draw = lambda n: [int(v) for v in rng.integers(0, top, n)]  # noqa: E731
+    if shape == "rle":              # a 2-byte varint header among them
+        return [("rle", draw(1)[0], 300), ("rle", top - 1, 9),
+                ("rle", 0, 5)], 314
+    if shape == "packed":           # 70 groups: a 2-byte header
+        return [("packed", draw(560)), ("packed", [top - 1] * 8)], 568
+    if shape == "alternating":
+        return [("packed", draw(16)), ("rle", draw(1)[0], 11),
+                ("packed", draw(24)), ("rle", top - 1, 8),
+                ("packed", draw(8))], 67
+    if shape == "cut_group":        # the count ends inside the last group
+        return [("rle", draw(1)[0], 12), ("packed", draw(24))], 12 + 21
+    # cut_header: the count ends on a group boundary inside the last
+    # header, and is not the stream's whole length
+    return [("packed", draw(8)), ("rle", 1 % top, 3),
+            ("packed", draw(32))], 8 + 3 + 16
+
+
+@pytest.mark.parametrize("shape", ["rle", "packed", "alternating",
+                                   "cut_group", "cut_header"])
+@pytest.mark.parametrize("bw", [0, 1, 2, 3, 4, 6, 7, 8, 12, 16, 20, 25, 26,
+                                31, 32])
+def test_hybrid_decoder_matches_bit_at_a_time_reference(bw, shape):
+    rng = np.random.default_rng(bw * 7 + len(shape))
+    segments, count = _segments(shape, bw, rng)
+    buf = _hybrid(segments, bw)
+    expect = (np.zeros(count, np.int32) if bw == 0
+              else _reference_decode(buf, bw, count))
+    # decoded in place into a slice of a larger array: nothing beside it
+    # moves; through a view of a pyarrow buffer, as a decompressed page
+    # arrives (its bytes read as signed)
+    final = np.full(count + 6, -7, np.int32)
+    lit, rle = rle_bp_decode(memoryview(pa.py_buffer(buf)), bw,
+                             final[3:3 + count])
+    assert np.array_equal(final[3:3 + count], expect)
+    assert (final[:3] == -7).all() and (final[-3:] == -7).all()
+    rle_rows = min(count, sum(s[2] for s in segments if s[0] == "rle"))
+    assert (lit, rle) == ((0, count) if bw == 0 else (count - rle_rows,
+                                                      rle_rows))
+
+
+def test_hybrid_decoder_on_hand_built_stream_and_its_runs():
     # hand-built hybrid: RLE run of 7 x value 3, then a bit-packed group of
     # 8 (bit width 2), then RLE 5 x value 1
-    def varint(v):
-        out = b""
-        while True:
-            b7 = v & 0x7F
-            v >>= 7
-            out += bytes([b7 | (0x80 if v else 0)])
-            if not v:
-                return out
     bw = 2
-    packed_vals = [0, 1, 2, 3, 0, 1, 2, 3]
     packed = np.packbits(
-        np.array([[(v >> i) & 1 for i in range(bw)] for v in packed_vals],
+        np.array([[(v >> i) & 1 for i in range(bw)]
+                  for v in [0, 1, 2, 3, 0, 1, 2, 3]],
                  np.uint8).reshape(-1), bitorder="little").tobytes()
-    stream = (varint(7 << 1) + bytes([3])            # RLE 7 x 3
-              + varint((1 << 1) | 1) + packed        # bit-packed group of 8
-              + varint(5 << 1) + bytes([1]))         # RLE 5 x 1
-    buf = memoryview(stream)
-    count = 20
-    expanded = rle_bp_decode(buf, bw, count)
-    rv, rl = rle_bp_runs(buf, bw, count)
-    assert np.array_equal(np.repeat(rv, rl), expanded)
-    assert rl.sum() == count
-    mv, ml = merge_runs(np.array([3, 3, 1, 1, 1, 2], np.int32),
-                        np.array([2, 5, 1, 1, 3, 4], np.int64))
-    assert mv.tolist() == [3, 1, 2] and ml.tolist() == [7, 5, 4]
+    stream = (_varint(7 << 1) + bytes([3])          # RLE 7 x 3
+              + _varint((1 << 1) | 1) + packed      # bit-packed group of 8
+              + _varint(5 << 1) + bytes([1]))       # RLE 5 x 1
+    out = np.empty(20, np.int32)
+    assert rle_bp_decode(memoryview(stream), bw, out) == (8, 12)
+    assert out.tolist() == [3] * 7 + [0, 1, 2, 3] * 2 + [1] * 5
+    assert run_count(out) == 1 + 8 + 1      # the RLE runs, 8 literals
+    # the runs the `ree` form ships: page boundaries and length-1 runs merge
+    idx = np.repeat(np.array([3, 3, 1, 1, 1, 2], np.int32), [2, 5, 1, 1, 3, 4])
+    ends, vals = index_runs(idx)
+    assert vals.tolist() == [3, 1, 2] and ends.tolist() == [7, 12, 16]
+    assert ends.dtype == np.int32 and run_count(idx) == 3
+    assert run_count(idx[:0]) == 0
+
+
+def test_forms_and_values_unchanged_and_a_declined_chunk_stops_at_its_prefix(
+        tmp_path, monkeypatch):
+    """A pyarrow-written chunk of each form: each takes the form it took
+    before the decoder was rewritten, decodes equal to pyarrow's own read,
+    and a declined chunk's PLAIN tail is never opened."""
+    n = 40000
+    rng = np.random.default_rng(5)
+    hc_head = rng.integers(0, 1 << 40, n).astype(np.int64)
+    hc_head[:2000] = hc_head[0]         # repeated head keeps early pages dict
+    t = pa.table({
+        "dict": pa.array(rng.integers(1, 51, n).astype(np.float64)),
+        "nulls": pa.array(rng.integers(0, 9, n).astype(np.int64),
+                          mask=rng.uniform(size=n) < 0.1),
+        "sorted": pa.array(np.sort(rng.integers(0, 15, n)).astype(np.int64)),
+        "mixed": pa.array(hc_head),
+        "declined": pa.array(rng.integers(0, 1 << 40, n).astype(np.int64)),
+    })
+    path = _write(t, tmp_path, dictionary_pagesize_limit=2048,
+                  data_page_size=4096, row_group_size=n)
+    pf = pq.ParquetFile(path)
+    traced = tracing.Tracer(capacity=4096)
+    monkeypatch.setattr(tracing, "TRACER", traced)
+    got = {}
+    with traced.activate():
+        for ci, name in enumerate(t.column_names):
+            got[name] = read_dict_column(path, pf.metadata, 0, ci,
+                                         t.schema.field(name).type,
+                                         want_runs=True)
+    spans = {r.args["column"]: r.args for r in traced.since(0)
+             if r.name == "scan.chunk_decode"}
+    assert {c: a["form"] for c, a in spans.items()} == {
+        "dict": "dict", "nulls": "dict", "sorted": "ree", "mixed": "mixed",
+        "declined": "declined"}
+    assert got["declined"] is None
+    for name, r in got.items():
+        if r is None:
+            continue
+        prefix = (ce.ree_to_plain(r.prefix)
+                  if pa.types.is_run_end_encoded(r.prefix.type)
+                  else r.prefix.cast(t.schema.field(name).type))
+        whole = (prefix if r.tail is None
+                 else pa.concat_arrays([prefix, r.tail]))
+        assert whole.equals(t.column(name).combine_chunks()), name
+    for name, a in spans.items():
+        assert a["literal_values"] + a["rle_values"] > 0, name
+        if a["form"] in ("dict", "ree"):       # every page opened
+            assert a["pages_decompressed"] == a["pages"], name
+    assert spans["dict"]["literal_values"] + spans["dict"]["rle_values"] == n
+    assert spans["sorted"]["rle_values"] > spans["sorted"]["literal_values"]
+    assert spans["mixed"]["pages_decompressed"] == spans["mixed"]["pages"]
+    d = spans["declined"]
+    assert 2 <= d["pages_decompressed"] < d["pages"]
+    decompress = [r for r in traced.since(0) if r.name == "scan.decompress"]
+    assert len(decompress) == sum(a["pages_decompressed"]
+                                  for a in spans.values())
+    # and the whole scan reads what pyarrow reads
+    out, _ = _roundtrip(path, t)
+    assert out.equals(t)
 
 
 def test_scan_keeps_rle_dominant_column_as_runs(tmp_path):

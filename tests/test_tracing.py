@@ -464,7 +464,10 @@ def test_parquet_scan_leaves_its_host_pipeline(site, tpch, lineitem_dir,
         (io,) = [k for k in kids if k.name == "scan.chunk_io"]
         assert io.args["bytes"] == d.args["compressed_bytes"]
         pages = [k for k in kids if k.name == "scan.decompress"]
-        assert len(pages) == d.args["pages"]
+        # a declined chunk stops after its prefix: its PLAIN tail, if any,
+        # is never opened
+        assert len(pages) == d.args["pages_decompressed"] <= d.args["pages"]
+        assert d.args["literal_values"] + d.args["rle_values"] > 0
         assert all(k.args["compressed_bytes"] > 0 and k.args["bytes"] > 0
                    for k in pages)
     stages = [r for r in records if r.name == "upload.stage"]
