@@ -34,6 +34,7 @@ from spark_rapids_tpu.exprs.core import (ColV, EvalCtx, Expression,
                                          flat_len as _n_flat)
 from spark_rapids_tpu.ops import batch_kernels as bk
 from spark_rapids_tpu.ops import join as jk
+from spark_rapids_tpu.utils import tracing as _tracing
 
 
 def legal_broadcast_sides(how: str) -> List[int]:
@@ -48,6 +49,24 @@ def legal_broadcast_sides(how: str) -> List[int]:
     if how in ("inner", "right", "cross"):
         sides.append(0)
     return sides
+
+
+def _note_drained(span, batches: Sequence[DeviceBatch], **args) -> None:
+    """What a ``join.drain`` span counts: the batches drained, empty ones
+    included, and their live rows."""
+    span.note(batches=len(batches), rows=sum(b.num_rows for b in batches),
+              **args)
+
+
+def _drain(side: str, batches: Iterator[DeviceBatch]) -> List[DeviceBatch]:
+    """One side of a join pulled to its end, in one ``join.drain`` span:
+    the child's scans, stages, uploads and joins, which run inside it."""
+    with _tracing.span("join.drain", _tracing.LAYER_EXEC,
+                       {"side": side}) as span:
+        out = list(batches)
+        if span is not None:
+            _note_drained(span, out)
+    return out
 
 
 def _eval_keys(xp, colvs, capacity, smax, key_exprs) -> List[ColV]:
@@ -202,10 +221,20 @@ class TpuShuffledHashJoinExec(_HashJoinBase):
                                     self.left_keys + self.right_keys)
                if self.left_keys else None)
         if ooc is None:
-            yield from self._single_pass(ctx, list(left), list(right))
+            yield from self._single_pass(ctx, _drain("left", left),
+                                         _drain("right", right))
             return
-        mode, payload = ooc.stage_two(left, right, self.left_keys,
-                                      self.right_keys)
+        with _tracing.span("join.drain", _tracing.LAYER_EXEC,
+                           {"side": "both"}) as span:
+            mode, payload = ooc.stage_two(left, right, self.left_keys,
+                                          self.right_keys)
+            if span is not None:
+                span.note(mode=mode)
+                if mode == "inline":
+                    lbatches, rbatches = payload
+                    _note_drained(span, lbatches + rbatches,
+                                  left_batches=len(lbatches),
+                                  right_batches=len(rbatches))
         if mode == "inline":
             yield from self._single_pass(ctx, payload[0], payload[1])
             return

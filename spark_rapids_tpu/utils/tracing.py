@@ -322,7 +322,7 @@ class Tracer:
     slice without copying the whole ring.
     """
 
-    DEFAULT_CAPACITY = 65536
+    DEFAULT_CAPACITY = 262144
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY):
         self._lock = threading.Lock()
