@@ -81,12 +81,16 @@ class DeviceBatch:
           encoded form is RETAINED on the column (DeviceColumn.encoding) so
           downstream operators can work on the index domain.
         - pa.RunEndEncodedArray (RLE-dominant parquet chunks) ships as
-          (run_ends, per-run values) and expands in HBM with a searchsorted
-          gather (columnar/encoding.expand_ree_device).
+          (run_ends, per-run values), its run list padded to a power-of-two
+          bucket, and expands in HBM in the cached ``ree_expand`` program
+          (columnar/encoding.ree_expand_program: a scatter of the run ends, a
+          cumsum, one gather a buffer), one call a column.
 
         (Host-side re-encoding of plain columns was tried and cut: on the
         1-core bench rig np.unique staging cost exceeds the link saving.)"""
         from spark_rapids_tpu.columnar import encoding as ce
+        from spark_rapids_tpu.serving.program_cache import (
+            global_program_cache, named_jit)
         # three leaf spans under upload.stage: the host conversion, the
         # put, and the eager device-side decode (dispatches, not awaited)
         with _tracing.span("stage.host", _tracing.LAYER_TRANSFER) as sp:
@@ -101,6 +105,7 @@ class DeviceBatch:
             staged = []
             encoded = {}     # column index -> "string" | "fixed" | "ree"
             enc_meta = {}    # column index -> (token, unique) for dict columns
+            ree_runs = {}    # column index -> live runs of a "ree" column
             enc_bytes = 0    # bytes actually staged for the link
             dec_bytes = 0    # bytes the decoded forms would have staged
 
@@ -128,11 +133,16 @@ class DeviceBatch:
                                  if f.dtype is DType.DOUBLE and with_bits
                                  else None)
                         encoded[i] = "ree"
-                        staged.append((ends, rvalid, vd, vbits))
+                        ree_runs[i] = len(ends)
+                        # the encoding's bytes; the bucket pad below (fewer
+                        # entries than the live runs) is the program's
                         enc_bytes += _nb(ends, rvalid, vd, vbits)
                         dec_bytes += (n * vd.dtype.itemsize
                                       + (n * 8 if vbits is not None else 0)
                                       + _nb(rvalid))
+                        ends, (rvalid, vd, vbits) = ce.pad_runs(
+                            ends, cap, (rvalid, vd, vbits))
+                        staged.append((ends, rvalid, vd, vbits))
                         continue
                 if (isinstance(arr, pa.DictionaryArray)
                         and len(arr.dictionary) > 0):
@@ -205,18 +215,20 @@ class DeviceBatch:
             for i, (f, slot) in enumerate(zip(schema, up)):
                 enc = None
                 if encoded.get(i) == "ree":
-                    # HBM expansion of the RLE runs: searchsorted over the run
-                    # ends picks each row's run, one gather per buffer. The
+                    # HBM expansion of the RLE runs, one cached program call
+                    # a column, shared by every run list of the bucket. The
                     # decoded column exists ONLY on device.
                     ends, rv, vd, vbits = slot
-                    d, ridx = ce.expand_ree_device(jnp, ends, vd, cap)
-                    bits = (jnp.take(vbits, ridx, axis=0)
-                            if vbits is not None else None)
+                    key = ("ree_expand", vd.dtype.str, cap,
+                           int(ends.shape[0]), vbits is not None,
+                           rv is not None)
+                    prog = global_program_cache().get_or_build(
+                        key, lambda: named_jit("ree_expand",
+                                               ce.ree_expand_program(cap)))
+                    d, bits, v = prog(ends, np.int32(ree_runs[i]),
+                                      np.int32(n), vd, vbits, rv)
                     l = None
-                    v = (jnp.logical_and(jnp.take(rv, ridx, axis=0), alive)
-                         if rv is not None else None)
-                    nd += (5 + (vbits is not None)
-                           + 2 * (rv is not None))
+                    nd += 1
                 elif i in encoded:
                     # padded gather: index padding rows point at dict slot 0;
                     # their garbage values land beyond the live prefix
@@ -281,7 +293,9 @@ class DeviceBatch:
                 cols.append(DeviceColumn(f.dtype, d, validity, l, bits,
                                          encoding=enc))
             if sp is not None:
-                sp.note(columns=len(encoded), dispatches=nd)
+                sp.note(columns=len(encoded), dispatches=nd,
+                        ree_columns=len(ree_runs),
+                        ree_runs=sum(ree_runs.values()))
         return DeviceBatch(schema, tuple(cols), n)
 
     def sliced_buffers(self) -> List[Tuple]:

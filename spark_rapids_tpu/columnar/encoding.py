@@ -6,12 +6,15 @@ shipping decoded bytes over it
 measures order-of-magnitude effective-bandwidth gains from exactly this
 shape). Three cooperating pieces:
 
-- **Run-end-encoded staging** (`ree_staged`, `expand_ree_device`): a parquet
-  column chunk whose index stream is RLE-dominant uploads as (run_ends,
-  per-run values) pairs — often hundreds of bytes for millions of rows —
-  and expands in HBM with a jitted searchsorted gather, the TPU analog of
-  the reference's device-side decode (GpuParquetScan.scala:576). The host
-  never materializes the decoded column.
+- **Run-end-encoded staging** (`ree_staged`, `pad_runs`,
+  `ree_expand_program`): a
+  parquet column chunk whose index stream is RLE-dominant uploads as
+  (run_ends, per-run values) pairs — often hundreds of bytes for millions
+  of rows — and expands in HBM in one cached program per shape,
+  ``ree_expand`` (a scatter of the run ends, a cumsum, one gather a
+  buffer), the TPU analog of the reference's device-side decode
+  (GpuParquetScan.scala:576). The host never materializes the decoded
+  column.
 - **DictEncoding** (`DictEncoding`, `EncSpec`, flatten helpers): a device
   batch column that arrived dictionary-encoded KEEPS its narrow index
   vector and small dictionary alongside the decoded data, so downstream
@@ -59,7 +62,7 @@ def ree_staged(arr: "pa.RunEndEncodedArray") -> Tuple[np.ndarray, pa.Array]:
 
 def ree_to_plain(arr: "pa.RunEndEncodedArray") -> pa.Array:
     """Expand an REE array on HOST (CPU-engine / fallback paths only; the
-    device path expands in HBM via expand_ree_device)."""
+    device path expands in HBM in the ``ree_expand`` program)."""
     ends, vals = ree_staged(arr)
     if len(ends) == 0:
         return vals
@@ -68,15 +71,49 @@ def ree_to_plain(arr: "pa.RunEndEncodedArray") -> pa.Array:
     return vals.take(pa.array(take))
 
 
-def expand_ree_device(xp, run_ends, values, capacity: int):
-    """Jitted device expansion: row i takes values[j] for the first run end
-    > i (cumsum/searchsorted gather). Rows past the last run end (capacity
-    padding) clamp to the final run; their garbage lands beyond the live
-    prefix, which the batch's validity/alive mask already excludes."""
-    idx = xp.searchsorted(run_ends, xp.arange(capacity, dtype=np.int32),
-                          side="right")
-    idx = xp.minimum(idx, len(values) - 1).astype(np.int32)
-    return xp.take(values, idx, axis=0), idx
+def pad_runs(run_ends: np.ndarray, capacity: int,
+             per_run: Sequence[Optional[np.ndarray]]
+             ) -> Tuple[np.ndarray, List[Optional[np.ndarray]]]:
+    """Pad a staged run list to its power-of-two bucket (``dict_bucket``),
+    so that run lists of one bucket share one ``ree_expand`` program: pad
+    ends sit at ``capacity``, which the expansion's scatter drops, and pad
+    values are zeros (False) that no row reads."""
+    pad = dict_bucket(len(run_ends)) - len(run_ends)
+    if not pad:
+        return run_ends, list(per_run)
+    ends = np.concatenate([run_ends, np.full(pad, capacity, np.int32)])
+    return ends, [None if a is None
+                  else np.concatenate([a, np.zeros(pad, a.dtype)])
+                  for a in per_run]
+
+
+def ree_expand_program(capacity: int):
+    """The traced body of the ``ree_expand`` program: run-end expansion to
+    ``capacity`` rows. Row i belongs to run #{run ends <= i}
+    (``searchsorted(run_ends, i, side="right")``), counted by a scatter of
+    ones at the run ends and an inclusive cumsum: no search, no loop,
+    O(capacity + runs) for any run count. Ends at or past ``capacity`` (the
+    last live one where the batch is full, and the pad of ``pad_runs``) are
+    dropped; rows past the last live end (capacity padding) clamp to run
+    ``num_runs - 1``, and the batch's live mask excludes them. ``num_runs``
+    and ``num_rows`` are traced scalars, so run lists of one bucket share
+    the program whatever their count. Returns the gathered ``(values,
+    bits, valid)``: ``bits`` None where not given, ``valid`` None where the
+    runs carry none (the batch's shared mask then serves), else AND-ed
+    with the live rows."""
+    import jax.numpy as jnp
+
+    def ree_expand(run_ends, num_runs, num_rows, values, bits, valid):
+        hits = jnp.zeros(capacity, np.int32).at[run_ends].add(1, mode="drop")
+        idx = jnp.minimum(jnp.cumsum(hits, dtype=np.int32), num_runs - 1)
+
+        def take(a):
+            return None if a is None else jnp.take(a, idx, axis=0)
+        if valid is not None:
+            valid = jnp.logical_and(
+                take(valid), jnp.arange(capacity, dtype=np.int32) < num_rows)
+        return take(values), take(bits), valid
+    return ree_expand
 
 
 def ree_encoded_nbytes(num_runs: int, elem_size: int) -> int:
@@ -115,7 +152,8 @@ class DictEncoding:
 
 
 def dict_bucket(k: int) -> int:
-    """Power-of-two padding bucket for dictionary device arrays."""
+    """Power-of-two padding bucket for dictionary and run-list device
+    arrays."""
     from spark_rapids_tpu.columnar.dtypes import bucket_capacity
     return bucket_capacity(k, minimum=8)
 

@@ -299,8 +299,9 @@ PARQUET_DEVICE_RLE = _conf(
     "io.parquet.deviceRleExpand.enabled", bool, True,
     "TPU parquet scans keep RLE-dominant dictionary-encoded column chunks "
     "as (run-ends, run-values) pairs across the host link and expand them "
-    "in HBM with a jitted searchsorted gather — often hundreds of bytes on "
-    "the wire for millions of rows. Chunks whose index stream is mostly "
+    "in HBM in one cached program (a scatter of the run ends, a cumsum, a "
+    "gather) — often hundreds of bytes on the wire for millions of rows. "
+    "Chunks whose index stream is mostly "
     "bit-packed ship as dictionary indices instead; requires "
     "deviceDictDecode.")
 

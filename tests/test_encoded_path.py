@@ -114,6 +114,123 @@ def test_ree_upload_bit_identical_and_double_bits():
     assert_tables_equal(plain.slice(150, 300), sa.to_arrow())
 
 
+# ------------------------------------------- the run-end expansion program
+def _ree(num_runs, seed=0, values=None, max_run=20):
+    rng = np.random.default_rng(seed)
+    ends = np.cumsum(rng.integers(1, max_run, num_runs)).astype(np.int32)
+    if values is None:
+        values = pa.array(rng.integers(-1000, 1000, num_runs), pa.int64())
+    return pa.RunEndEncodedArray.from_arrays(pa.array(ends), values)
+
+
+def _expected_rows(ree, capacity):
+    """Every row of the capacity, padding included, by np.searchsorted:
+    (run index, live mask) over the slice-relative run list."""
+    ends, _ = ce.ree_staged(ree)
+    idx = np.minimum(np.searchsorted(ends, np.arange(capacity),
+                                     side="right"), len(ends) - 1)
+    return idx, np.arange(capacity) < len(ree)
+
+
+def _assert_expanded(ree, col, capacity):
+    idx, live = _expected_rows(ree, capacity)
+    _, vals = ce.ree_staged(ree)
+    n = len(ree)
+    data = np.asarray(col.data)
+    assert data.shape == (capacity,)
+    plain = np.asarray(ce.ree_to_plain(ree).fill_null(0))
+    np_vals = np.asarray(vals.fill_null(0))
+    # live rows as the host decode; capacity padding clamps to the last run
+    assert np.array_equal(data[:n], plain, equal_nan=True)
+    assert np.array_equal(data, np_vals[idx], equal_nan=True)
+    valid = live if vals.null_count == 0 else np.logical_and(
+        np.asarray(vals.is_valid())[idx], live)
+    assert np.array_equal(np.asarray(col.validity), valid)
+    return idx
+
+
+@pytest.mark.parametrize("num_runs", [1, 2, 7, 8, 9, 1000, 65536])
+def test_ree_expansion_equals_searchsorted(num_runs):
+    """Run counts on both sides of the padding buckets' edges (8 | 9) up to
+    65,536: the cached program's rows equal ``ree_to_plain`` and
+    ``np.searchsorted`` row for row, the capacity padding included."""
+    ree = _ree(num_runs, seed=num_runs, max_run=4 if num_runs > 1000 else 20)
+    b = DeviceBatch.from_arrow(pa.table({"x": ree}))
+    _assert_expanded(ree, b.columns[0], b.capacity)
+    assert b.columns[0].encoding is None
+    ends, vals = ce.ree_staged(ree)
+    pends, (pvals,) = ce.pad_runs(ends, b.capacity, (np.asarray(vals),))
+    assert len(pends) == len(pvals) == ce.dict_bucket(num_runs) >= num_runs
+    assert (pends[num_runs:] == b.capacity).all()
+
+
+@pytest.mark.parametrize("offset,length", [(1, 40), (37, 500), (5, 1),
+                                           (0, 128), (200, 128)])
+def test_ree_expansion_of_a_slice(offset, length):
+    """A sliced REE array (``offset > 0``, a one-row slice, and a slice
+    that fills its capacity exactly, whose last run end is dropped by the
+    scatter) expands as its host decode."""
+    ree = _ree(128, seed=offset, max_run=16).slice(offset, length)
+    b = DeviceBatch.from_arrow(pa.table({"x": ree}))
+    assert b.num_rows == length
+    _assert_expanded(ree, b.columns[0], b.capacity)
+
+
+def test_ree_expansion_of_doubles_keeps_their_bits():
+    """DOUBLE runs: data and the uint64 ``bits`` sibling identical to the
+    staged values' bits row for row, -0.0 and NaN included."""
+    vals = pa.array([1.5, -0.0, float("nan"), 0.0, -np.inf, 3.75, -0.0,
+                     float("nan"), 2.0 ** -1074], pa.float64())
+    ree = _ree(9, seed=3, values=vals)
+    col = DeviceBatch.from_arrow(pa.table({"x": ree})).columns[0]
+    idx = _assert_expanded(ree, col, col.capacity)
+    assert np.array_equal(np.asarray(col.bits),
+                          np.asarray(vals).view(np.uint64)[idx])
+    assert np.array_equal(np.asarray(col.data).view(np.uint64),
+                          np.asarray(vals).view(np.uint64)[idx])
+
+
+@pytest.mark.parametrize("null_runs", [[0], [3, 4], [8]])
+def test_ree_expansion_of_null_runs(null_runs):
+    """Run values with nulls: a row's validity is its run's, AND-ed with
+    the live mask, so capacity padding over a valid last run stays
+    invalid."""
+    raw = list(range(9))
+    vals = pa.array([None if i in null_runs else v for i, v in
+                     enumerate(raw)], pa.int32())
+    ree = _ree(9, seed=11, values=vals)
+    col = DeviceBatch.from_arrow(pa.table({"x": ree})).columns[0]
+    _assert_expanded(ree, col, col.capacity)
+    assert not np.asarray(col.validity)[len(ree):].any()
+
+
+def test_ree_expansion_is_one_cached_program(monkeypatch):
+    """A second upload of the same shape (another run count of the bucket,
+    another row count of the capacity) is a program-cache hit of the one
+    ``ree_expand`` program, and no ``searchsorted`` is traced."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.serving.program_cache import global_program_cache
+
+    def refuse(*a, **k):
+        raise AssertionError("searchsorted on the upload path")
+    monkeypatch.setattr(jnp, "searchsorted", refuse)
+    cache = global_program_cache()
+    def table(ends):
+        return pa.table({"x": pa.RunEndEncodedArray.from_arrays(
+            pa.array(np.array(ends, np.int32)),
+            pa.array(np.arange(len(ends), dtype=np.int64)))})
+    first = table([20, 40, 60, 80, 100])
+    second = table([3, 9, 30, 31, 70, 90, 120])
+    assert ce.dict_bucket(5) == ce.dict_bucket(7)
+    DeviceBatch.from_arrow(first)
+    before = cache.snapshot_counters()
+    b = DeviceBatch.from_arrow(second)
+    after = cache.snapshot_counters()
+    assert (after["hits"] - before["hits"],
+            after["misses"] - before["misses"]) == (1, 0)
+    _assert_expanded(second.column(0).chunk(0), b.columns[0], b.capacity)
+
+
 def test_upload_metrics_count_encoded_vs_decoded_bytes():
     t = _encoded_vs_decoded_table()
     before = um.TRANSFER_METRICS.snapshot()

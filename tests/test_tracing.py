@@ -225,7 +225,7 @@ KNOWN_KINDS = {
     "mshrink", "mexpand", "mwindow", "mwindow_part", "magg",
     "magg_merge_ag", "magg_merge_part", "magg_part", "mjoin_size",
     "mjoin_gather", "mjoin_lpart", "mjoin_rpart", "msort", "msort_sample",
-    "msort_part", "munion", "mexchange", "pconsol"}
+    "msort_part", "munion", "mexchange", "pconsol", "ree_expand"}
 #: ring-only by their nature: the root, and windows whose two ends are only
 #: known afterwards (tracing.record with explicit timestamps)
 RING_ONLY = {"query", "serving.queue_wait", "serving.preempt_yield",
@@ -487,6 +487,33 @@ def test_parquet_scan_leaves_its_host_pipeline(site, tpch, lineitem_dir,
     assert by_id[concat.parent_id].cat == "exec"
     assert concat.args["batches"] == 3 and concat.args["columns"] == 7
     assert concat.args["rows"] <= tpch["lineitem"].num_rows
+
+
+def test_stage_expand_counts_its_run_end_columns():
+    """An upload with one run-end-encoded column beside a dictionary and a
+    plain one: ``stage.expand`` notes the ``ree`` column and its live runs
+    (of the slice, not of the whole array), and holds its one
+    ``program.ree_expand`` call."""
+    from spark_rapids_tpu.columnar.batch import DeviceBatch
+    ends = pa.array(np.array([3, 10, 64, 200], np.int32))
+    ree = pa.RunEndEncodedArray.from_arrays(
+        ends, pa.array([4, 5, 6, 7], pa.int32()))
+    rows = 150
+    table = pa.table({
+        "r": ree.slice(5, rows),          # rows 5..154: runs 2, 3 and 4
+        "d": pa.array(np.arange(rows) % 3).dictionary_encode(),
+        "p": pa.array(np.arange(rows, dtype=np.int64))})
+    mark = tracing.TRACER.mark()
+    with tracing.TRACER.activate():
+        DeviceBatch.from_arrow(table)
+    records = tracing.TRACER.since(mark)
+    (expand,) = [r for r in records if r.name == "stage.expand"]
+    assert expand.args["columns"] == 2
+    assert expand.args["ree_columns"] == 1
+    assert expand.args["ree_runs"] == 3
+    assert expand.args["dispatches"] > expand.args["columns"]
+    calls = [r for r in records if r.parent_id == expand.span_id]
+    assert [r.name for r in calls] == ["program.ree_expand"]
 
 
 def test_one_batch_is_not_concatenated():
