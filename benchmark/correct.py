@@ -50,10 +50,12 @@ def compare(got, ref, exact):
     return mismatches, gap
 
 
-def judge(answers, tables, cpu_execs, unanswered=0):
-    """``answers``: {query id: [tables the timed path returned]}. Returns
-    (correct, numbers) where numbers is the list the run prints: each a
-    short name with its number and its limit."""
+def judge(answers, tables, cpu_execs, unanswered=0, failed=0):
+    """``answers``: {query id: [tables the timed path returned]};
+    ``unanswered``: requests that never came back; ``failed``: those that
+    raised or were shed, an answer that never comes too. Returns (correct,
+    numbers) where numbers is the list the run prints: each a short name
+    with its number and its limit."""
     numbers = []
     for qid in sorted(answers):
         ref_mod = load_reference(qid)
@@ -71,6 +73,8 @@ def judge(answers, tables, cpu_execs, unanswered=0):
     numbers.append({"name": "cpu_execs", "value": cpu_execs, "limit": 0,
                     "holds": "at_most"})
     numbers.append({"name": "unanswered", "value": unanswered, "limit": 0,
+                    "holds": "at_most"})
+    numbers.append({"name": "failed", "value": failed, "limit": 0,
                     "holds": "at_most"})
     return all(holds(n) for n in numbers), numbers
 

@@ -19,7 +19,8 @@ import threading
 import time
 import types
 
-from benchmark import correct, least_bytes, loadgen, manifest, readers, reduce
+from benchmark import (correct, least_bytes, loadgen, manifest, overload,
+                       readers, reduce)
 from benchmark.datagen import gen_tables
 
 CPU_EXEC = re.compile(r"\bCpu\w*Exec\b")
@@ -379,9 +380,12 @@ def open_window(st, seconds, seed, tracer=None, rate=None):
                     "latency_p90_s": loadgen.percentile(latencies, 90)})
 
 
-def window(st, seconds, seed, tracer=None):
+def window(st, seconds, seed, tracer=None, rate=None):
+    """The cell's loop; ``rate``: another offered rate (a sweep)."""
     if st.workload["loop"] == "open":
-        return open_window(st, seconds, seed, tracer)
+        return open_window(st, seconds, seed, tracer, rate)
+    if st.workload["loop"] == "open_overload":
+        return overload.window(st, seconds, GRACE_S, tracer, rate)
     return closed_window(st, seconds, tracer)
 
 
@@ -412,7 +416,7 @@ def run_cell(cell, seed, seconds, trace, t_start, scale=None, need_tpu=True):
         teardown(st)
     # the window has closed and the peak is read: now the reference
     ok, numbers = correct.judge(win.answers, st.tables, len(st.cpu_execs),
-                                win.unanswered)
+                                win.unanswered, win.failed - win.unanswered)
     device = dict(st.device, memory_peak_bytes=memory["peak_bytes_in_use"])
     values = dict(win.end_to_end, setup_s=setup_s)
     group = "per_layer" if trace else "end_to_end"

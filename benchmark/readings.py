@@ -54,7 +54,8 @@ def main(argv=None):
             finally:
                 harness.teardown(st)
             ok, numbers = correct.judge(win.answers, st.tables,
-                                        len(st.cpu_execs), win.unanswered)
+                                        len(st.cpu_execs), win.unanswered,
+                                        win.failed - win.unanswered)
             bad += not ok
             qids |= set(st.qids)
             print("reading: " + json.dumps({

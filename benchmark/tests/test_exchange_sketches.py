@@ -76,7 +76,7 @@ def test_nothing_to_read_is_no_reading(monkeypatch, queries):
 
 def test_the_manifest_lists_it_for_the_cell():
     mf = manifest.load()
-    entry = mf["per_layer"][-1]
+    (entry,) = [m for m in mf["per_layer"] if m["name"] == NAME]
     assert entry == {"name": NAME, "unit": "1/query", "better": "lower",
                      "source": "program_span", "layer": "Exchange; Mesh",
                      "moves": "query_wall_s", "workloads": [CELL]}

@@ -16,10 +16,16 @@ def _metric(mf, name):
                 if m["name"] == name)
 
 
+def _drop_cells_of(mf, config):
+    mf["workloads"] = [w for w in mf["workloads"] if w["config"] != config]
+
+
 def test_the_committed_manifest_is_valid(mf, capsys):
     assert manifest.problems_of(mf) == []
     assert run.main(["--validate"]) == 0
-    assert ("valid: 4 cells, 4 end-to-end and 25 per-layer metrics"
+    assert (f"valid: {len(mf['workloads'])} cells, "
+            f"{len(mf['end_to_end'])} end-to-end and "
+            f"{len(mf['per_layer'])} per-layer metrics"
             in capsys.readouterr().out)
 
 
@@ -45,8 +51,8 @@ def test_pr22_mistake_is_refused(mf):
      "unit"),
     (lambda mf: mf["workloads"][0].update(name="bad name"), "name"),
     (lambda mf: mf["configs"][0].update(source="x" * 201), "source"),
-    (lambda mf: mf["workloads"].pop(2), "has no cell"),
-    (lambda mf: mf["workloads"].pop(3), "has no cell"),
+    (lambda mf: _drop_cells_of(mf, "tpch_sf1_server"), "has no cell"),
+    (lambda mf: _drop_cells_of(mf, "tpch_sf1_parquet"), "has no cell"),
     (lambda mf: _metric(mf, "first_call_s").update(why="because"), "keys"),
     (lambda mf: _metric(mf, "latency_p90_s").update(bound=0.5), "bound"),
     (lambda mf: _metric(mf, "upload_mb_setup").update(moves="nothing"),

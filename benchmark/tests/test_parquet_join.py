@@ -77,10 +77,12 @@ def test_without_the_span_or_its_args_there_is_no_reading(monkeypatch):
 
 
 def test_the_manifest_lists_the_cell_and_its_metrics(capsys):
-    assert run.main(["--validate"]) == 0
-    assert "valid: 8 cells, 4 end-to-end and 49 per-layer metrics" \
-        in capsys.readouterr().out
     mf = manifest.load()
+    assert run.main(["--validate"]) == 0
+    assert (f"valid: {len(mf['workloads'])} cells, "
+            f"{len(mf['end_to_end'])} end-to-end and "
+            f"{len(mf['per_layer'])} per-layer metrics"
+            in capsys.readouterr().out)
     entry = manifest.workload_entry(mf, CELL)
     assert (entry["config"], entry["traffic"], entry["chips"]) == (
         "tpch_sf1_parquet_q3", "join", 1)
@@ -92,8 +94,9 @@ def test_the_manifest_lists_the_cell_and_its_metrics(capsys):
     joins = {m["name"] for m in manifest.metrics_of(mf, RESIDENT,
                                                     "per_layer")}
     assert ours == scans | joins
-    for m in mf["per_layer"][-2:]:
-        assert m["name"] in TWO
+    two = [m for m in mf["per_layer"] if m["name"] in TWO]
+    assert len(two) == len(TWO)
+    for m in two:
         assert m["workloads"] == [CELL, RESIDENT]
         assert (m["layer"], m["moves"], m["source"]) == (
             "Operators (XLA)", "query_wall_s", "program_span")

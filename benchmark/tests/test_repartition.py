@@ -207,9 +207,9 @@ def test_without_peaks_the_share_is_not_read(monkeypatch):
 
 # ----------------------------------------------- the manifest and the cell
 def test_the_manifest_is_valid_and_lists_the_cell(capsys):
-    assert run.main(["--validate"]) == 0
-    assert "valid: 7 cells" in capsys.readouterr().out
     mf = manifest.load()
+    assert run.main(["--validate"]) == 0
+    assert f"valid: {len(mf['workloads'])} cells" in capsys.readouterr().out
     entry = manifest.workload_entry(mf, CELL)
     assert (entry["config"], entry["chips"]) == (CONFIG, 1)
     config = manifest.config_file(mf, CONFIG)
@@ -221,9 +221,9 @@ def test_the_manifest_is_valid_and_lists_the_cell(capsys):
     ours = {m["name"] for m in manifest.metrics_of(mf, CELL, "per_layer")}
     q18 = {m["name"] for m in manifest.metrics_of(
         mf, "tpch_sf1_highcard.q18", "per_layer")}
-    assert ours == q18 | set(FIVE)
+    assert q18 | set(FIVE) <= ours
     for m in mf["per_layer"]:
-        if m["name"] in FIVE:
+        if m["name"] in FIVE or m["name"] in ours - q18:
             assert m["workloads"] == [CELL]
             assert (m["layer"], m["moves"], m["source"]) == (
                 "Exchange; Mesh", "query_wall_s", "program_span")
